@@ -46,6 +46,7 @@ def main() -> None:
     }
     names = args.only.split(",") if args.only else list(benches)
     print("name,us_per_call,derived")
+    failed = []
     for name in names:
         try:
             rows = list(benches[name]())
@@ -61,9 +62,12 @@ def main() -> None:
                 "rows": [{"name": nm, "value": us, "derived": derived}
                          for nm, us, derived in rows],
             }, mirror, name)
-        except Exception as e:  # noqa: BLE001 — report and continue
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
             print(f"{name}.ERROR,0,{type(e).__name__}: {e}", flush=True)
             traceback.print_exc(file=sys.stderr)
+            failed.append(name)
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

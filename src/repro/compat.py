@@ -1,94 +1,29 @@
-"""Version-portable JAX API surface (DESIGN.md §8).
-
-The repo targets both JAX 0.4.x (the pinned CI/toolchain version) and
-current JAX. Three API families moved between those versions:
-
-  * mesh construction — ``jax.make_mesh`` gained the ``axis_types``
-    keyword (and ``jax.sharding.AxisType``) after 0.4.x; very old
-    versions have no ``jax.make_mesh`` at all.
-  * ``shard_map`` — graduated from ``jax.experimental.shard_map`` to
-    ``jax.shard_map``, renaming ``check_rep`` to ``check_vma`` on the way.
-  * sharding helpers — re-exported here so call sites never import from
-    version-dependent module paths.
+"""The repo's one door to JAX's mesh and shard_map API (DESIGN.md §8).
 
 Every mesh/shard_map construction in the repo goes through this module;
-nothing else may call ``jax.make_mesh`` / ``jax.shard_map`` directly.
-Feature detection is by inspection, not version parsing, so forks and
-backports behave correctly.
+nothing else calls ``jax.make_mesh`` / ``jax.shard_map`` directly, so the
+defaults below (all-Auto mesh axes, keyword-only shard_map) hold
+everywhere. Written for the installed JAX (>= 0.9).
 """
 from __future__ import annotations
-
-import inspect
-import math
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec  # noqa: F401  (re-export)
 
-# --- feature flags -------------------------------------------------------
 
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_MAKE_MESH = hasattr(jax, "make_mesh")
-if HAS_MAKE_MESH:
-    _MAKE_MESH_PARAMS = frozenset(
-        inspect.signature(jax.make_mesh).parameters)
-else:
-    _MAKE_MESH_PARAMS = frozenset()
-HAS_MESH_AXIS_TYPES = "axis_types" in _MAKE_MESH_PARAMS
+def make_mesh(shape, axes, *, devices=None):
+    """`jax.make_mesh` with every axis Auto; `devices` pins the device list
+    (e.g. a described TPU topology's devices for an ahead-of-time
+    compile)."""
+    axes = tuple(axes)
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kwargs)
 
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # JAX 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
-# replication checking: check_vma (new) vs check_rep (0.4.x)
-_CHECK_KW = ("check_vma" if "check_vma" in _SHARD_MAP_PARAMS
-             else "check_rep" if "check_rep" in _SHARD_MAP_PARAMS
-             else None)
-
-
-# --- mesh construction ---------------------------------------------------
-
-def default_axis_types(n: int):
-    """The Auto axis-type tuple on JAX versions that have it, else None."""
-    if HAS_AXIS_TYPE:
-        return (jax.sharding.AxisType.Auto,) * n
-    return None
-
-
-def make_mesh(shape, axes, *, devices=None, axis_types=None):
-    """Build a Mesh portably.
-
-    ``axis_types`` defaults to all-Auto where supported and is silently
-    dropped on versions without the concept (0.4.x meshes are implicitly
-    Auto on every axis, so the semantics match).
-    """
-    shape, axes = tuple(shape), tuple(axes)
-    if HAS_MAKE_MESH:
-        kwargs = {}
-        if devices is not None and "devices" in _MAKE_MESH_PARAMS:
-            kwargs["devices"] = devices
-        if HAS_MESH_AXIS_TYPES:
-            kwargs["axis_types"] = (axis_types if axis_types is not None
-                                    else default_axis_types(len(axes)))
-        return jax.make_mesh(shape, axes, **kwargs)
-    from jax.experimental import mesh_utils
-    if devices is None:
-        # create_device_mesh requires len(devices) == prod(shape); match
-        # jax.make_mesh's slicing behavior for smaller meshes
-        n = math.prod(shape)
-        devices = jax.devices()[:n]
-    dev = mesh_utils.create_device_mesh(shape, devices=devices)
-    return Mesh(dev, axes)
-
-
-# --- shard_map -----------------------------------------------------------
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Portable shard_map: keyword-only, translating ``check_vma`` to the
-    installed version's replication-check keyword (or dropping it)."""
-    kwargs = {}
-    if check_vma is not None and _CHECK_KW is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+    """Keyword-only `jax.shard_map`; `check_vma=None` keeps JAX's default."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

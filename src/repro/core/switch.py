@@ -513,7 +513,7 @@ def copy_kv_pages_host(cfg: ModelConfig, cc: CacheConfig, src, dst,
     """Host-side cross-world KV page copies for KV layers [lo, hi).
 
     `src_host` / `dst_host` are ONE data group's flat per-rank buffers,
-    shape (G, NE) — src a device_get snapshot, dst the staged buffer this
+    shape (G, *rank_shape) — src a device_get snapshot, dst the staged buffer this
     writes into. Pages canonicalize through the full-head form: a per-rank
     (EP) source page already holds all K heads; a pooled (TP) source page
     is reassembled from its kv_rep representative ranks. Writes mirror the
@@ -602,7 +602,6 @@ def _kv_migrate_body(cfg: ModelConfig, cc: CacheConfig, G: int,
     _, _, _, page, K, dh = ep_shape
     Lc = hi - lo
     Kl, kv_rep = gi.kv_local, gi.kv_rep
-    NE = int(np.prod(ep_shape))
 
     def ep_to_tp(kv_src, kv_dst, src_pages, dst_pages, valid):
         r = lax.axis_index(model_axis)
@@ -627,7 +626,7 @@ def _kv_migrate_body(cfg: ModelConfig, cc: CacheConfig, G: int,
         dst = scatter_pages_rows(
             dst.reshape(dst.shape[0] * 2, tp_shape[2], page * Kl * dh),
             flat_dst, moved, row0=lo * 2, backend=backend)
-        return dst.reshape(1, 1, NE)
+        return dst.reshape(kv_dst.shape)
 
     def tp_to_ep(kv_src, kv_dst, src_pages, dst_pages, valid):
         r = lax.axis_index(model_axis)
@@ -651,7 +650,7 @@ def _kv_migrate_body(cfg: ModelConfig, cc: CacheConfig, G: int,
             dst.reshape(dst.shape[0] * 2, ep_shape[2], page * K * dh),
             dp, full.reshape(Lc * 2, pmax, page * K * dh),
             row0=lo * 2, backend=backend)
-        return dst.reshape(1, 1, NE)
+        return dst.reshape(kv_dst.shape)
 
     return ep_to_tp if direction == "ep_to_tp" else tp_to_ep
 

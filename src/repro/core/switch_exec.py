@@ -70,12 +70,12 @@ def _pow2_pad(n: int, lo: int = 8) -> int:
     return p
 
 
-# Fixed plan width of the commit-time dirty-page delta pass. Wider dirty
-# sets are split into multiple mover calls of this width, so the delta
-# executable is compiled exactly once per direction — a later switch can
-# never hit a compile inside the decode pause because its overlap window
-# happened to dirty more pages.
-DELTA_PMAX = 8
+# Fixed plan width of every chunked KV mover call (layer chunks and the
+# commit-time dirty-page delta). Wider plans are split into several calls
+# of this width, so each mover compiles once per (direction, layers) at
+# warmup — a live switch never compiles because it had more pages to move
+# or its overlap window dirtied more of them.
+KV_BLOCK = 8
 
 
 @dataclass
@@ -100,8 +100,7 @@ class SwitchSession:
     direction: str                          # "<src>_to_<dst>" (stats label)
     kv_dir: str | None                      # KV-view mover direction
     t_start: float
-    plan_arrays: tuple                      # (sp, dp, vm) device, (Dd, G, P)
-    pmax: int
+    plan_blocks: list             # [(sp, dp, vm)] device, (Dd, G, KV_BLOCK)
     assignments: list                       # per data group lists merged
     new_alloc: list
     chunks: list                            # [(w_lo, w_hi, kv_lo, kv_hi)]
@@ -354,8 +353,10 @@ class SwitchExecutor:
         self.session = SwitchSession(
             src=src, dst=dst, direction=f"{src}_to_{dst}", kv_dir=kv_dir,
             t_start=t0,
-            plan_arrays=tuple(jnp.asarray(a) for a in plan_arrays),
-            pmax=pmax, assignments=assignments,
+            plan_blocks=[tuple(jnp.asarray(a[..., b:b + KV_BLOCK])
+                               for a in plan_arrays)
+                         for b in range(0, pmax, KV_BLOCK)],
+            assignments=assignments,
             new_alloc=new_alloc, chunks=self._layer_chunks(chunk_layers),
             experts_dst=experts_dst, kv_dst=kv_dst,
             kv_pages=kv_pages, live_requests=len(live),
@@ -376,18 +377,19 @@ class SwitchExecutor:
                          s.experts_dst["w13"], s.experts_dst["w2"])
             s.experts_dst = {"w13": d13, "w2": d2}
         if s.kv_dst is not None and kv_hi > kv_lo:
-            sp, dp, vm = s.plan_arrays                 # device-resident
-            mfn = self.chunk_migrate_fn(s.kv_dir, kv_lo, kv_hi, s.pmax)
-            s.kv_dst = mfn(kv_flat, s.kv_dst, sp, dp, vm)
+            mfn = self.chunk_migrate_fn(s.kv_dir, kv_lo, kv_hi, KV_BLOCK)
+            for sp, dp, vm in s.plan_blocks:          # device-resident
+                s.kv_dst = mfn(kv_flat, s.kv_dst, sp, dp, vm)
         s.next_chunk += 1
         return not s.done
 
     def warmup_movers(self, src, dst, experts, kv_flat,
                       chunk_layers: int) -> None:
         """Compile every chunked-switch mover for src->dst before traffic:
-        a dry start/advance/abort with an EMPTY plan (pmax = the standard
-        minimum width) plus the commit-time delta executable, so the first
-        LIVE switch selects executables, never compiles (paper §4.4).
+        a dry start/advance/abort with an EMPTY plan (one KV_BLOCK-wide
+        block, the width every live plan is split into) plus the
+        commit-time delta executable, so a LIVE switch selects
+        executables, never compiles (paper §4.4).
 
         Read-only on the live state: start() stages fresh zero destination
         buffers (the only donated arguments), plans with no requests, and
@@ -400,10 +402,10 @@ class SwitchExecutor:
             pass
         if s.kv_dst is not None:
             # the commit-time dirty-page delta mover (all layers, fixed
-            # DELTA_PMAX width) only runs when a window got dirty — warm
+            # KV_BLOCK width) only runs when a window got dirty — warm
             # it on a throwaway zero buffer so a dirty commit never compiles
-            mfn = self.chunk_migrate_fn(s.kv_dir, 0, self.Lk, DELTA_PMAX)
-            sp, dp, vm = s.plan_arrays
+            mfn = self.chunk_migrate_fn(s.kv_dir, 0, self.Lk, KV_BLOCK)
+            sp, dp, vm = s.plan_blocks[0]
             scratch = self._zeros(kv_flat.shape, kv_flat.dtype,
                                   (self.da, self.m))
             jax.block_until_ready(mfn(kv_flat, scratch, sp, dp, vm))
@@ -532,7 +534,7 @@ class SwitchExecutor:
             if delta_pages:
                 # fixed-width blocks -> one compiled delta executable per
                 # direction, regardless of how dirty the window got
-                W = DELTA_PMAX
+                W = KV_BLOCK
                 mfn = self.chunk_migrate_fn(s.kv_dir, 0, self.Lk, W)
                 nblocks = max(-(-len(pairs) // W)
                               for rows in per for pairs in rows.values())
@@ -597,7 +599,7 @@ class CrossWorldSession:
     chunks: list                            # [(w_lo, w_hi, kv_lo, kv_hi)]
     next_chunk: int = 0
     experts_chunks: list = None             # staged [(w13, w2)] np, in order
-    kv_host: np.ndarray = None              # staged (Dd, G_dst, NE) np
+    kv_host: np.ndarray = None    # staged (Dd, G_dst, *rank_shape) np
     kv_pages: int = 0
     live_requests: int = 0
     plan_pause_s: float = 0.0
@@ -664,7 +666,7 @@ class CrossWorldSwitcher:
         if self.Lk > 0:
             # per-rank NE is world-independent (cc.nelems ignores G), so the
             # destination rows reuse the source buffer's trailing dim
-            kv_host = np.zeros((self.Dd, G_dst, kv_flat.shape[-1]),
+            kv_host = np.zeros((self.Dd, G_dst) + kv_flat.shape[2:],
                                dtype=kv_flat.dtype)
         self.session = CrossWorldSession(
             src=src, dst=dst, G_src=G_src, G_dst=G_dst,
@@ -842,11 +844,12 @@ class CrossWorldSwitcher:
             w2 = np.concatenate([c[1] for c in s.experts_chunks], axis=0)
             dst_ax = s.dst.expert_axes((self.da,), self.m)
             esh = NamedSharding(dst_mesh, P(None, dst_ax, None, None, None))
-            experts = {"w13": jax.device_put(jnp.asarray(w13), esh),
-                       "w2": jax.device_put(jnp.asarray(w2), esh)}
+            # numpy straight to the shards: no full copy on one device
+            experts = {"w13": jax.device_put(w13, esh),
+                       "w2": jax.device_put(w2, esh)}
         kv = None
         if s.kv_host is not None:
-            kv = jax.device_put(jnp.asarray(s.kv_host),
+            kv = jax.device_put(s.kv_host,
                                 NamedSharding(dst_mesh, P(self.da, self.m)))
             jax.block_until_ready(kv)
         # prefix caches never migrate across worlds: the commit starts

@@ -3,28 +3,27 @@
 Every ``ops.py`` dispatcher funnels through :func:`resolve_backend`, so the
 policy lives in exactly one place:
 
-  explicit "ref"                 -> pure-jnp oracle
-  explicit "kernel" / "pallas"   -> Pallas (compiled on TPU, interpret mode
-                                    elsewhere — a *debugging* path off-TPU)
-  explicit "interpret"           -> Pallas interpret mode, even on TPU
-  None (auto)                    -> REPRO_FORCE_REF=1 forces ref; otherwise
-                                    kernel on TPU, ref on CPU/GPU hosts
+  "ref"          -> pure-jnp oracle
+  "pallas"       -> the compiled Pallas kernel; only on a TPU (anywhere else
+                    it is an error, never a silent substitute)
+  "interpret"    -> Pallas interpret mode (CPU parity tests)
+  None (auto)    -> pallas on a TPU, ref everywhere else
 
-The auto default is deliberately ref off-TPU: interpret-mode Pallas is
-orders of magnitude slower than the jnp oracle and is only ever wanted
-explicitly (parity tests, roofline bench).
+Interpret mode is orders of magnitude slower than the jnp oracle and is
+only ever wanted explicitly. A run that must prove it used the kernels
+(``chip_smoke.py``) reads the trace-time counters below and fails on any
+op that resolved to ``ref`` or ``interpret``.
 
-The module also keeps trace-time dispatch counters so tests can assert that
-a given code path (e.g. chunked switch staging) actually routes through the
-kernel ops rather than generic XLA gathers.  Counters tick once per *trace*,
-not per execution — sufficient to prove routing.
+Counters tick once per *trace*, not per execution — sufficient to prove
+routing.
 """
 from __future__ import annotations
 
-import os
 from collections import Counter
 
 import jax
+
+BACKENDS = ("ref", "pallas", "interpret")
 
 #: (op_name, resolved_backend) -> number of traces since last reset_counts().
 COUNTS: Counter[tuple[str, str]] = Counter()
@@ -47,28 +46,20 @@ def calls(op: str, resolved: str | None = None) -> int:
 
 
 def resolve_backend(explicit: str | None = None, *,
-                    env: str | None = None,
                     platform: str | None = None) -> str:
-    """Collapse (explicit request, env override, platform) to one of
-    {"ref", "pallas", "interpret"}.
+    """Collapse (explicit request, platform) to one of BACKENDS.
 
-    `env`/`platform` default to the real environment; tests inject them to
-    pin a branch without monkeypatching the process.
+    `platform` defaults to JAX's default backend; tests inject it to pin a
+    branch without touching the process.
     """
-    if env is None:
-        env = os.environ.get("REPRO_FORCE_REF", "0")
-    if explicit == "ref":
-        return "ref"
+    if explicit is not None and explicit not in BACKENDS:
+        raise ValueError(f"unknown kernel backend {explicit!r}; expected "
+                         f"one of {BACKENDS} or None (auto)")
+    if explicit in ("ref", "interpret"):
+        return explicit
     if platform is None:
         platform = jax.default_backend()
-    if explicit in ("kernel", "pallas"):
-        return "pallas" if platform == "tpu" else "interpret"
-    if explicit == "interpret":
-        return "interpret"
-    if explicit is not None:
-        raise ValueError(
-            f"unknown kernel backend {explicit!r}; expected one of "
-            "'ref', 'kernel', 'pallas', 'interpret', or None (auto)")
-    if env == "1":
-        return "ref"
+    if explicit == "pallas" and platform != "tpu":
+        raise ValueError(f"backend 'pallas' compiles for a TPU, not "
+                         f"{platform!r}; use 'interpret' off the chip")
     return "pallas" if platform == "tpu" else "ref"

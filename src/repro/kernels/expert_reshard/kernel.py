@@ -3,20 +3,42 @@
 EP->TP runs permute-then-exchange: this kernel packs each rank's complete
 experts into per-peer contiguous chunks in ONE pass over HBM (vs. a staged
 copy), preserving the gate/up pairing of w13. TP->EP runs the inverse
-interleave after the exchange. Grid (G, E_loc): one (peer, expert) chunk
-per step; block shapes keep the copied tile in VMEM.
+interleave after the exchange.
+
+Every permute is a handful of rectangular HBM->HBM DMAs with static
+offsets — one per (peer, gate/up half) — so no expert ever passes through
+VMEM (a whole mixtral-8x7b expert is 224-448 MiB) and the slice offsets
+are multiples of I/G, which is tile-aligned at published widths.
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_ANY = pl.BlockSpec(memory_space=pl.ANY)
 
 
-def _pack_kernel(w_ref, o_ref, *, G: int):
-    # w (1, 2, G, I/G, D) block for one expert -> o (1, 1, 2, I/G, D)
-    g = pl.program_id(0)
-    o_ref[0, 0] = w_ref[0, :, g]
+def _dma_permute(name: str, pairs, x: jax.Array, out_shape, interpret):
+    """Run `pairs(src_ref, dst_ref)` -> [(src_window, dst_window), ...] as
+    concurrent DMAs from x into a fresh HBM output of `out_shape`."""
+    def kernel(x_ref, o_ref, sem):
+        copies = [pltpu.make_async_copy(s, d, sem)
+                  for s, d in pairs(x_ref, o_ref)]
+        for c in copies:
+            c.start()
+        for c in copies:
+            c.wait()
+
+    return pl.pallas_call(
+        kernel,
+        in_specs=[_ANY],
+        out_specs=_ANY,
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
+        interpret=interpret,
+        name=name,
+    )(x)
 
 
 def pack_peer_chunks_pallas(w13: jax.Array, G: int, *,
@@ -24,67 +46,36 @@ def pack_peer_chunks_pallas(w13: jax.Array, G: int, *,
     """w13 (E_loc, 2I, D) -> (G, E_loc, 2*(I/G), D)."""
     E_loc, W2, D = w13.shape
     I = W2 // 2
-    wv = w13.reshape(E_loc, 2, G, I // G, D)
-    import functools
-    out = pl.pallas_call(
-        functools.partial(_pack_kernel, G=G),
-        grid=(G, E_loc),
-        in_specs=[pl.BlockSpec((1, 2, G, I // G, D),
-                               lambda g, e: (e, 0, 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1, 2, I // G, D),
-                               lambda g, e: (g, e, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, E_loc, 2, I // G, D), w13.dtype),
-        interpret=interpret,
-    )(wv)
-    return out.reshape(G, E_loc, 2 * (I // G), D)
-
-
-def _pack_w_kernel(w_ref, o_ref):
-    # w (1, D, G, I/G) block for one expert -> o (1, 1, D, I/G)
-    g = pl.program_id(0)
-    o_ref[0, 0] = w_ref[0, :, g]
+    Ig = I // G
+    return _dma_permute(
+        "expert_pack_peer_chunks",
+        lambda x, o: [(x.at[:, pl.ds(h * I + g * Ig, Ig)],
+                       o.at[g, :, pl.ds(h * Ig, Ig)])
+                      for g in range(G) for h in range(2)],
+        w13, (G, E_loc, 2 * Ig, D), interpret)
 
 
 def pack_width_chunks_pallas(w2: jax.Array, G: int, *,
                              interpret: bool = True) -> jax.Array:
     """w2 (E_loc, D, I) -> (G, E_loc, D, I/G): per-peer down-proj chunks."""
     E_loc, D, I = w2.shape
-    wv = w2.reshape(E_loc, D, G, I // G)
-    return pl.pallas_call(
-        _pack_w_kernel,
-        grid=(G, E_loc),
-        in_specs=[pl.BlockSpec((1, D, G, I // G),
-                               lambda g, e: (e, 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1, D, I // G),
-                               lambda g, e: (g, e, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, E_loc, D, I // G), w2.dtype),
-        interpret=interpret,
-    )(wv)
-
-
-def _interleave_w_kernel(c_ref, o_ref):
-    # c (G, 1, D, Ic) all peers' shards of one expert -> o (1, D, G, Ic)
-    o_ref[0] = jnp.moveaxis(c_ref[:, 0], 0, 1)
+    Ig = I // G
+    return _dma_permute(
+        "expert_pack_width_chunks",
+        lambda x, o: [(x.at[:, :, pl.ds(g * Ig, Ig)], o.at[g])
+                      for g in range(G)],
+        w2, (G, E_loc, D, Ig), interpret)
 
 
 def interleave_width_shards_pallas(chunks: jax.Array, *,
                                    interpret: bool = True) -> jax.Array:
     """chunks (G, E_loc, D, Ic) -> (E_loc, D, G*Ic): inverse of pack_width."""
     G, E_loc, D, Ic = chunks.shape
-    out = pl.pallas_call(
-        _interleave_w_kernel,
-        grid=(E_loc,),
-        in_specs=[pl.BlockSpec((G, 1, D, Ic), lambda e: (0, e, 0, 0))],
-        out_specs=pl.BlockSpec((1, D, G, Ic), lambda e: (e, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((E_loc, D, G, Ic), chunks.dtype),
-        interpret=interpret,
-    )(chunks)
-    return out.reshape(E_loc, D, G * Ic)
-
-
-def _interleave_kernel(c_ref, o_ref):
-    # c (G, 1, 2, half, D) all peers' shards of one expert -> o (1, 2, G, half, D)
-    o_ref[0] = jnp.moveaxis(c_ref[:, 0], 0, 1)
+    return _dma_permute(
+        "expert_interleave_width_shards",
+        lambda x, o: [(x.at[g], o.at[:, :, pl.ds(g * Ic, Ic)])
+                      for g in range(G)],
+        chunks, (E_loc, D, G * Ic), interpret)
 
 
 def interleave_shards_pallas(chunks: jax.Array, *,
@@ -92,15 +83,10 @@ def interleave_shards_pallas(chunks: jax.Array, *,
     """chunks (G, E_loc, 2*(I/G), D) -> (E_loc, 2I, D)."""
     G, E_loc, Wl, D = chunks.shape
     half = Wl // 2
-    cv = chunks.reshape(G, E_loc, 2, half, D)
-    out = pl.pallas_call(
-        _interleave_kernel,
-        grid=(E_loc,),
-        in_specs=[pl.BlockSpec((G, 1, 2, half, D),
-                               lambda e: (0, e, 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, 2, G, half, D),
-                               lambda e: (e, 0, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((E_loc, 2, G, half, D), chunks.dtype),
-        interpret=interpret,
-    )(cv)
-    return out.reshape(E_loc, 2 * G * half, D)
+    I = G * half
+    return _dma_permute(
+        "expert_interleave_shards",
+        lambda x, o: [(x.at[g, :, pl.ds(h * half, half)],
+                       o.at[:, pl.ds(h * I + g * half, half)])
+                      for g in range(G) for h in range(2)],
+        chunks, (E_loc, 2 * I, D), interpret)

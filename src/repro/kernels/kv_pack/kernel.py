@@ -1,14 +1,16 @@
-"""Pallas TPU page-pack kernel: the gather stage of the KV switch (paper
-§4.3, Fig. 8(b)).
+"""Pallas TPU page-pack kernels: the gather/scatter stages of the KV switch
+(paper §4.3, Fig. 8(b)).
 
-Reads the page-indexed work descriptors and copies scattered KV pages into
-a contiguous per-peer chunk in one HBM pass — the 'Direct' row of Table 1.
-On real TPU the store side would be a `make_async_remote_copy` into the
-peer's slot; portably we pack locally and let the collective move the
-chunk (still one local HBM read per element).
+The page indices are scalar-prefetched into SMEM; pools, packed chunks and
+staged values all stay in HBM (`pl.ANY`) and every page moves with one
+HBM->HBM DMA — the 'Direct' row of Table 1, one HBM read per element and
+no VMEM round-trip. On real TPU the store side would be a
+`make_async_remote_copy` into the peer's slot; portably we pack locally and
+let the collective move the chunk.
 
-Grid (n,): one page per step; the pool stays in HBM (ANY) and the page is
-moved with a dynamic slice.
+A row-batched pool (R, pages, M) is viewed as (R, pages, M/128, 128) when
+M is lane-aligned, so each page is a whole trailing tile block and the
+DMA's dynamic index falls on a major dimension.
 """
 from __future__ import annotations
 
@@ -16,36 +18,40 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_ANY = pl.BlockSpec(memory_space=pl.ANY)
 
 
-def _pack_kernel(idx_ref, pool_ref, o_ref):
-    pid = idx_ref[0]
-    o_ref[0] = pool_ref[pl.ds(pid, 1)][0]
+def _page_view(M: int) -> tuple[int, int]:
+    return (M // 128, 128) if M % 128 == 0 else (1, M)
 
 
-def gather_pages_pallas(pool: jax.Array, idx: jax.Array, *,
-                        interpret: bool = True) -> jax.Array:
-    """pool (pages, page, K, dh); idx (n,) int32 -> (n, page, K, dh)."""
-    n = idx.shape[0]
-    page, K, dh = pool.shape[1:]
-    return pl.pallas_call(
-        _pack_kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, page, K, dh), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, page, K, dh), pool.dtype),
-        interpret=interpret,
-    )(idx, pool)
+def _copy_rows(n: int, rows: int, src, dst, sem) -> None:
+    """For each row r: start the n page DMAs `src(r, i) -> dst(r, i)`, then
+    wait for them (at most n copies in flight)."""
+    def row(r, carry):
+        def start(i, c):
+            pltpu.make_async_copy(src(r, i), dst(r, i), sem).start()
+            return c
+
+        def wait(i, c):
+            pltpu.make_async_copy(src(r, i), dst(r, i), sem).wait()
+            return c
+
+        lax.fori_loop(0, n, start, 0)
+        lax.fori_loop(0, n, wait, 0)
+        return carry
+
+    lax.fori_loop(0, rows, row, 0)
 
 
-def _pack_rows_kernel(idx_ref, pool_ref, o_ref):
-    r = pl.program_id(0)
-    pid = idx_ref[0]
-    o_ref[...] = pool_ref[pl.ds(r, 1), pl.ds(pid, 1)]
+def _gather_rows_kernel(idx_ref, pool_ref, o_ref, sem):
+    R, n = o_ref.shape[0], o_ref.shape[1]
+    _copy_rows(n, R, lambda r, i: pool_ref.at[r, idx_ref[i]],
+               lambda r, i: o_ref.at[r, i], sem)
 
 
 def gather_pages_rows_pallas(pool: jax.Array, idx: jax.Array, *,
@@ -53,30 +59,29 @@ def gather_pages_rows_pallas(pool: jax.Array, idx: jax.Array, *,
     """Row-batched gather: pool (R, pages, M); idx (n,) -> (R, n, M).
 
     One launch stages every (layer, K/V) row of a chunk's pool view — the
-    fused per-chunk mover of the switch staging path.  Grid (R, n): each
-    step moves one page of one row with a dynamic slice out of HBM.
+    fused per-chunk mover of the switch staging path.
     """
-    R, _, M = pool.shape
+    R, pages, M = pool.shape
     n = idx.shape[0]
-    return pl.pallas_call(
-        _pack_rows_kernel,
-        grid=(R, n),
-        in_specs=[
-            pl.BlockSpec((1,), lambda r, i: (i,)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, 1, M), lambda r, i: (r, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, n, M), pool.dtype),
+    a, l = _page_view(M)
+    out = pl.pallas_call(
+        _gather_rows_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,), in_specs=[_ANY],
+            out_specs=_ANY, scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct((R, n, a, l), pool.dtype),
         interpret=interpret,
-    )(idx, pool)
+        name="kv_gather_pages_rows",
+    )(idx.astype(jnp.int32), pool.reshape(R, pages, a, l))
+    return out.reshape(R, n, M)
 
 
-def _scatter_rows_kernel(idx_ref, vals_ref, pool_in_ref, pool_out_ref, *,
-                         row0: int):
+def _scatter_rows_kernel(idx_ref, vals_ref, pool_in_ref, pool_out_ref, sem,
+                         *, row0: int):
     del pool_in_ref   # aliased with pool_out_ref
-    r = pl.program_id(0)
-    pid = idx_ref[0]
-    pool_out_ref[pl.ds(row0 + r, 1), pl.ds(pid, 1)] = vals_ref[...]
+    Rv, n = vals_ref.shape[0], vals_ref.shape[1]
+    _copy_rows(n, Rv, lambda r, i: vals_ref.at[r, i],
+               lambda r, i: pool_out_ref.at[row0 + r, idx_ref[i]], sem)
 
 
 def scatter_pages_rows_pallas(pool: jax.Array, idx: jax.Array,
@@ -87,43 +92,37 @@ def scatter_pages_rows_pallas(pool: jax.Array, idx: jax.Array,
     pool (R, pages, M), idx (n,), vals (Rv, n, M) with row0 + Rv <= R.
     Input/output aliased: one in-place HBM pass commits a whole chunk.
     """
-    Rv, n, M = vals.shape
-    return pl.pallas_call(
+    R, pages, M = pool.shape
+    Rv, n, _ = vals.shape
+    a, l = _page_view(M)
+    out = pl.pallas_call(
         partial(_scatter_rows_kernel, row0=row0),
-        grid=(Rv, n),
-        in_specs=[
-            pl.BlockSpec((1,), lambda r, i: (i,)),
-            pl.BlockSpec((1, 1, M), lambda r, i: (r, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,), in_specs=[_ANY, _ANY],
+            out_specs=_ANY, scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct((R, pages, a, l), pool.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(idx, vals, pool)
+        name="kv_scatter_pages_rows",
+    )(idx.astype(jnp.int32), vals.reshape(Rv, n, a, l),
+      pool.reshape(R, pages, a, l))
+    return out.reshape(R, pages, M)
 
 
-def _scatter_kernel(idx_ref, vals_ref, pool_in_ref, pool_out_ref):
-    del pool_in_ref   # aliased with pool_out_ref
-    pid = idx_ref[0]
-    pool_out_ref[pl.ds(pid, 1)] = vals_ref[...]
+def gather_pages_pallas(pool: jax.Array, idx: jax.Array, *,
+                        interpret: bool = True) -> jax.Array:
+    """pool (pages, page, K, dh); idx (n,) int32 -> (n, page, K, dh)."""
+    pages, *page_shape = pool.shape
+    out = gather_pages_rows_pallas(pool.reshape(1, pages, -1), idx,
+                                   interpret=interpret)
+    return out.reshape(idx.shape[0], *page_shape)
 
 
 def scatter_pages_pallas(pool: jax.Array, idx: jax.Array, vals: jax.Array, *,
                          interpret: bool = True) -> jax.Array:
     """Write vals (n, page, K, dh) into pool at idx (input/output aliased)."""
-    n = idx.shape[0]
-    page, K, dh = pool.shape[1:]
-    return pl.pallas_call(
-        _scatter_kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1, page, K, dh), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        input_output_aliases={2: 0},
-        interpret=interpret,
-    )(idx, vals, pool)
+    pages = pool.shape[0]
+    out = scatter_pages_rows_pallas(
+        pool.reshape(1, pages, -1), idx, vals.reshape(1, vals.shape[0], -1),
+        interpret=interpret)
+    return out.reshape(pool.shape)

@@ -16,11 +16,10 @@ from jax.experimental import pallas as pl
 
 
 def _gmm_kernel(x_ref, w_ref, o_ref):
-    # x (1, bc, D), w (1, bw, D) -> o (1, bc, bw)
-    x = x_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
+    # x (1, bc, D), w (1, bw, D) -> o (1, bc, bw); the MXU takes the
+    # operands in their own dtype and accumulates in fp32
     o_ref[0] = jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
+        x_ref[0], w_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -49,5 +48,6 @@ def grouped_matmul_pallas(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((1, bc, bw), lambda e, i, j: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, Cp, Wp), x.dtype),
         interpret=interpret,
+        name="moe_grouped_matmul",
     )(x, w)
     return out[:, :C, :W]

@@ -1,8 +1,7 @@
 """Jit'd dispatcher for paged attention.
 
-Backend policy lives in repro.kernels.dispatch: explicit "ref"/"kernel"/
-"pallas"/"interpret", or None = auto (REPRO_FORCE_REF=1 forces ref; kernel
-on TPU, ref elsewhere).
+Backend policy lives in repro.kernels.dispatch: explicit "ref"/"pallas"/
+"interpret", or None = auto (pallas on TPU, ref elsewhere).
 """
 from __future__ import annotations
 

@@ -400,9 +400,9 @@ def _lower_serve(cfg, shape, mesh, layout, da, G, dp, page=128):
                              layout, G), layout, G))
         shp = ssm_state_shapes(cfg, Dd, Bslot)
         dt = cfg.param_dtype
-        NE = cc.nelems(cfg, G)
+        kv = (Dd, G) + cc.rank_shape(cfg, G)
         maxp = cc.max_pages_per_req
-        args = (pk, sds((Dd, G, NE), dt), sds(shp["conv_x"], dt),
+        args = (pk, sds(kv, dt), sds(shp["conv_x"], dt),
                 sds(shp["conv_B"], dt), sds(shp["conv_C"], dt),
                 sds(shp["ssm"], jnp.float32), sds((Dd, Bslot, 1)),
                 sds((Dd, Bslot)), sds((Dd, Bslot)),
@@ -420,12 +420,12 @@ def _lower_serve(cfg, shape, mesh, layout, da, G, dp, page=128):
         pk = jax.eval_shape(lambda: encdec_decode_pack(
             cfg, pack_params(cfg, init_params(cfg, jax.random.PRNGKey(0)),
                              layout, G), layout, G))
-        NE = cc.nelems(cfg, G)
+        kv = (Dd, G) + cc.rank_shape(cfg, G)
         maxp = cc.max_pages_per_req
         Kx = G * gi.kv_local if layout == TP else cfg.num_kv_heads
         xkv = sds((Dd, Bslot, cfg.num_layers, 2, cfg.encoder_seq, Kx,
                    cfg.dh), cfg.param_dtype)
-        args = (pk, sds((Dd, G, NE), cfg.param_dtype), xkv,
+        args = (pk, sds(kv, cfg.param_dtype), xkv,
                 sds((Dd, Bslot, 1)), sds((Dd, Bslot)), sds((Dd, Bslot)),
                 sds((Dd, Bslot, maxp)), sds((2,), jnp.uint32))
         return step.lower(*args)
@@ -442,9 +442,9 @@ def _lower_step(cfg, step, mesh, layout, cc, Bslot, Sq, Dd, G):
              if layout == TPEP else None)
     pk = jax.eval_shape(lambda p: build_decode_pack(cfg, p, layout, G),
                         _params_like(cfg, layout, G, G_exp))
-    NE = cc.nelems(cfg, G)
+    kv = (Dd, G) + cc.rank_shape(cfg, G)
     maxp = cc.max_pages_per_req
-    args = (pk, sds((Dd, G, NE), cfg.param_dtype),
+    args = (pk, sds(kv, cfg.param_dtype),
             sds((Dd, Bslot, Sq)), sds((Dd, Bslot)), sds((Dd, Bslot)),
             sds((Dd, Bslot, maxp)), sds((2,), jnp.uint32))
     return step.lower(*args)

@@ -35,31 +35,90 @@ Examples (CPU, 8 host devices):
       --mesh 1x4 --http-port 8000
 """
 import os
+from pathlib import Path
+
 if "REPRO_HOST_DEVICES" in os.environ:
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                + os.environ["REPRO_HOST_DEVICES"])
+
+REPO = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache(root: str | os.PathLike = REPO) -> str:
+    """Keep JAX's persistent compile cache where JAX_COMPILATION_CACHE_DIR
+    says (JAX reads the variable itself), else at the fixed, git-ignored
+    `<root>/.jax_cache`. Returns the directory in use."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(root) / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def model_config(arch: str, *, reduced: bool = False,
+                 layers: int | None = None):
+    """The served configuration. `reduced` swaps in the tiny float32 CPU
+    cut; `layers` cuts whole layers only — widths and dtypes stay as
+    published."""
+    from repro.configs import get_config
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced()
+    if layers:
+        cfg = cfg.replace(num_layers=min(layers, cfg.num_layers))
+    return cfg
+
+
+def build_engine(cfg, *, mesh: str = "1x4", layouts: str = "tp,ep",
+                 policy: str = "interactive", t_high: int | None = None,
+                 cache=None, **ecfg):
+    """Mesh + layouts + switch policy + cache -> MoebiusEngine. The one
+    construction path of the launcher and `chip_smoke.py`; `ecfg` holds
+    the remaining EngineConfig fields."""
+    from repro.core.layouts import EP, TP, get_layout
+    from repro.core.policy import PolicyConfig, calibrate_threshold
+    from repro.launch.mesh import make_mesh
+    from repro.serving.engine import EngineConfig, MoebiusEngine
+    from repro.serving.kvcache import CacheConfig
+
+    dd, g = (int(x) for x in mesh.split("x"))
+    specs = tuple(get_layout(l.strip()) for l in layouts.split(",")
+                  if l.strip())
+    th = t_high or max(8, calibrate_threshold(cfg, g))
+    if policy == "interactive":
+        pol, start = PolicyConfig.interactive(th), TP
+    elif policy == "rollout":
+        pol, start = PolicyConfig.rollout(th), EP
+    else:
+        pol = PolicyConfig(t_high=10**9, t_low=-1, cooldown_s=10**9)
+        start = get_layout(policy.removeprefix("static-"))
+    cc = cache or CacheConfig(page_size=16, pages_ep=256,
+                              max_pages_per_req=64)
+    ecfg.setdefault("ladder", (g, 4 * g, 16 * g))
+    return MoebiusEngine(cfg, make_mesh((dd, g), ("data", "model")), cc,
+                         ecfg=EngineConfig(start_layout=start, layouts=specs,
+                                           policy=pol, **ecfg))
 
 
 def main():
     import argparse
     import json
 
-    import jax
-
-    from repro.configs import get_config
-    from repro.core.layouts import EP, TP, get_layout
-    from repro.core.policy import PolicyConfig, calibrate_threshold
-    from repro.launch.mesh import make_mesh
-    from repro.serving.engine import EngineConfig, MoebiusEngine
     from repro.serving.frontend import AsyncEngine
-    from repro.serving.kvcache import CacheConfig
     from repro.serving.workloads import (BurstySpec, QosMixSpec, RolloutSpec,
                                          bursty_trace, qos_mixed_trace,
                                          replay, rollout_batch)
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the tiny float32 CPU cut of --arch; "
+                         "--no-reduced serves its published widths")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut depth to this many whole layers (widths and "
+                         "dtypes unchanged)")
     ap.add_argument("--mesh", default="1x4")
     ap.add_argument("--workload", default="rollout",
                     choices=["rollout", "bursty", "qosmix"])
@@ -113,48 +172,27 @@ def main():
                          "instead of replaying a trace (POST /v1/generate"
                          ", GET /v1/metrics; 0 = pick a free port)")
     ap.add_argument("--attn-backend", default=None,
-                    choices=["ref", "kernel", "pallas", "interpret"],
-                    help="paged-attention backend (default: auto — kernel "
+                    choices=["ref", "pallas", "interpret"],
+                    help="paged-attention backend (default: auto — pallas "
                          "on TPU, ref elsewhere)")
     ap.add_argument("--moe-backend", default=None,
-                    choices=["ref", "kernel", "pallas", "interpret"],
+                    choices=["ref", "pallas", "interpret"],
                     help="grouped MoE GEMM backend (same auto policy)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--max-steps", type=int, default=5000)
     args = ap.parse_args()
 
-    dd, g = (int(x) for x in args.mesh.split("x"))
-    mesh = make_mesh((dd, g), ("data", "model"))
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    layouts = tuple(get_layout(l.strip())
-                    for l in args.layouts.split(",") if l.strip())
-    th = args.t_high or max(8, calibrate_threshold(cfg, g))
-    if args.policy == "interactive":
-        pol = PolicyConfig.interactive(th)
-        start = TP
-    elif args.policy == "rollout":
-        pol = PolicyConfig.rollout(th)
-        start = EP
-    else:
-        pol = PolicyConfig(t_high=10**9, t_low=-1, cooldown_s=10**9)
-        start = get_layout(args.policy.removeprefix("static-"))
-    cc = CacheConfig(page_size=16, pages_ep=256, max_pages_per_req=64)
-    eng = MoebiusEngine(cfg, mesh, cc,
-                        ecfg=EngineConfig(start_layout=start,
-                                          layouts=layouts,
-                                          ladder=(g, 4 * g, 16 * g),
-                                          prefill_chunk=args.prefill_chunk,
-                                          token_budget=args.token_budget,
-                                          mixed_batch=not args.two_phase,
-                                          policy=pol,
-                                          decode_steps=args.decode_steps,
-                                          prefix_cache=not args.no_prefix_cache,
-                                          qos=args.qos,
-                                          attn_backend=args.attn_backend,
-                                          moe_backend=args.moe_backend,
-                                          seed=args.seed))
+    use_compile_cache()
+    cfg = model_config(args.arch, reduced=args.reduced, layers=args.layers)
+    eng = build_engine(cfg, mesh=args.mesh, layouts=args.layouts,
+                       policy=args.policy, t_high=args.t_high,
+                       prefill_chunk=args.prefill_chunk,
+                       token_budget=args.token_budget,
+                       mixed_batch=not args.two_phase,
+                       decode_steps=args.decode_steps,
+                       prefix_cache=not args.no_prefix_cache,
+                       qos=args.qos, attn_backend=args.attn_backend,
+                       moe_backend=args.moe_backend, seed=args.seed)
     if args.http_port is not None:
         # live HTTP/SSE mode: no trace — requests arrive over the wire
         import asyncio

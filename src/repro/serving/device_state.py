@@ -11,7 +11,7 @@ from host metadata every step (the `_decode_once` path's per-token
 The state is functional: `build_decode_loop` returns the advanced
 tokens/positions/budgets arrays and the engine swaps them in via
 `advance()`. Delta updates are chunked to a FIXED width (`SCATTER_W`, the
-same fixed-plan-width idiom as the switch executor's DELTA_PMAX): padding
+same fixed-plan-width idiom as the switch executor's KV_BLOCK): padding
 rows carry an out-of-bounds slot index, which JAX scatter semantics drop
 (`mode="drop"`), so there are exactly two scatter executables per rung —
 a burst of joins can never hit a compile inside the serving loop.

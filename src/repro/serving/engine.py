@@ -76,10 +76,9 @@ class EngineConfig:
     # switch. N == 1 keeps the classic per-token host loop.
     decode_steps: int = 1
     # kernel backends for the step fns (kernels/dispatch.resolve_backend):
-    # None = auto (kernel on TPU, ref elsewhere; REPRO_FORCE_REF=1 forces
-    # ref), "ref" = pure-jnp oracle, "kernel"/"pallas" = the Pallas kernel
-    # (interpret mode off-TPU — a debugging path), "interpret" = interpret
-    # mode everywhere. attn_backend picks paged attention; moe_backend picks
+    # None = auto (pallas on TPU, ref elsewhere), "ref" = pure-jnp oracle,
+    # "pallas" = the compiled kernel (TPU only), "interpret" = Pallas
+    # interpret mode. attn_backend picks paged attention; moe_backend picks
     # the grouped expert GEMM inside _ffn (DESIGN.md §14).
     attn_backend: str | None = None
     moe_backend: str | None = None
@@ -87,16 +86,20 @@ class EngineConfig:
     # gather/scatter + expert_reshard permutes inside the jitted movers
     # and the cross-world staged gathers); same resolution rules
     switch_backend: str | None = None
-    # opt-in: warmup() also dry-runs the chunked switch movers for every
-    # active->other same-world layout pair, so the FIRST live switch
-    # selects compiled executables instead of compiling inside its window
-    # (paper §4.4). Off by default — tests and non-switching servers
-    # shouldn't pay the mover compiles.
+    # opt-in: warmup() also makes an idle chunked round trip to every
+    # other same-world resident layout, compiling and running its step fns
+    # and the movers both ways, so no live switch — nor the serving after
+    # it — compiles anything (paper §4.4). Off by default — tests and
+    # non-switching servers shouldn't pay the mover compiles.
     warm_switches: bool = False
     # share page-aligned prompt prefixes across requests (refcounted pages
     # + CoW; DESIGN.md §6). Greedy outputs are byte-identical with the
     # cache on or off — it only removes redundant prefill compute/bytes.
     prefix_cache: bool = True
+    # keep the fp32 logits behind every sampled token in Executor.logits,
+    # keyed (rid, position): lets a parity check compare two runs at the
+    # first step where their greedy tokens part (one host fetch per step)
+    record_logits: bool = False
     # trace-replay idle fast-forward: when every pending request is still
     # in the future and nothing is live, jump the engine clock to the next
     # arrival instead of burning empty step() iterations (quiet-period
@@ -143,7 +146,6 @@ class MoebiusEngine:
     delegating properties below."""
 
     def __init__(self, cfg: ModelConfig, mesh, cc: CacheConfig,
-                 params_global: dict | None = None,
                  ecfg: EngineConfig | None = None,
                  data_axis: str = "data", model_axis: str = "model"):
         self.cfg, self.mesh, self.cc = cfg, mesh, cc
@@ -172,7 +174,7 @@ class MoebiusEngine:
 
         # --- the three layers ---
         self.ex = Executor(cfg, mesh, cc, self.ecfg, self.layouts, start,
-                           params_global=params_global, metrics=self.metrics,
+                           metrics=self.metrics,
                            data_axis=data_axis, model_axis=model_axis)
         # allocators live at the START layout's world (a sized start like
         # "tp@4" begins life on the sub-mesh)
@@ -294,7 +296,32 @@ class MoebiusEngine:
         self.sched.submit(req)
 
     def warmup(self, layouts=None) -> None:
+        """Compile (and run on zeros) the active layout's step fns. With
+        warm_switches, also switch idle to each other same-world layout and
+        back: its step fns then run once in their own expert layout, and
+        the movers of both directions are compiled before traffic."""
         self.ex.warmup(layouts)
+        if not (self.ecfg.warm_switches and self.ecfg.chunk_layers > 0):
+            return
+        home = self.active
+        for lo in (self.layouts if layouts is None else layouts):
+            if lo is home or self.ex._is_cross_world(lo):
+                continue
+            self._idle_switch(lo)
+            self.ex.warmup((lo, home))    # lo's steps + lo->home movers
+            self._idle_switch(home)
+
+    def _idle_switch(self, target: LayoutSpec) -> None:
+        """A chunked switch with no live request, recorded nowhere."""
+        assert not self.sched.live(), "idle switch with live requests"
+        sess = self.ex.switch_start(target, [], self.ecfg.chunk_layers,
+                                    self.sched.alloc, self.sched.prefix)
+        while not sess.done:
+            self.ex.switch_advance()
+        alloc, caches, _ = self.ex.switch_commit(target, [])
+        self.sched.alloc, self.sched.prefix = alloc, caches
+        self.sched.set_layout(target)
+        self.coord.switch_completed(self.active)
 
     def requeue_for_reprefill(self, r: Request) -> None:
         self.sched.requeue_for_reprefill(r)

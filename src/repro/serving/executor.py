@@ -24,12 +24,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.layouts import (LayoutSpec, get_layout, group_info,
                                 pack_params, world_of)
 from repro.core.residency import ResidentRuntime
 from repro.core.switch_exec import CrossWorldSwitcher, SwitchExecutor
 from repro.models.common import ModelConfig
+from repro.models.moe import unpack_experts, unpack_w13
 from repro.models.registry import init_params
 from repro.serving.device_state import DeviceDecodeState
 from repro.serving.kvcache import COPY_W, CacheConfig, make_copy_pages
@@ -37,7 +40,54 @@ from repro.serving.metrics import ServeMetrics
 from repro.serving.request import Request
 from repro.serving.scheduler import MixedPlan, MixedRow
 from repro.serving.steps import (build_decode_loop, build_decode_pack,
-                                 build_mixed_step)
+                                 build_mixed_step, decode_pack_specs)
+
+
+def build_weight_init(cfg: ModelConfig, mesh, specs, active: LayoutSpec,
+                      Dd: int, *, data_axis: str = "data",
+                      model_axis: str = "model"):
+    """The start-up weight program for the resident layouts `specs` that
+    share `mesh`: `init(key) -> (packs, experts)` generates the global
+    parameters from the seed and packs them into each layout's stored
+    form, and `shardings` are the serve step's NamedShardings for both.
+    `experts` is the `active` layout's expert store (None when `active`
+    lives on another mesh); inactive layouts' experts are never built, so
+    under `jax.jit(init, out_shardings=shardings)` the only device copy of
+    the experts is the sharded store itself."""
+    w = mesh.shape[model_axis]
+    ep_axes = (data_axis, model_axis)
+
+    def init(key):
+        params = init_params(cfg, key)
+        packs, experts = {}, None
+        for spec in specs:
+            stored = pack_params(cfg, params, spec, w,
+                                 expert_G=spec.expert_group(w, Dd * w))
+            pk = build_decode_pack(cfg, stored, spec, w)
+            if cfg.is_moe:
+                moe = dict(pk["layers"]["moe"])
+                ex = {"w13": moe.pop("w13"), "w2": moe.pop("w2")}
+                pk["layers"] = {**pk["layers"], "moe": moe}
+                if spec == active:
+                    experts = ex
+            packs[spec] = pk
+        return packs, experts
+
+    shapes, _ = jax.eval_shape(init, jax.random.PRNGKey(0))
+    packs, ex_sh = {}, None
+    for spec in specs:
+        ps = decode_pack_specs(cfg, shapes[spec], spec, model_axis,
+                               ep_axes=ep_axes)
+        sh = jax.tree.map(lambda p: NamedSharding(mesh, p), ps,
+                          is_leaf=lambda x: isinstance(x, P))
+        if cfg.is_moe:
+            moe = dict(sh["layers"]["moe"])
+            ex = {"w13": moe.pop("w13"), "w2": moe.pop("w2")}
+            sh["layers"] = {**sh["layers"], "moe": moe}
+            if spec == active:
+                ex_sh = ex
+        packs[spec] = sh
+    return init, (packs, ex_sh)
 
 
 class Executor:
@@ -45,7 +95,6 @@ class Executor:
 
     def __init__(self, cfg: ModelConfig, mesh, cc: CacheConfig, ecfg,
                  layouts: tuple[LayoutSpec, ...], active: LayoutSpec,
-                 params_global: dict | None = None,
                  metrics: ServeMetrics | None = None,
                  data_axis: str = "data", model_axis: str = "model"):
         self.cfg, self.mesh, self.cc, self.ecfg = cfg, mesh, cc, ecfg
@@ -72,40 +121,34 @@ class Executor:
         # full-mesh layouts split each prefill chunk 1/w per rank
         q = max(s.prefill_quantum(world_of(s, self.G)) for s in layouts)
         self.prefill_chunk = -(-ecfg.prefill_chunk // q) * q
-        if params_global is None:
-            params_global = init_params(cfg, jax.random.PRNGKey(ecfg.seed))
-
-        # canonical unpacked experts kept on host: cross-world switches
-        # re-pack from this copy instead of resharding device buffers
-        # (experts are read-only in serving, so the copy is never stale)
-        self._moe_host = None
-        if cfg.is_moe:
-            moe_g = params_global["layers"]["moe"]
-            self._moe_host = {"w13": np.asarray(moe_g["w13"]),
-                              "w2": np.asarray(moe_g["w2"])}
-
-        # --- N-resident control plane; single-copy expert data plane ---
+        # --- N-resident control plane; single-copy expert data plane,
+        # generated from the seed straight into the stored forms at the
+        # shardings the serve steps take (one jit per world) ---
         self.packs: dict[str, dict] = {}
-        self._expert_store: dict[str, dict] = {}   # only active layout kept
-        for spec in layouts:
-            w = world_of(spec, self.G)
-            stored = pack_params(cfg, params_global, spec, w,
-                                 expert_G=spec.expert_group(w, self.Dd * w))
-            pk = build_decode_pack(cfg, stored, spec, w)
-            if cfg.is_moe:
-                moe = pk["layers"]["moe"]
-                self._expert_store[spec] = {
-                    "w13": moe.pop("w13"), "w2": moe.pop("w2")}
-            self.packs[spec] = pk
-        if cfg.is_moe:
-            # free the inactive layouts' expert copies (single resident copy)
-            self._experts = self._expert_store.pop(self.active)
-            del self._expert_store
+        self._experts = None
+        for w in sorted({world_of(s, self.G) for s in layouts}):
+            specs = tuple(s for s in layouts if world_of(s, self.G) == w)
+            init, shardings = build_weight_init(
+                cfg, self.meshes[w], specs, active, self.Dd,
+                data_axis=data_axis, model_axis=model_axis)
+            packs, experts = jax.jit(init, out_shardings=shardings)(
+                jax.random.PRNGKey(ecfg.seed))
+            self.packs.update(packs)
+            if experts is not None:
+                self._experts = experts
+        # canonical unpacked experts kept on host only when a cross-world
+        # switch is possible: it re-packs from this copy instead of
+        # resharding device buffers (experts are read-only in serving, so
+        # the copy is never stale)
+        self._moe_host = None
+        if cfg.is_moe and len({world_of(s, self.G) for s in layouts}) > 1:
+            self._moe_host = self._experts_to_host()
 
         # --- unified KV buffer (committed to its serve-step sharding up
         # front: a lazily-committed buffer would change sharding signature
         # after the first dispatch and recompile every warmed executable) ---
-        self.NE = cc.nelems(cfg, self.G)   # per-rank size, world-independent
+        # per-rank stored shape, world-independent (cc.nelems ignores G)
+        self.kv_rank_shape = cc.rank_shape(cfg, self.G)
         self.kv_flat = self._zero_kv(world_of(active, self.G))
         self._copy_fns: dict = {}          # CoW page copier, per layout
 
@@ -130,6 +173,9 @@ class Executor:
             model_axis=model_axis, data_axis=data_axis,
             backend=ecfg.switch_backend)
         self._key = jax.random.PRNGKey(ecfg.seed + 1)
+        # (rid, position) -> fp32 logits of the sampled token, filled only
+        # under EngineConfig.record_logits (parity checks across layouts)
+        self.logits: dict[tuple[int, int], np.ndarray] = {}
         # completion sink for fused-pipeline retirements (the engine wires
         # this to Scheduler.finish_request)
         self.on_finish = lambda r: None
@@ -150,12 +196,26 @@ class Executor:
 
     def _zero_kv(self, w: int):
         """Fresh zero KV buffer shaped/sharded for world `w` (per-rank
-        nelems is world-independent, so only the rank axis changes)."""
-        return jax.device_put(
-            jnp.zeros((self.Dd, w, self.NE), self.cfg.param_dtype),
-            jax.sharding.NamedSharding(
-                self.meshes[w],
-                jax.sharding.PartitionSpec(self.da, self.m)))
+        nelems is world-independent, so only the rank axis changes),
+        created on its shards."""
+        shape = (self.Dd, w) + self.kv_rank_shape
+        sh = NamedSharding(self.meshes[w], P(self.da, self.m))
+        return jax.jit(lambda: jnp.zeros(shape, self.cfg.param_dtype),
+                       out_shardings=sh)()
+
+    def _experts_to_host(self) -> dict:
+        """Canonical (L, E, ...) numpy copy of the active expert store,
+        fetched one layer at a time so device memory never holds a second
+        full copy."""
+        w = self._world(self.active)
+        lay = self.active.expert_layout(self.cfg, w, self.Dd * w)
+        E = self.cfg.num_experts
+        unpack = {"w13": lambda x: unpack_w13(x, lay, E),
+                  "w2": lambda x: unpack_experts(x, lay, 2, E)}
+        with jax.default_device(jax.devices("cpu")[0]):
+            return {k: np.stack([
+                np.asarray(unpack[k](jnp.asarray(np.asarray(layer))))
+                for layer in self._experts[k]]) for k in unpack}
 
     def _switcher_for(self, w: int) -> SwitchExecutor:
         sw = self._switchers.get(w)
@@ -199,7 +259,8 @@ class Executor:
                 self.cfg, self._mesh_for(layout), layout, self.cc, B, Sq=Sq,
                 temperature=self.ecfg.temperature, data_axes=(self.da,),
                 model_axis=self.m, attn_backend=self.ecfg.attn_backend,
-                moe_backend=self.ecfg.moe_backend))
+                moe_backend=self.ecfg.moe_backend,
+                return_logits=self.ecfg.record_logits))
 
     def _decode_fn(self, layout: LayoutSpec, B: int):
         return self._mixed_fn(layout, B, 1)
@@ -229,7 +290,9 @@ class Executor:
         """
         mixed = getattr(self.ecfg, "mixed_batch", True)
         for lo in (self.layouts if layouts is None else layouts):
-            self._prefill_fn(lo)
+            if not mixed:
+                # the (prefill width, chunk) shape serves two-phase only
+                self._prefill_fn(lo)
             for b in self.ladder_for(lo):
                 self._decode_fn(lo, b)
                 if mixed:
@@ -252,14 +315,17 @@ class Executor:
             if lo is not self.active:
                 continue
             pk = self._assemble_pack(lo)
-            key = jax.random.key_data(jax.random.PRNGKey(0))
+            # the live loop's per-step key derivation compiles here too
+            key = self._step_key(0)
             maxp = self.cc.max_pages_per_req
-            Bp = get_layout(lo).prefill_width(self._world(lo))
-            toks = jnp.zeros((self.Dd, Bp, self.prefill_chunk), jnp.int32)
-            z2 = jnp.zeros((self.Dd, Bp), jnp.int32)
-            bt = jnp.zeros((self.Dd, Bp, maxp), jnp.int32)
-            self._prefill_fn(lo)(pk, jnp.zeros_like(self.kv_flat),
-                                 toks, z2, z2, bt, key)
+            if not mixed:
+                Bp = get_layout(lo).prefill_width(self._world(lo))
+                toks = jnp.zeros((self.Dd, Bp, self.prefill_chunk),
+                                 jnp.int32)
+                z2 = jnp.zeros((self.Dd, Bp), jnp.int32)
+                bt = jnp.zeros((self.Dd, Bp, maxp), jnp.int32)
+                self._prefill_fn(lo)(pk, jnp.zeros_like(self.kv_flat),
+                                     toks, z2, z2, bt, key)
             for b in self.ladder_for(lo):
                 z2 = jnp.zeros((self.Dd, b), jnp.int32)
                 bt = jnp.zeros((self.Dd, b, maxp), jnp.int32)
@@ -390,10 +456,16 @@ class Executor:
             vl[d, s] = n
             bt[d, s, :len(r.pages)] = r.pages
         fn = self._mixed_fn(self.active, B, Sq)
-        nxt, self.kv_flat = fn(self._assemble_pack(self.active), self.kv_flat,
-                               jnp.asarray(toks), jnp.asarray(pos),
-                               jnp.asarray(vl), jnp.asarray(bt),
-                               self._step_key(step_i))
+        nxt, self.kv_flat, *logits = fn(
+            self._assemble_pack(self.active), self.kv_flat, jnp.asarray(toks),
+            jnp.asarray(pos), jnp.asarray(vl), jnp.asarray(bt),
+            self._step_key(step_i))
+        if logits:
+            lg = np.asarray(logits[0])
+            for row in plan.rows:
+                # keyed by the KV position of the token these logits sample
+                self.logits[(row.req.rid, row.start_pos + row.n_tokens)] = \
+                    lg[row.d, row.row, :self.cfg.vocab_size]
         if n_pref:
             self.metrics.prefill(n_pref)
         if n_dec:

@@ -4,12 +4,16 @@ The TPU analogue of the paper's unified memory manager (§4.2): each rank owns
 ONE flat element pool; the EP and TP layouts are *views* (reshapes) of the
 same bytes:
 
-  flat:    (Dd, G, NE)                      sharded P("data", "model")
+  flat:    (Dd, G, NE/lane, lane)           sharded P("data", "model")
   EP view: (Dd, G, L, 2, pages_ep, page, K,  dh)   pages per model-rank
   TP view: (Dd, G, L, 2, pages_tp, page, Kl, dh)   pages shared across the
                                                     group, head-sliced per rank
 
 pages_tp = pages_ep * K // Kl, so both views cover exactly NE elements.
+Each rank's NE elements are stored as (NE/lane, lane) rows, lane = 128
+where NE allows: a rank block shaped (1, 1, NE) would put a size-1 dim in
+the TPU's tiled minor pair, padding the pool in HBM and making every
+program that reshapes it slow to compile.
 Group token capacity: EP = G*pages_ep*page, TP = pages_tp*page =
 EP / kv_rep — the paper's KV-head-replication capacity penalty falls out of
 the byte accounting.
@@ -26,6 +30,7 @@ the jitted copy-on-write page mover. Everything is re-exported here, so
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 import numpy as np
@@ -55,6 +60,12 @@ class CacheConfig:
         L = num_kv_layers(cfg)
         return (L * 2 * self.pages_ep * self.page_size
                 * cfg.num_kv_heads * cfg.dh)
+
+    def rank_shape(self, cfg: ModelConfig, G: int) -> tuple[int, int]:
+        """Stored shape of one rank's NE elements: lane-dense rows."""
+        ne = self.nelems(cfg, G)
+        lane = math.gcd(ne, 128)
+        return (ne // lane, lane)
 
     def pages_tp(self, cfg: ModelConfig, G: int) -> int:
         gi = group_info(cfg, G)
@@ -109,7 +120,7 @@ class PageAllocator(PagePoolAllocator):
 # Device page copy (copy-on-write mover; same-view, within each pool)
 # ---------------------------------------------------------------------------
 
-# fixed pair-width per compiled copy executable (the DELTA_PMAX idiom):
+# fixed pair-width per compiled copy executable (the KV_BLOCK idiom):
 # wider CoW bursts split into COPY_W blocks, so the serving loop compiles
 # the copier exactly once per layout view
 COPY_W = 4
@@ -134,7 +145,6 @@ def make_copy_pages(cfg: ModelConfig, cc: CacheConfig, mesh, layout, *,
     spec = get_layout(layout)
     G = mesh.shape[model_axis]
     view = cc.view_shape(cfg, G, spec)
-    NE = int(np.prod(view))
 
     def body(kv_flat, src, dst, valid):
         r = lax.axis_index(model_axis)
@@ -143,7 +153,7 @@ def make_copy_pages(cfg: ModelConfig, cc: CacheConfig, mesh, layout, *,
         dp = jnp.where(valid[0][r], dst[0][r], 0)
         data = pool[:, :, sp]                              # (L,2,pmax,...)
         pool = pool.at[:, :, dp].set(data)
-        return pool.reshape(1, 1, NE)
+        return pool.reshape(kv_flat.shape)
 
     flat_spec = P(data_axis, model_axis)
     rep = P(data_axis, None, None)

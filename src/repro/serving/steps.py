@@ -365,7 +365,7 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
     a chunk. There is no separate prefill or decode step function.
 
     Global signature:
-      pack, kv_flat (Dd, G, NE), tokens (Dd, Bslot, Sq), positions (Dd, Bslot),
+      pack, kv_flat (Dd, G, *rank_shape), tokens (Dd, Bslot, Sq), positions (Dd, Bslot),
       valid_len (Dd, Bslot), block_table (Dd, Bslot, maxp), key
       -> (next_token (Dd, Bslot), kv_flat')
     `positions` = global KV position of tokens[:, :, 0] (a decode row's
@@ -393,7 +393,7 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
             m=m, lay_exp=g["lay_exp"], ep_axes=g["ep_axes"],
             attn_backend=attn_backend, moe_backend=moe_backend,
             temperature=temperature, page=g["page"], maxp=maxp, Sq=Sq)
-        out = (nxt.reshape(1, bs), new_pool.reshape(1, 1, -1))
+        out = (nxt.reshape(1, bs), new_pool.reshape(kv_flat.shape))
         if return_logits:
             head = pack["embed"] if cfg.tie_embeddings else pack["lm_head"]
             lg = (xl @ head.T.astype(xl.dtype)).astype(jnp.float32)
@@ -434,7 +434,7 @@ def build_decode_loop(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
     masked out (their KV writes land on the null page, their outputs are 0).
 
     Global signature:
-      pack, kv_flat (Dd, G, NE), tokens (Dd, B), positions (Dd, B),
+      pack, kv_flat (Dd, G, *rank_shape), tokens (Dd, B), positions (Dd, B),
       budgets (Dd, B), block_table (Dd, B, maxp), key
       -> (out_tokens (Dd, B, steps), kv_flat',
           tokens' (Dd, B), positions' (Dd, B), budgets' (Dd, B))
@@ -479,7 +479,7 @@ def build_decode_loop(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
         out0 = jnp.zeros((bs, steps), jnp.int32)
         pool, tok, pos, bud, out = lax.fori_loop(
             0, steps, substep, (pool, tokens, positions, budgets, out0))
-        return (out.reshape(1, bs, steps), pool.reshape(1, 1, -1),
+        return (out.reshape(1, bs, steps), pool.reshape(kv_flat.shape),
                 tok.reshape(1, bs), pos.reshape(1, bs), bud.reshape(1, bs))
 
     pspecs = _pack_specs_for(cfg, layout, g["G"], g["G_exp"], m, g["ep_axes"])

@@ -287,7 +287,7 @@ def build_hybrid_serve_step(cfg: ModelConfig, mesh, layout: str,
         ncC = jnp.concatenate([ns[2] for ns in new_states], 0)
         nst = jnp.concatenate([ns[3] for ns in new_states], 0)
         back = lambda a, proto: jnp.moveaxis(a, 0, 1).reshape(proto.shape)
-        return (nxt.reshape(1, bs), jnp.stack(new_pool, 0).reshape(1, 1, -1),
+        return (nxt.reshape(1, bs), jnp.stack(new_pool, 0).reshape(kv_flat.shape),
                 back(ncx, conv_x), back(ncB, conv_B), back(ncC, conv_C),
                 back(nst, ssm_st))
 
@@ -437,7 +437,7 @@ def build_encdec_serve_step(cfg: ModelConfig, mesh, layout: str,
         last = jnp.clip(valid - 1, 0, Sq - 1)
         xl = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
         nxt = _sample(cfg, pack, xl, layout, m, key, temperature, 0)
-        return nxt.reshape(1, bs), new_pool.reshape(1, 1, -1)
+        return nxt.reshape(1, bs), new_pool.reshape(kv_flat.shape)
 
     norm = lambda: jax.tree.map(lambda _: P(), {"scale": 0, "bias": 0}) \
         if cfg.norm_type == "layernorm" else {"scale": P()}

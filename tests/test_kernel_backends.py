@@ -2,12 +2,12 @@
 
 One `resolve_backend` governs all four kernel packages; these tests pin
 
-  * the resolution matrix (explicit choice x REPRO_FORCE_REF x platform),
+  * the resolution matrix (explicit choice x platform),
   * ref vs pallas-interpret parity THROUGH the ops.py dispatchers for all
     four kernels, over hypothesis-drawn shapes: GQA ratios, Sq > 1 mixed
     rows, sliding windows, ragged per-expert token counts including
     zero-token experts, and non-divisible page counts,
-  * the serving integration: `moe_backend="kernel"` decode tokens match
+  * the serving integration: `moe_backend="interpret"` decode tokens match
     the einsum path exactly (fp32) including across a live tp->ep chunked
     switch, and the chunked switch staging actually routes through the
     fused kv_pack / expert_reshard ops (dispatch trace counters).
@@ -38,27 +38,28 @@ def mesh11():
 # ---------------------------------------------------------------------------
 def test_resolve_backend_matrix():
     rb = dispatch.resolve_backend
-    # auto: force-ref env wins; else kernel on TPU, ref elsewhere
-    assert rb(None, env="1", platform="tpu") == "ref"
-    assert rb(None, env=None, platform="tpu") == "pallas"
-    assert rb(None, env=None, platform="cpu") == "ref"
-    assert rb(None, env="0", platform="cpu") == "ref"
-    # explicit ref is always ref
-    assert rb("ref", env=None, platform="tpu") == "ref"
-    # kernel/pallas: real kernel on TPU, interpret-mode elsewhere
-    for req in ("kernel", "pallas"):
-        assert rb(req, env=None, platform="tpu") == "pallas"
-        assert rb(req, env=None, platform="cpu") == "interpret"
-    # interpret mode everywhere when asked
-    assert rb("interpret", env=None, platform="tpu") == "interpret"
+    # auto: kernel on TPU, ref elsewhere
+    assert rb(None, platform="tpu") == "pallas"
+    assert rb(None, platform="cpu") == "ref"
+    # explicit ref / interpret are honoured everywhere
+    for plat in ("tpu", "cpu"):
+        assert rb("ref", platform=plat) == "ref"
+        assert rb("interpret", platform=plat) == "interpret"
+    # the compiled kernel exists only on the chip: no silent substitute
+    assert rb("pallas", platform="tpu") == "pallas"
     with pytest.raises(ValueError):
-        rb("mystery", env=None, platform="cpu")
+        rb("pallas", platform="cpu")
+    for bad in ("mystery", "kernel"):
+        with pytest.raises(ValueError):
+            rb(bad, platform="cpu")
 
 
 def test_force_ref_env_unifies_all_dispatchers(monkeypatch):
-    """REPRO_FORCE_REF=1 forces the ref backend in every kernel package
-    (the auto path reads the env through one shared resolver)."""
+    """Every kernel package resolves auto through the one shared resolver
+    (ref off the chip), and the retired REPRO_FORCE_REF switch no longer
+    changes what any dispatcher picks."""
     monkeypatch.setenv("REPRO_FORCE_REF", "1")
+    assert dispatch.resolve_backend(None, platform="tpu") == "pallas"
     dispatch.reset_counts()
     from repro.kernels.expert_reshard.ops import pack_peer_chunks
     from repro.kernels.kv_pack.ops import gather_pages
@@ -231,12 +232,12 @@ def _serve(cfg, mesh, *, moe_backend=None, switch_backend=None,
 
 
 def test_moe_backend_decode_parity_across_switch(tiny_moe, mesh11):
-    """moe_backend="kernel" greedy decode == einsum path, token for token,
+    """moe_backend="interpret" greedy decode == einsum path, token for token,
     with and without a live tp->ep chunked switch in the middle (fp32
     compute: byte-identical per DESIGN.md §14)."""
     for sw in (None, "ep"):
         ref = _serve(tiny_moe, mesh11, moe_backend="ref", switch_to=sw)
-        ker = _serve(tiny_moe, mesh11, moe_backend="kernel", switch_to=sw)
+        ker = _serve(tiny_moe, mesh11, moe_backend="interpret", switch_to=sw)
         assert ref == ker, f"kernel MoE diverged (switch={sw})"
 
 

@@ -17,6 +17,17 @@ def test_view_byte_parity(K, G):
     ep = cc.view_shape(cfg, G, EP)
     tp = cc.view_shape(cfg, G, TP)
     assert int(np.prod(ep)) == int(np.prod(tp)) == cc.nelems(cfg, G)
+    assert int(np.prod(cc.rank_shape(cfg, G))) == cc.nelems(cfg, G)
+
+
+@pytest.mark.parametrize("G", [1, 4])
+def test_rank_shape_is_lane_dense(G):
+    """A rank's KV block is stored as (NE/128, 128) rows: no size-1 dim in
+    the TPU's tiled minor pair, which would pad the pool in HBM."""
+    cfg = get_config("mixtral-8x7b").replace(num_layers=4)
+    cc = CacheConfig(page_size=16, pages_ep=288)
+    rows, lane = cc.rank_shape(cfg, G)
+    assert lane == 128 and rows * lane == cc.nelems(cfg, G)
 
 
 @pytest.mark.parametrize("K,G,expected_ratio", [(4, 8, 2), (2, 8, 4),

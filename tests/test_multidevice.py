@@ -656,7 +656,7 @@ print("OK")
 
 
 def test_moe_backend_parity_across_live_switch():
-    """moe_backend="kernel" (interpret off-TPU) must reproduce the einsum
+    """moe_backend="interpret" must reproduce the einsum
     decode path token-for-token on the real (2, 4) mesh, including across
     a live tp->ep chunked switch (DESIGN.md §14 acceptance)."""
     run_multidevice(COMMON + """
@@ -686,7 +686,7 @@ def run(backend, switch_at=None):
     return {r.rid: r.output for r in eng.finished}
 for at in (None, 4):
     ref = run("ref", at)
-    ker = run("kernel", at)
+    ker = run("interpret", at)
     assert ker == ref, f"kernel MoE diverged on mesh (switch_at={at})"
 print("OK")
 """, timeout=1200)
