@@ -1,0 +1,8 @@
+"""Kernel moe_grouped_matmul: roofline bound of its useful work over its
+device time (%).
+"""
+from benchlib import readers
+
+
+def read(run):
+    return readers.moe_gemm_roofline(run)

@@ -1,0 +1,8 @@
+"""Kernel paged_attention: roofline bound of its useful work over its
+device time (%).
+"""
+from benchlib import readers
+
+
+def read(run):
+    return readers.paged_attn_roofline(run)
