@@ -11,7 +11,6 @@ AND the fused decode loop, whose key carries the fused step count:
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 
 
@@ -19,18 +18,12 @@ from dataclasses import dataclass, field
 class ResidentRuntime:
     # key tuple (layout, kind, *geometry) -> compiled/jitted step fn
     executables: dict = field(default_factory=dict)
-    build_times: dict = field(default_factory=dict)
     ladder: tuple = (4, 8, 16, 32, 64, 128, 256)
 
     def get_or_build(self, key: tuple, builder):
-        """Resident lookup by full key tuple; builds (and records the build
-        time) on first use. The engine routes every step-fn cache through
-        here so warmup, switch, and steady state share one registry."""
+        """Resident lookup by full key tuple; builds on first use. The
+        engine routes every step-fn cache through here so warmup, switch,
+        and steady state share one registry."""
         if key not in self.executables:
-            t0 = time.perf_counter()
             self.executables[key] = builder()
-            self.build_times[key] = time.perf_counter() - t0
         return self.executables[key]
-
-    def total_build_time(self) -> float:
-        return sum(self.build_times.values())

@@ -61,6 +61,7 @@ from repro.models.common import ModelConfig
 from repro.models.moe import make_expert_layout
 from repro.serving.kvcache import (CacheConfig, PageAllocator, PrefixCache,
                                    num_kv_layers)
+from repro.tracing import span
 
 
 def _pow2_pad(n: int, lo: int = 8) -> int:
@@ -276,39 +277,42 @@ class SwitchExecutor:
         alloc', caches', stats); request metadata is rewritten in place."""
         src, dst = get_layout(src), get_layout(dst)
         t0 = time.perf_counter()
-        (sp, dp, vm), pmax, _, new_alloc, kv_dir, cache_moves = self._plan(
-            src, dst, live, mutate=True, cur_alloc=cur_alloc, caches=caches)
+        with span("switch.plan"):
+            (sp, dp, vm), pmax, _, new_alloc, kv_dir, cache_moves = \
+                self._plan(src, dst, live, mutate=True, cur_alloc=cur_alloc,
+                           caches=caches)
         t_plan = time.perf_counter() - t0
 
-        t1 = time.perf_counter()
-        if self.cfg.is_moe:
-            kind, fn = self.reshard_fn(src, dst, experts)
-            if kind == "direct":
-                w13, w2 = fn(experts["w13"], experts["w2"])
-                experts = {"w13": w13, "w2": w2}
-            else:
-                out = fn(experts)
-                experts = {"w13": out["w13"], "w2": out["w2"]}
-            jax.block_until_ready(experts["w13"])
-        t_w = time.perf_counter() - t1
+        with span("switch.commit"):
+            t1 = time.perf_counter()
+            if self.cfg.is_moe:
+                kind, fn = self.reshard_fn(src, dst, experts)
+                if kind == "direct":
+                    w13, w2 = fn(experts["w13"], experts["w2"])
+                    experts = {"w13": w13, "w2": w2}
+                else:
+                    out = fn(experts)
+                    experts = {"w13": out["w13"], "w2": out["w2"]}
+                jax.block_until_ready(experts["w13"])
+            t_w = time.perf_counter() - t1
 
-        t2 = time.perf_counter()
-        if self.Lk > 0 and kv_dir is not None:
-            mfn = self.migrate_fn(kv_dir, pmax)
-            kv_flat = mfn(kv_flat, jnp.asarray(sp), jnp.asarray(dp),
-                          jnp.asarray(vm))
-            jax.block_until_ready(kv_flat)
-        t_kv = time.perf_counter() - t2
+            t2 = time.perf_counter()
+            if self.Lk > 0 and kv_dir is not None:
+                mfn = self.migrate_fn(kv_dir, pmax)
+                kv_flat = mfn(kv_flat, jnp.asarray(sp), jnp.asarray(dp),
+                              jnp.asarray(vm))
+                jax.block_until_ready(kv_flat)
+            t_kv = time.perf_counter() - t2
 
-        new_caches = caches
-        if caches is not None and kv_dir is not None:
-            new_caches = [PrefixCache.rebuild(new_alloc[d], cache_moves[d])
-                          for d in range(self.Dd)]
-        total = time.perf_counter() - t0
-        stats = SwitchStats(direction=f"{src}_to_{dst}", total_s=total,
-                            pause_s=total, plan_s=t_plan, weights_s=t_w,
-                            kv_s=t_kv, kv_pages=int(vm.sum()), chunks=1,
-                            live_requests=len(live))
+            new_caches = caches
+            if caches is not None and kv_dir is not None:
+                new_caches = [PrefixCache.rebuild(new_alloc[d], cache_moves[d])
+                              for d in range(self.Dd)]
+            total = time.perf_counter() - t0
+            stats = SwitchStats(direction=f"{src}_to_{dst}", total_s=total,
+                                pause_s=total, plan_s=t_plan, weights_s=t_w,
+                                kv_s=t_kv, kv_pages=int(vm.sum()), chunks=1,
+                                live_requests=len(live))
         return experts, kv_flat, new_alloc, new_caches, stats
 
     # ------------------------------------------------------------------
@@ -331,38 +335,40 @@ class SwitchExecutor:
         Source buffers and request metadata stay live for overlap decode."""
         assert self.session is None, "switch already in progress"
         src, dst = get_layout(src), get_layout(dst)
-        t0 = time.perf_counter()
-        plan_arrays, pmax, assignments, new_alloc, kv_dir, cache_moves = \
-            self._plan(src, dst, live, mutate=False, cur_alloc=cur_alloc,
-                       caches=caches)
-        experts_dst = None
-        if self.cfg.is_moe:
-            src_lay, dst_lay = pair_expert_layouts(self.cfg, src, dst,
-                                                   self.G, self.chips)
-            sds = expert_pair_dst_struct(self.cfg, src_lay, dst_lay, experts)
-            dst_ax = dst.expert_axes((self.da,), self.m)
-            experts_dst = {
-                k: self._zeros(s.shape, s.dtype,
-                               (None, dst_ax, None, None, None))
-                for k, s in sds.items()}
-        kv_dst = None
-        if self.Lk > 0 and kv_dir is not None:
-            kv_dst = self._zeros(kv_flat.shape, kv_flat.dtype,
-                                 (self.da, self.m))
-        kv_pages = int(plan_arrays[2].sum())
-        self.session = SwitchSession(
-            src=src, dst=dst, direction=f"{src}_to_{dst}", kv_dir=kv_dir,
-            t_start=t0,
-            plan_blocks=[tuple(jnp.asarray(a[..., b:b + KV_BLOCK])
-                               for a in plan_arrays)
-                         for b in range(0, pmax, KV_BLOCK)],
-            assignments=assignments,
-            new_alloc=new_alloc, chunks=self._layer_chunks(chunk_layers),
-            experts_dst=experts_dst, kv_dst=kv_dst,
-            kv_pages=kv_pages, live_requests=len(live),
-            plan_pause_s=time.perf_counter() - t0,
-            cache_moves=cache_moves, caches=caches)
-        return self.session
+        with span("switch.plan"):
+            t0 = time.perf_counter()
+            plan_arrays, pmax, assignments, new_alloc, kv_dir, cache_moves = \
+                self._plan(src, dst, live, mutate=False, cur_alloc=cur_alloc,
+                           caches=caches)
+            experts_dst = None
+            if self.cfg.is_moe:
+                src_lay, dst_lay = pair_expert_layouts(self.cfg, src, dst,
+                                                       self.G, self.chips)
+                sds = expert_pair_dst_struct(self.cfg, src_lay, dst_lay,
+                                             experts)
+                dst_ax = dst.expert_axes((self.da,), self.m)
+                experts_dst = {
+                    k: self._zeros(s.shape, s.dtype,
+                                   (None, dst_ax, None, None, None))
+                    for k, s in sds.items()}
+            kv_dst = None
+            if self.Lk > 0 and kv_dir is not None:
+                kv_dst = self._zeros(kv_flat.shape, kv_flat.dtype,
+                                     (self.da, self.m))
+            kv_pages = int(plan_arrays[2].sum())
+            self.session = SwitchSession(
+                src=src, dst=dst, direction=f"{src}_to_{dst}", kv_dir=kv_dir,
+                t_start=t0,
+                plan_blocks=[tuple(jnp.asarray(a[..., b:b + KV_BLOCK])
+                                   for a in plan_arrays)
+                             for b in range(0, pmax, KV_BLOCK)],
+                assignments=assignments,
+                new_alloc=new_alloc, chunks=self._layer_chunks(chunk_layers),
+                experts_dst=experts_dst, kv_dst=kv_dst,
+                kv_pages=kv_pages, live_requests=len(live),
+                plan_pause_s=time.perf_counter() - t0,
+                cache_moves=cache_moves, caches=caches)
+            return self.session
 
     def advance(self, experts, kv_flat) -> bool:
         """Migrate the next layer chunk (dispatched async; decode may run
@@ -370,17 +376,18 @@ class SwitchExecutor:
         Returns True while chunks remain."""
         s = self.session
         assert s is not None and not s.done
-        w_lo, w_hi, kv_lo, kv_hi = s.chunks[s.next_chunk]
-        if self.cfg.is_moe and w_hi > w_lo:
-            fn = self.chunk_reshard_fn(s.src, s.dst, w_lo, w_hi)
-            d13, d2 = fn(experts["w13"], experts["w2"],
-                         s.experts_dst["w13"], s.experts_dst["w2"])
-            s.experts_dst = {"w13": d13, "w2": d2}
-        if s.kv_dst is not None and kv_hi > kv_lo:
-            mfn = self.chunk_migrate_fn(s.kv_dir, kv_lo, kv_hi, KV_BLOCK)
-            for sp, dp, vm in s.plan_blocks:          # device-resident
-                s.kv_dst = mfn(kv_flat, s.kv_dst, sp, dp, vm)
-        s.next_chunk += 1
+        with span("switch.chunk", i=s.next_chunk):
+            w_lo, w_hi, kv_lo, kv_hi = s.chunks[s.next_chunk]
+            if self.cfg.is_moe and w_hi > w_lo:
+                fn = self.chunk_reshard_fn(s.src, s.dst, w_lo, w_hi)
+                d13, d2 = fn(experts["w13"], experts["w2"],
+                             s.experts_dst["w13"], s.experts_dst["w2"])
+                s.experts_dst = {"w13": d13, "w2": d2}
+            if s.kv_dst is not None and kv_hi > kv_lo:
+                mfn = self.chunk_migrate_fn(s.kv_dir, kv_lo, kv_hi, KV_BLOCK)
+                for sp, dp, vm in s.plan_blocks:          # device-resident
+                    s.kv_dst = mfn(kv_flat, s.kv_dst, sp, dp, vm)
+            s.next_chunk += 1
         return not s.done
 
     def warmup_movers(self, src, dst, experts, kv_flat,
@@ -504,80 +511,83 @@ class SwitchExecutor:
         (experts', kv', alloc', caches', stats)."""
         s = self.session
         assert s is not None and s.done
-        t_pause0 = time.perf_counter()
-        live_ids = {r.rid for r in live}
+        with span("switch.commit"):
+            t_pause0 = time.perf_counter()
+            live_ids = {r.rid for r in live}
 
-        # requests that finished during the window: return their planned
-        # destination pages to the new allocator
-        for a in s.assignments:
-            if a.req.rid not in live_ids and a.new_pages:
-                s.new_alloc[a.req.data_group].release(
-                    max(a.new_owner, 0), a.new_pages)
+            # requests that finished during the window: return their planned
+            # destination pages to the new allocator
+            for a in s.assignments:
+                if a.req.rid not in live_ids and a.new_pages:
+                    s.new_alloc[a.req.data_group].release(
+                        max(a.new_owner, 0), a.new_pages)
 
-        # cache entries evicted during the window: release their planned
-        # destination refs NOW, before the delta pass — its top-up/CoW
-        # allocations must be able to use those reclaimable pages
-        if s.caches is not None and s.kv_dir is not None:
-            s.alive_moves = []
-            for d in range(self.Dd):
-                keep = []
-                for m in s.cache_moves[d]:
-                    if s.caches[d].move_alive(m):
-                        keep.append(m)
-                    else:
-                        s.new_alloc[d].release(m.dst_pool, list(m.dst_pages))
-                s.alive_moves.append(keep)
+            # cache entries evicted during the window: release their planned
+            # destination refs NOW, before the delta pass — its top-up/CoW
+            # allocations must be able to use those reclaimable pages
+            if s.caches is not None and s.kv_dir is not None:
+                s.alive_moves = []
+                for d in range(self.Dd):
+                    keep = []
+                    for m in s.cache_moves[d]:
+                        if s.caches[d].move_alive(m):
+                            keep.append(m)
+                        else:
+                            s.new_alloc[d].release(m.dst_pool,
+                                                   list(m.dst_pages))
+                    s.alive_moves.append(keep)
 
-        delta_pages = 0
-        if s.kv_dst is not None:
-            per, delta_pages = self._delta_pairs(live_ids)
-            if delta_pages:
-                # fixed-width blocks -> one compiled delta executable per
-                # direction, regardless of how dirty the window got
-                W = KV_BLOCK
-                mfn = self.chunk_migrate_fn(s.kv_dir, 0, self.Lk, W)
-                nblocks = max(-(-len(pairs) // W)
-                              for rows in per for pairs in rows.values())
-                for b in range(nblocks):
-                    plans = [pairs_to_plan(
-                        s.kv_dir,
-                        {g: per[d][g][b * W:(b + 1) * W]
-                         for g in range(self.G)}, self.G)
-                        for d in range(self.Dd)]
-                    # blocks are <= W wide; min_width=W makes the padded
-                    # width structurally equal to the compiled pmax
-                    (sp, dp, vm), _ = self._stack_plans(plans, min_width=W)
-                    s.kv_dst = mfn(kv_flat, s.kv_dst, jnp.asarray(sp),
-                                   jnp.asarray(dp), jnp.asarray(vm))
+            delta_pages = 0
+            if s.kv_dst is not None:
+                per, delta_pages = self._delta_pairs(live_ids)
+                if delta_pages:
+                    # fixed-width blocks -> one compiled delta executable per
+                    # direction, regardless of how dirty the window got
+                    W = KV_BLOCK
+                    mfn = self.chunk_migrate_fn(s.kv_dir, 0, self.Lk, W)
+                    nblocks = max(-(-len(pairs) // W)
+                                  for rows in per for pairs in rows.values())
+                    for b in range(nblocks):
+                        plans = [pairs_to_plan(
+                            s.kv_dir,
+                            {g: per[d][g][b * W:(b + 1) * W]
+                             for g in range(self.G)}, self.G)
+                            for d in range(self.Dd)]
+                        # blocks are <= W wide; min_width=W makes the padded
+                        # width structurally equal to the compiled pmax
+                        (sp, dp, vm), _ = self._stack_plans(plans, min_width=W)
+                        s.kv_dst = mfn(kv_flat, s.kv_dst, jnp.asarray(sp),
+                                       jnp.asarray(dp), jnp.asarray(vm))
 
-        apply_assignments([a for a in s.assignments
-                           if a.req.rid in live_ids])
-        # surviving cache entries re-index under the destination pools
-        # (dead moves released their dst refs above; _dst_page may have
-        # sacrificed more to serve live requests' top-ups)
-        new_caches = s.caches
-        if s.caches is not None and s.kv_dir is not None:
-            new_caches = [
-                PrefixCache.rebuild(s.new_alloc[d], s.alive_moves[d])
-                for d in range(self.Dd)]
-        if s.kv_dst is not None:
-            jax.block_until_ready(s.kv_dst)
-        if s.experts_dst is not None:
-            jax.block_until_ready(s.experts_dst["w13"])
-        now = time.perf_counter()
-        # pause = the synchronous plan/staging phase in start() plus this
-        # commit phase — measured consistently with monolithic(), whose
-        # pause likewise includes its plan time
-        stats = SwitchStats(
-            direction=s.direction, total_s=now - s.t_start,
-            pause_s=s.plan_pause_s + (now - t_pause0),
-            plan_s=s.plan_pause_s, kv_pages=s.kv_pages,
-            delta_pages=delta_pages, chunks=len(s.chunks),
-            live_requests=s.live_requests)
-        out = (s.experts_dst, s.kv_dst if s.kv_dst is not None else kv_flat,
-               s.new_alloc, new_caches, stats)
-        self.session = None
-        return out
+            apply_assignments([a for a in s.assignments
+                               if a.req.rid in live_ids])
+            # surviving cache entries re-index under the destination pools
+            # (dead moves released their dst refs above; _dst_page may have
+            # sacrificed more to serve live requests' top-ups)
+            new_caches = s.caches
+            if s.caches is not None and s.kv_dir is not None:
+                new_caches = [
+                    PrefixCache.rebuild(s.new_alloc[d], s.alive_moves[d])
+                    for d in range(self.Dd)]
+            if s.kv_dst is not None:
+                jax.block_until_ready(s.kv_dst)
+            if s.experts_dst is not None:
+                jax.block_until_ready(s.experts_dst["w13"])
+            now = time.perf_counter()
+            # pause = the synchronous plan/staging phase in start() plus this
+            # commit phase — measured consistently with monolithic(), whose
+            # pause likewise includes its plan time
+            stats = SwitchStats(
+                direction=s.direction, total_s=now - s.t_start,
+                pause_s=s.plan_pause_s + (now - t_pause0),
+                plan_s=s.plan_pause_s, kv_pages=s.kv_pages,
+                delta_pages=delta_pages, chunks=len(s.chunks),
+                live_requests=s.live_requests)
+            out = (s.experts_dst,
+                   s.kv_dst if s.kv_dst is not None else kv_flat,
+                   s.new_alloc, new_caches, stats)
+            self.session = None
+            return out
 
 
 # ---------------------------------------------------------------------------
@@ -652,32 +662,34 @@ class CrossWorldSwitcher:
         Source buffers and request metadata stay live for overlap decode."""
         assert self.session is None, "cross-world switch already in progress"
         src, dst = get_layout(src), get_layout(dst)
-        t0 = time.perf_counter()
-        new_alloc = [PageAllocator(self.cc, self.cfg, G_dst, dst)
-                     for _ in range(self.Dd)]
-        assignments, moves = [], []
-        for d in range(self.Dd):
-            reqs = [r for r in live if r.data_group == d]
-            mv, asg = plan_cross_world(reqs, self.cfg, self.cc, new_alloc[d],
-                                       src, dst, G_src, G_dst)
-            moves.append(mv)
-            assignments.extend(asg)
-        kv_host = None
-        if self.Lk > 0:
-            # per-rank NE is world-independent (cc.nelems ignores G), so the
-            # destination rows reuse the source buffer's trailing dim
-            kv_host = np.zeros((self.Dd, G_dst) + kv_flat.shape[2:],
-                               dtype=kv_flat.dtype)
-        self.session = CrossWorldSession(
-            src=src, dst=dst, G_src=G_src, G_dst=G_dst,
-            direction=f"{src}_to_{dst}", t_start=t0,
-            assignments=assignments, moves=moves, new_alloc=new_alloc,
-            chunks=self._layer_chunks(chunk_layers),
-            experts_chunks=[] if self.cfg.is_moe else None,
-            kv_host=kv_host, kv_pages=sum(len(m) for m in moves),
-            live_requests=len(live),
-            plan_pause_s=time.perf_counter() - t0, caches=caches)
-        return self.session
+        with span("switch.plan"):
+            t0 = time.perf_counter()
+            new_alloc = [PageAllocator(self.cc, self.cfg, G_dst, dst)
+                         for _ in range(self.Dd)]
+            assignments, moves = [], []
+            for d in range(self.Dd):
+                reqs = [r for r in live if r.data_group == d]
+                mv, asg = plan_cross_world(reqs, self.cfg, self.cc,
+                                           new_alloc[d], src, dst, G_src,
+                                           G_dst)
+                moves.append(mv)
+                assignments.extend(asg)
+            kv_host = None
+            if self.Lk > 0:
+                # per-rank NE is world-independent (cc.nelems ignores G), so
+                # the destination rows reuse the source buffer's trailing dim
+                kv_host = np.zeros((self.Dd, G_dst) + kv_flat.shape[2:],
+                                   dtype=kv_flat.dtype)
+            self.session = CrossWorldSession(
+                src=src, dst=dst, G_src=G_src, G_dst=G_dst,
+                direction=f"{src}_to_{dst}", t_start=t0,
+                assignments=assignments, moves=moves, new_alloc=new_alloc,
+                chunks=self._layer_chunks(chunk_layers),
+                experts_chunks=[] if self.cfg.is_moe else None,
+                kv_host=kv_host, kv_pages=sum(len(m) for m in moves),
+                live_requests=len(live),
+                plan_pause_s=time.perf_counter() - t0, caches=caches)
+            return self.session
 
     def _stage_fn(self, view: tuple, lo: int, hi: int, W: int):
         """Jitted fused page gather for one source rank's flat (NE,) row:
@@ -757,17 +769,18 @@ class CrossWorldSwitcher:
         the source in between). Returns True while chunks remain."""
         s = self.session
         assert s is not None and not s.done
-        w_lo, w_hi, kv_lo, kv_hi = s.chunks[s.next_chunk]
-        if self.cfg.is_moe and w_hi > w_lo:
-            eg = s.dst.expert_group(s.G_dst, self.Dd * s.G_dst)
-            s.experts_chunks.append(
-                pack_experts_host(self.cfg, self.moe_host, s.dst, eg,
-                                  w_lo, w_hi))
-        if s.kv_host is not None and kv_hi > kv_lo:
-            for d in range(self.Dd):
-                self._stage_kv_chunk(d, kv_flat, s, s.moves[d],
-                                     kv_lo, kv_hi)
-        s.next_chunk += 1
+        with span("switch.chunk", i=s.next_chunk):
+            w_lo, w_hi, kv_lo, kv_hi = s.chunks[s.next_chunk]
+            if self.cfg.is_moe and w_hi > w_lo:
+                eg = s.dst.expert_group(s.G_dst, self.Dd * s.G_dst)
+                s.experts_chunks.append(
+                    pack_experts_host(self.cfg, self.moe_host, s.dst, eg,
+                                      w_lo, w_hi))
+            if s.kv_host is not None and kv_hi > kv_lo:
+                for d in range(self.Dd):
+                    self._stage_kv_chunk(d, kv_flat, s, s.moves[d],
+                                         kv_lo, kv_hi)
+            s.next_chunk += 1
         return not s.done
 
     def abort(self) -> SwitchStats:
@@ -824,47 +837,49 @@ class CrossWorldSwitcher:
         (experts', kv', alloc', caches', stats)."""
         s = self.session
         assert s is not None and s.done
-        t_pause0 = time.perf_counter()
-        live_ids = {r.rid for r in live}
-        for a in s.assignments:
-            if a.req.rid not in live_ids and a.new_pages:
-                s.new_alloc[a.req.data_group].release(
-                    max(a.new_owner, 0), a.new_pages)
-        delta_pages = 0
-        if s.kv_host is not None:
-            per, delta_pages = self._delta_moves(live_ids)
-            if delta_pages:
-                for d in range(self.Dd):
-                    self._stage_kv_chunk(d, kv_flat, s, per[d], 0, self.Lk)
-        apply_assignments([a for a in s.assignments
-                           if a.req.rid in live_ids])
-        experts = None
-        if self.cfg.is_moe:
-            w13 = np.concatenate([c[0] for c in s.experts_chunks], axis=0)
-            w2 = np.concatenate([c[1] for c in s.experts_chunks], axis=0)
-            dst_ax = s.dst.expert_axes((self.da,), self.m)
-            esh = NamedSharding(dst_mesh, P(None, dst_ax, None, None, None))
-            # numpy straight to the shards: no full copy on one device
-            experts = {"w13": jax.device_put(w13, esh),
-                       "w2": jax.device_put(w2, esh)}
-        kv = None
-        if s.kv_host is not None:
-            kv = jax.device_put(s.kv_host,
-                                NamedSharding(dst_mesh, P(self.da, self.m)))
-            jax.block_until_ready(kv)
-        # prefix caches never migrate across worlds: the commit starts
-        # with fresh empty caches over the destination allocators
-        new_caches = s.caches
-        if s.caches is not None:
-            new_caches = [PrefixCache(s.new_alloc[d])
-                          for d in range(self.Dd)]
-        now = time.perf_counter()
-        stats = SwitchStats(
-            direction=s.direction, total_s=now - s.t_start,
-            pause_s=s.plan_pause_s + (now - t_pause0),
-            plan_s=s.plan_pause_s, kv_pages=s.kv_pages,
-            delta_pages=delta_pages, chunks=len(s.chunks),
-            live_requests=s.live_requests)
-        out = (experts, kv, s.new_alloc, new_caches, stats)
-        self.session = None
-        return out
+        with span("switch.commit"):
+            t_pause0 = time.perf_counter()
+            live_ids = {r.rid for r in live}
+            for a in s.assignments:
+                if a.req.rid not in live_ids and a.new_pages:
+                    s.new_alloc[a.req.data_group].release(
+                        max(a.new_owner, 0), a.new_pages)
+            delta_pages = 0
+            if s.kv_host is not None:
+                per, delta_pages = self._delta_moves(live_ids)
+                if delta_pages:
+                    for d in range(self.Dd):
+                        self._stage_kv_chunk(d, kv_flat, s, per[d], 0, self.Lk)
+            apply_assignments([a for a in s.assignments
+                               if a.req.rid in live_ids])
+            experts = None
+            if self.cfg.is_moe:
+                w13 = np.concatenate([c[0] for c in s.experts_chunks], axis=0)
+                w2 = np.concatenate([c[1] for c in s.experts_chunks], axis=0)
+                dst_ax = s.dst.expert_axes((self.da,), self.m)
+                esh = NamedSharding(dst_mesh,
+                                    P(None, dst_ax, None, None, None))
+                # numpy straight to the shards: no full copy on one device
+                experts = {"w13": jax.device_put(w13, esh),
+                           "w2": jax.device_put(w2, esh)}
+            kv = None
+            if s.kv_host is not None:
+                kv = jax.device_put(
+                    s.kv_host, NamedSharding(dst_mesh, P(self.da, self.m)))
+                jax.block_until_ready(kv)
+            # prefix caches never migrate across worlds: the commit starts
+            # with fresh empty caches over the destination allocators
+            new_caches = s.caches
+            if s.caches is not None:
+                new_caches = [PrefixCache(s.new_alloc[d])
+                              for d in range(self.Dd)]
+            now = time.perf_counter()
+            stats = SwitchStats(
+                direction=s.direction, total_s=now - s.t_start,
+                pause_s=s.plan_pause_s + (now - t_pause0),
+                plan_s=s.plan_pause_s, kv_pages=s.kv_pages,
+                delta_pages=delta_pages, chunks=len(s.chunks),
+                live_requests=s.live_requests)
+            out = (experts, kv, s.new_alloc, new_caches, stats)
+            self.session = None
+            return out

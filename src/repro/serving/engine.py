@@ -39,6 +39,7 @@ from repro.serving.metrics import ServeMetrics
 from repro.serving.qos import QosPolicy, slo_targets
 from repro.serving.request import Request
 from repro.serving.scheduler import Scheduler
+from repro.tracing import span
 
 
 @dataclass
@@ -341,23 +342,27 @@ class MoebiusEngine:
         self.ex.run_copies(self.sched.drain_copies())
         if not self.sched.prefilling:
             return
-        picked = self.sched.select_prefill_rows(self.ex.prefill_chunk)
+        with span("sched.plan"):
+            picked = self.sched.select_prefill_rows(self.ex.prefill_chunk)
         if not picked:
             return
         nxt = self.ex.run_prefill(picked, self._step_i)
-        t = self.now()
-        for r, d, row, n in picked:
-            self.sched.finish_prefill(r, n, int(nxt[d, row]), t)
+        with span("sched.commit"):
+            t = self.now()
+            for r, d, row, n in picked:
+                self.sched.finish_prefill(r, n, int(nxt[d, row]), t)
 
     def _decode_once(self) -> None:
         if not self.sched.running:
             return
-        B, stepped = self.sched.plan_decode(self._step_i)
+        with span("sched.plan"):
+            B, stepped = self.sched.plan_decode(self._step_i)
         self.ex.run_copies(self.sched.drain_copies())
         if not stepped:
             return
         toks = self.ex.run_decode(B, stepped, self._step_i)
-        self.sched.commit_decode(stepped, toks)
+        with span("sched.commit"):
+            self.sched.commit_decode(stepped, toks)
 
     def _decode_step(self) -> None:
         """Dispatch one decode iteration on whichever control plane the
@@ -369,28 +374,34 @@ class MoebiusEngine:
         else:
             self._decode_once()
 
-    def _mixed_step(self) -> None:
+    def _mixed_step(self) -> tuple[int, int]:
         """ONE token-budgeted dispatch per iteration (DESIGN.md §10): all
         eligible decode tokens first, prefill chunks packed into the
-        remaining budget, through a single step function."""
+        remaining budget, through a single step function. Returns the
+        dispatched (B, Sq), (0, 0) when no mixed step ran."""
         if self.ecfg.decode_steps > 1:
             if not self.sched.prefilling:
                 # pure decode: the fused N-step pipeline serves it (copies
                 # from admission land inside decode_fused's drain)
                 self.ex.decode_fused(self.sched, self._step_i)
-                return
+                return 0, 0
             # a prefill chunk joins: drain the one-deep pipeline to a step
             # boundary and run single-token mixed dispatches until the
             # storm passes (runners re-join the fused loop afterwards)
             self.ex.suspend_fused(self.sched)
-        plan = self.sched.plan_mixed(self._step_i, budget=self.token_budget,
-                                     chunk=self.ex.prefill_chunk)
+        with span("sched.plan"):
+            plan = self.sched.plan_mixed(self._step_i,
+                                         budget=self.token_budget,
+                                         chunk=self.ex.prefill_chunk)
         # CoW copies from BOTH prefill admission and the plan's page growth
         # must land before the dispatch that could write their source pages
         self.ex.run_copies(self.sched.drain_copies())
-        if plan.rows:
-            nxt = self.ex.run_mixed(plan, self._step_i)
+        if not plan.rows:
+            return 0, 0
+        nxt = self.ex.run_mixed(plan, self._step_i)
+        with span("sched.commit"):
             self.sched.commit_mixed(plan, nxt, self.now())
+        return plan.B, plan.Sq
 
     def _charge_dispatches(self) -> None:
         """Virtual-clock cost model: bill `dispatch_dt` seconds per device
@@ -427,6 +438,10 @@ class MoebiusEngine:
         assert target is not self.active, "switch target == active layout"
         assert target in self.layouts, \
             f"layout {target} not resident (EngineConfig.layouts)"
+        with span("switch", direction=f"{self.active}_to_{target}"):
+            return self._execute_switch(target)
+
+    def _execute_switch(self, target: LayoutSpec) -> bool:
         cross_world = self.ex._is_cross_world(target)
         # fused decode: fetch in-flight tokens so every request's kv_len and
         # pages sit at a step boundary before the plan snapshot
@@ -674,40 +689,52 @@ class MoebiusEngine:
     # ------------------------------------------------------------------
     def step(self) -> None:
         self._step_i += 1
-        if self.ecfg.idle_skip:
-            self._skip_idle()
-        self._release_expired_holds()
-        if self._faults is not None:
-            for f in self._faults.poll(self._step_i, self.now()):
-                self._apply_fault(f)
-        self.sched.admit(self.now())
-        if self.sched.deadline_due(self.now()):
-            # expiry finishes requests in place: drain the fused pipeline
-            # first so none has in-flight tokens
-            self.ex.drain_decode()
-            self.sched.expire_deadlines(self.now())
-        # policy: sample once per iteration, between steps, through the
-        # scheduler's queue snapshot (in-flight fused tokens count toward
-        # the live-token load)
-        cap_ep = self.cc.capacity_tokens(self.cfg, self.G, EP)
-        att = (self.metrics.recent_attainment("interactive")
-               if self.ecfg.qos else None)
-        dec = self.coord.observe_queues(self.sched.snapshot(), cap_ep,
-                                        attainment=att)
-        if dec.switch:
-            self.execute_switch(dec.target)
-        self.sched.start_prefills()          # admit waiting -> prefill
-        if self.ecfg.mixed_batch:
-            self._mixed_step()
-        else:
-            self._run_prefill()
-            self._decode_step()
-        self._charge_dispatches()
-        self._check_recoveries()
-        self.metrics.pages_resident(sum(a.total_held()
-                                        for a in self.sched.alloc))
-        self.metrics.sample_mode(self.now(), self.active,
-                                 len(self.sched.running))
+        m = self.metrics
+        dec0, pre0 = m.decode_tokens, m.prefill_tokens
+        with span("step", step=self._step_i) as sp:
+            with span("sched.admit"):
+                if self.ecfg.idle_skip:
+                    self._skip_idle()
+                self._release_expired_holds()
+                if self._faults is not None:
+                    for f in self._faults.poll(self._step_i, self.now()):
+                        self._apply_fault(f)
+                self.sched.admit(self.now())
+                if self.sched.deadline_due(self.now()):
+                    # expiry finishes requests in place: drain the fused
+                    # pipeline first so none has in-flight tokens
+                    self.ex.drain_decode()
+                    self.sched.expire_deadlines(self.now())
+            # policy: sample once per iteration, between steps, through the
+            # scheduler's queue snapshot (in-flight fused tokens count
+            # toward the live-token load)
+            with span("policy"):
+                cap_ep = self.cc.capacity_tokens(self.cfg, self.G, EP)
+                att = (m.recent_attainment("interactive")
+                       if self.ecfg.qos else None)
+                dec = self.coord.observe_queues(self.sched.snapshot(),
+                                                cap_ep, attainment=att)
+            if dec.switch:
+                self.execute_switch(dec.target)
+            with span("sched.admit"):
+                started = self.sched.start_prefills()   # waiting -> prefill
+                t = self.now()
+                for d in started:
+                    if d.req.prefill_start_s is None:
+                        d.req.prefill_start_s = t
+            if self.ecfg.mixed_batch:
+                B, Sq = self._mixed_step()
+            else:       # two-phase: each dispatch's shape is on exec.stage
+                B = Sq = 0
+                self._run_prefill()
+                self._decode_step()
+            with span("account"):
+                self._charge_dispatches()
+                self._check_recoveries()
+                m.pages_resident(sum(a.total_held()
+                                     for a in self.sched.alloc))
+            sp.set_metadata(B=B, Sq=Sq, dec=m.decode_tokens - dec0,
+                            pre=m.prefill_tokens - pre0)
 
     def run(self, max_steps: int = 100000):
         for _ in range(max_steps):

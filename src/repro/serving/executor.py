@@ -41,6 +41,7 @@ from repro.serving.request import Request
 from repro.serving.scheduler import MixedPlan, MixedRow
 from repro.serving.steps import (build_decode_loop, build_decode_pack,
                                  build_mixed_step, decode_pack_specs)
+from repro.tracing import span
 
 
 def build_weight_init(cfg: ModelConfig, mesh, specs, active: LayoutSpec,
@@ -414,8 +415,11 @@ class Executor:
     def run_copies(self, copies: list) -> None:
         """Execute drained CopyPages decisions in emission order (the order
         encodes the free->realloc hazards the Scheduler already resolved)."""
-        for c in copies:
-            self.copy_pages(c.d, c.pool, list(c.pairs))
+        if not copies:
+            return
+        with span("exec.copies"):
+            for c in copies:
+                self.copy_pages(c.d, c.pool, list(c.pairs))
 
     # ------------------------------------------------------------------
     # mixed-batch dispatch (THE serve path; two-phase wrappers below)
@@ -441,37 +445,43 @@ class Executor:
         prefill-chunk rows under a single executable. Returns the (Dd, B)
         next-token array the engine hands to Scheduler.commit_mixed."""
         B, Sq = plan.B, plan.Sq
-        toks, pos, vl, bt = self._staging(B, Sq)
-        n_dec = n_pref = 0
-        for row in plan.rows:
-            r, d, s, n = row.req, row.d, row.row, row.n_tokens
-            if row.kind == "decode":
-                toks[d, s, 0] = r.output[-1]
-                n_dec += 1
-            else:
-                toks[d, s, :n] = r.prompt_array()[row.start_pos:
-                                                  row.start_pos + n]
-                n_pref += n
-            pos[d, s] = row.start_pos
-            vl[d, s] = n
-            bt[d, s, :len(r.pages)] = r.pages
-        fn = self._mixed_fn(self.active, B, Sq)
-        nxt, self.kv_flat, *logits = fn(
-            self._assemble_pack(self.active), self.kv_flat, jnp.asarray(toks),
-            jnp.asarray(pos), jnp.asarray(vl), jnp.asarray(bt),
-            self._step_key(step_i))
-        if logits:
-            lg = np.asarray(logits[0])
+        with span("exec.stage", B=B, Sq=Sq, slots=self.Dd * B * Sq) as sp:
+            toks, pos, vl, bt = self._staging(B, Sq)
+            n_dec = n_pref = 0
             for row in plan.rows:
-                # keyed by the KV position of the token these logits sample
-                self.logits[(row.req.rid, row.start_pos + row.n_tokens)] = \
-                    lg[row.d, row.row, :self.cfg.vocab_size]
+                r, d, s, n = row.req, row.d, row.row, row.n_tokens
+                if row.kind == "decode":
+                    toks[d, s, 0] = r.output[-1]
+                    n_dec += 1
+                else:
+                    toks[d, s, :n] = r.prompt_array()[row.start_pos:
+                                                      row.start_pos + n]
+                    n_pref += n
+                pos[d, s] = row.start_pos
+                vl[d, s] = n
+                bt[d, s, :len(r.pages)] = r.pages
+            sp.set_metadata(dec=n_dec, pre=n_pref)
+            fn = self._mixed_fn(self.active, B, Sq)
+            args = (self._assemble_pack(self.active), self.kv_flat,
+                    jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(vl),
+                    jnp.asarray(bt), self._step_key(step_i))
+        with span("exec.launch"):
+            nxt, self.kv_flat, *logits = fn(*args)
+        with span("exec.fetch"):
+            out = np.asarray(nxt)
+            if logits:
+                lg = np.asarray(logits[0])
+                for row in plan.rows:
+                    # keyed by the KV position of the token these logits
+                    # sample
+                    key = (row.req.rid, row.start_pos + row.n_tokens)
+                    self.logits[key] = lg[row.d, row.row, :self.cfg.vocab_size]
         if n_pref:
             self.metrics.prefill(n_pref)
         if n_dec:
             self.metrics.decode(n_dec, 1)
         self.metrics.dispatch(mixed=bool(n_dec and n_pref))
-        return np.asarray(nxt)
+        return out
 
     def run_prefill(self, picked: list, step_i: int) -> np.ndarray:
         """Two-phase wrapper: one chunked prefill step (rows from
@@ -526,45 +536,47 @@ class Executor:
         """One fused decode iteration: plan against the device state, apply
         the delta scatters, dispatch the N-step loop, pipeline the output
         fetch one iteration deep."""
-        N = self.ecfg.decode_steps
-        if not sched.running:
-            self.drain_decode()
-            return
-        B = sched.fused_rung()
-        st = self._dstate
-        if st is None or st.B != B or st.layout is not self.active:
-            self.drain_decode()            # step boundary before a rebuild
-            st = self._rebuild_dstate(B, sched)
-        joins, grows, plan, capped, starved = sched.plan_fused(st, N)
-        self.run_copies(sched.drain_copies())
-        # deltas must land even when nothing steps: plan_fused already
-        # recorded the joins in the host mirror, and a budget-clamped join
-        # still needs its token/position/table row on device for later
-        st.apply(joins, grows)
-        sched.resolve_fused(plan, capped, starved)
-        if not plan:
-            self.drain_decode()            # nothing live; flush the pipeline
-            return
-        fn = self._decode_loop_fn(self.active, st.B, N)
-        out, self.kv_flat, tok, pos, bud = fn(
-            self._assemble_pack(self.active), self.kv_flat, st.tokens,
-            st.positions, st.budgets, st.block_tables,
-            self._step_key(step_i))
-        st.advance(tok, pos, bud)
-        # start the device->host copy now; the tokens are read one engine
-        # iteration later, so host dispatch runs ahead of the device
-        if hasattr(out, "copy_to_host_async"):
-            out.copy_to_host_async()
-        total = 0
-        for d, s, r, steps in plan:
-            r.inflight += steps
-            r.budget_dev -= steps
-            total += steps
-        self.metrics.decode(total, N)
-        self.metrics.dispatch()
-        prev, self._pending = self._pending, (out, plan, st)
-        if prev is not None:
-            self._consume(prev)
+        with span("exec.fused"):
+            N = self.ecfg.decode_steps
+            if not sched.running:
+                self.drain_decode()
+                return
+            B = sched.fused_rung()
+            st = self._dstate
+            if st is None or st.B != B or st.layout is not self.active:
+                self.drain_decode()        # step boundary before a rebuild
+                st = self._rebuild_dstate(B, sched)
+            joins, grows, plan, capped, starved = sched.plan_fused(st, N)
+            self.run_copies(sched.drain_copies())
+            # deltas must land even when nothing steps: plan_fused already
+            # recorded the joins in the host mirror, and a budget-clamped
+            # join still needs its token/position/table row on device
+            st.apply(joins, grows)
+            sched.resolve_fused(plan, capped, starved)
+            if not plan:
+                self.drain_decode()        # nothing live; flush the pipeline
+                return
+            fn = self._decode_loop_fn(self.active, st.B, N)
+            out, self.kv_flat, tok, pos, bud = fn(
+                self._assemble_pack(self.active), self.kv_flat, st.tokens,
+                st.positions, st.budgets, st.block_tables,
+                self._step_key(step_i))
+            st.advance(tok, pos, bud)
+            # start the device->host copy now; the tokens are read one
+            # engine iteration later, so host dispatch runs ahead of the
+            # device
+            if hasattr(out, "copy_to_host_async"):
+                out.copy_to_host_async()
+            total = 0
+            for d, s, r, steps in plan:
+                r.inflight += steps
+                r.budget_dev -= steps
+                total += steps
+            self.metrics.decode(total, N)
+            self.metrics.dispatch()
+            prev, self._pending = self._pending, (out, plan, st)
+            if prev is not None:
+                self._consume(prev)
 
     def _consume(self, pending):
         """Fetch one fused dispatch's tokens and retire finished requests.

@@ -56,7 +56,9 @@ class Request:
     # DeviceDecodeState currently holds for this request's slot
     inflight: int = 0
     budget_dev: int = 0
-    # metrics
+    # metrics, on the engine clock: the first time the request left
+    # `waiting` (its prefill phase runs from here to the first token)
+    prefill_start_s: float | None = None
     first_token_s: float | None = None
     finish_s: float | None = None
     # staging fast-path: the prompt as one int32 ndarray, so prefill rows

@@ -1,0 +1,45 @@
+"""Host spans of the serving program in the profiler's trace.
+
+`span(name, **args)` opens a `jax.profiler.TraceAnnotation` named
+`moebius.<name>`. It writes into the profiler's own trace, on the same
+clock as the device ops, so each gap in which the chip is idle lines up
+with the program phase the host was in. Outside a profiler session it
+records nothing and costs well under a microsecond, so the spans stay in
+the code. Numbers go into keyword arguments, never into the name;
+arguments known only at the end of a span are added with
+`set_metadata(**args)` on the object the `with` statement binds.
+
+The step's span tree (DESIGN.md §9):
+
+    moebius.step {step, B, Sq, dec, pre}
+      moebius.sched.admit         admission, deadline expiry, prefill starts
+      moebius.policy              queue snapshot + coordinator
+      moebius.switch {direction}  a live switch, when taken
+        moebius.switch.plan       plan and stage (decode paused)
+        moebius.switch.chunk {i}  one layer chunk; overlap decode beside it
+        moebius.switch.commit     dirty-page delta + commit (decode paused)
+      moebius.sched.plan          the step's dispatch plan
+      moebius.exec.copies         copy-on-write page copies
+      moebius.exec.stage {B, Sq, dec, pre, slots}  host buffers + uploads
+      moebius.exec.launch         the jitted step call (compiles show here)
+      moebius.exec.fetch          host blocked on the sampled tokens
+      moebius.exec.fused          one fused N-step decode dispatch
+      moebius.sched.commit        tokens into requests
+      moebius.account             per-step bookkeeping
+
+`step`'s B and Sq are its mixed dispatch's shape (0 when it ran none);
+two-phase and in-switch dispatches carry theirs on `exec.stage`.
+
+The switch spans sit at the bounds of `SwitchStats`' timers, so plan +
+commit is the switch's `pause_s`; a monolithic switch has no chunks and
+its whole migration is the commit.
+"""
+from __future__ import annotations
+
+from jax.profiler import TraceAnnotation
+
+PREFIX = "moebius."
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    return TraceAnnotation(PREFIX + name, **args)
