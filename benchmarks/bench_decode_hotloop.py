@@ -206,7 +206,7 @@ def _skew_rows(smoke: bool, seed: int = 0):
                           preferred_element_type=jnp.float32)
 
     grouped_ffn = jax.jit(
-        lambda xd: _grouped_ffn_local(cfg, w13, w2, xd))
+        lambda xd: _grouped_ffn_local(cfg, w13[None], w2[None], 0, xd))
     einsum_ffn = jax.jit(einsum_ffn)
 
     rows = []

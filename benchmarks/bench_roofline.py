@@ -87,11 +87,11 @@ def _cases(smoke: bool):
         from repro.kernels.moe_gemm.ops import grouped_matmul
         E, C, D, W = 8, 256 // s, 256 // s, 512 // s
         x = jnp.ones((E, C, D), jnp.float32)
-        w = jnp.ones((E, W, D), jnp.float32)
+        w = jnp.ones((1, E, W, D), jnp.float32)
         fl = 2.0 * E * C * W * D
         by = 4.0 * (E * C * D + E * W * D + E * C * W)
         return (lambda bk: jax.jit(
-            lambda a, b: grouped_matmul(a, b, backend=bk))), (x, w), fl, by
+            lambda a, b: grouped_matmul(a, b, 0, backend=bk))), (x, w), fl, by
 
     def kv_pack():
         from repro.kernels.kv_pack.ops import gather_pages_rows

@@ -145,12 +145,15 @@ def kernel_parity(cfg, backend=None, *, G: int = 4, page: int = 16,
             paged_attention(*args, q_offset=qo, window=window,
                             backend=backend),
             paged_attention_ref(*args, q_offset=qo, window=window))
+    # a two-layer stack read at layer 1 (layer 0 zeros): the kernel must
+    # take its tiles from the layer the index names
     for name, (w_out, w_in) in (("w13", (2 * I, D)), ("w2", (D, I))):
-        x, w = rnd(E, 32, w_in), rnd(E, w_out, w_in)
+        x, wl = rnd(E, 32, w_in), rnd(E, w_out, w_in)
+        w = jnp.stack([jnp.zeros_like(wl), wl])
         errs[f"grouped_matmul_{name}"] = _close(
-            "grouped_matmul", grouped_matmul(x, w, backend=backend),
-            grouped_matmul_ref(x, w))
-        del x, w
+            "grouped_matmul", grouped_matmul(x, w, 1, backend=backend),
+            grouped_matmul_ref(x, wl))
+        del x, wl, w
     # duplicate-free page lists (scatter is unspecified on duplicates)
     idx = jax.random.permutation(next(keys), pages)[:8].astype(jnp.int32)
     errs["gather_pages"] = _equal(

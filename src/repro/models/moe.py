@@ -266,27 +266,30 @@ def shared_expert_forward(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
 # Explicit per-rank decode paths (inside shard_map over `axis`)
 # ---------------------------------------------------------------------------
 
-def _grouped_ffn_local(cfg: ModelConfig, w13, w2, xd, *,
+def _grouped_ffn_local(cfg: ModelConfig, w13, w2, li, xd, *,
                        backend: str | None = None):
-    """xd (E_loc, C, D); w13 (E_loc, W13_loc, D); w2 (E_loc, D, W2_loc).
+    """xd (E_loc, C, D); w13 (L, E_loc, W13_loc, D), w2 (L, E_loc, D,
+    W2_loc): the layer stacks, computed at layer li.
 
-    Both GEMMs route through kernels/moe_gemm.grouped_matmul; w2 stores its
-    width axis last, so the same (E,C,D)x(E,W,D)->(E,C,W) contraction fits
-    both.  With fp32 compute_dtype the ref backend is bit-identical to the
-    old inline einsums; sub-fp32 compute pays one fp32->compute round-trip
-    per GEMM on the kernel path (tolerance policy: DESIGN.md §14).
+    Both GEMMs route through kernels/moe_gemm.grouped_matmul, which reads
+    layer li's tiles straight from the stack (no per-layer copy); w2 stores
+    its width axis last, so the same (E,C,D)x(E,W,D)->(E,C,W) contraction
+    fits both.  With fp32 compute_dtype the ref backend is bit-identical to
+    the old inline einsums; sub-fp32 compute pays one fp32->compute
+    round-trip per GEMM on the kernel path (tolerance policy: DESIGN.md
+    §14).
     """
-    h = grouped_matmul(xd, w13, backend=backend).astype(jnp.float32)
+    h = grouped_matmul(xd, w13, li, backend=backend).astype(jnp.float32)
     hg, hu = jnp.split(h, 2, axis=-1)
     h = (jax.nn.silu(hg) * hu).astype(cfg.compute_dtype)
-    return grouped_matmul(h, w2, backend=backend).astype(jnp.float32)
+    return grouped_matmul(h, w2, li, backend=backend).astype(jnp.float32)
 
 
 def moe_decode_tp(cfg: ModelConfig, p: dict, x: jax.Array, axis: str | None,
-                  *, cap_factor: float | None = None,
+                  *, li, cap_factor: float | None = None,
                   moe_backend: str | None = None):
     """TP decode: x (T, D) replicated over `axis`; w13/w2 are this rank's
-    (E, W_loc) slices (leading G dim already consumed by shard_map).
+    (L, E, W_loc) layer stacks (G dim already squeezed), used at layer li.
     Output is a *partial* sum — caller psums together with attention output.
     """
     T, D = x.shape
@@ -299,7 +302,7 @@ def moe_decode_tp(cfg: ModelConfig, p: dict, x: jax.Array, axis: str | None,
     disp, _ = _dispatch_tensors(khot, jnp.zeros((E,), jnp.float32), C)
     xd = jnp.einsum("tec,td->ecd", disp,
                     x.astype(jnp.float32)).astype(cfg.compute_dtype)
-    y = _grouped_ffn_local(cfg, p["w13"], p["w2"], xd,
+    y = _grouped_ffn_local(cfg, p["w13"], p["w2"], li, xd,
                            backend=moe_backend)              # partial over axis
     out = jnp.einsum("tec,ecd->td", disp * gate_full[..., None], y)
     out = out.astype(cfg.compute_dtype)
@@ -310,9 +313,10 @@ def moe_decode_tp(cfg: ModelConfig, p: dict, x: jax.Array, axis: str | None,
 
 
 def moe_decode_ep(cfg: ModelConfig, p: dict, x: jax.Array, axis: str,
-                  lay: ExpertLayout, *, cap_factor: float | None = None,
+                  lay: ExpertLayout, *, li, cap_factor: float | None = None,
                   moe_backend: str | None = None):
-    """EP decode under shard_map: x (T_loc, D) is this rank's token slice.
+    """EP decode under shard_map: x (T_loc, D) is this rank's token slice;
+    w13/w2 are this rank's (L, E_loc, ...) layer stacks, used at layer li.
 
     Dispatch entries (token, k, tp-replica) -> per-dest buffers -> all_to_all
     -> local grouped FFN -> inverse all_to_all -> gate-weighted combine.
@@ -362,7 +366,7 @@ def moe_decode_ep(cfg: ModelConfig, p: dict, x: jax.Array, axis: str,
                            dtype=jnp.float32)                 # (G*Cd, C2)
     xd = jnp.einsum("te,tc,td->ecd", ehot_f, slot2,
                     rx.reshape(G * Cd, D)).astype(cfg.compute_dtype)
-    y = _grouped_ffn_local(cfg, p["w13"], p["w2"], xd,
+    y = _grouped_ffn_local(cfg, p["w13"], p["w2"], li, xd,
                            backend=moe_backend)               # (E_loc,C2,D)
     y_back = jnp.einsum("te,tc,ecd->td", ehot_f, slot2,
                         y.astype(jnp.float32)).reshape(G, Cd, D)

@@ -168,11 +168,12 @@ def _write_pages(pool_l, k, v, page_ids, slots):
 
 
 def _ffn(cfg, lpk, h_flat, spec: LayoutSpec, m, lay_exp, cap_factor,
-         ep_axes=None, moe_backend=None):
-    """h_flat (T, D) -> (T, D) ffn output; TP-style paths return AFTER psum."""
+         ep_axes=None, moe_backend=None, *, li):
+    """h_flat (T, D) -> (T, D) ffn output; TP-style paths return AFTER psum.
+    MoE: lpk["moe"]'s w13/w2 are the layer stacks, used at layer li."""
     if cfg.is_moe:
         if spec.expert_kind == "tp":
-            part = moe_decode_tp(cfg, lpk["moe"], h_flat, m,
+            part = moe_decode_tp(cfg, lpk["moe"], h_flat, m, li=li,
                                  cap_factor=cap_factor,
                                  moe_backend=moe_backend)
             return lax.psum(part, m)
@@ -185,9 +186,10 @@ def _ffn(cfg, lpk, h_flat, spec: LayoutSpec, m, lay_exp, cap_factor,
             Tl = T // Gm
             mine = lax.dynamic_slice_in_dim(h_flat, r * Tl, Tl, 0)
             y = moe_decode_ep(cfg, lpk["moe"], mine, ep_axes, lay_exp,
-                              cap_factor=cap_factor, moe_backend=moe_backend)
+                              li=li, cap_factor=cap_factor,
+                              moe_backend=moe_backend)
             return lax.all_gather(y, m, axis=0, tiled=True)
-        return moe_decode_ep(cfg, lpk["moe"], h_flat, m, lay_exp,
+        return moe_decode_ep(cfg, lpk["moe"], h_flat, m, lay_exp, li=li,
                              cap_factor=cap_factor, moe_backend=moe_backend)
     mlp = lpk["mlp"]
     if spec.dense_tp:
@@ -286,10 +288,20 @@ def _chunk_core(cfg, spec: LayoutSpec, pack, pool, tokens, positions,
     kv_total = positions + valid_len                   # (bs,)
     # rope tables are layer-invariant: compute once, thread into the scan
     cos, sin = rope_cos_sin(pos_mat, cfg.dh, cfg.rope_theta)
+    # the expert stacks stay out of the scan's xs: the grouped GEMM reads
+    # layer li's tiles straight from them, where a per-layer slice would
+    # be copied into a fresh buffer for the kernel on every layer and step
+    layers, experts = pack["layers"], {}
+    if cfg.is_moe:
+        moe = dict(layers["moe"])
+        experts = {k: moe.pop(k) for k in ("w13", "w2")}
+        layers = {**layers, "moe": moe}
 
     def layer_fn(carry, xs):
         h, pool = carry
         lpk, li = xs
+        if experts:
+            lpk = dict(lpk, moe=dict(lpk["moe"], **experts))
         # the pool rides the CARRY (dynamic per-layer slice update) rather
         # than the scan's xs/ys: emitting a stacked new pool per step would
         # materialize a full pool copy per call — per *substep* in the
@@ -308,14 +320,15 @@ def _chunk_core(cfg, spec: LayoutSpec, pack, pool, tokens, positions,
         h = h + attn.astype(h.dtype)
         hn = apply_norm(cfg, h, lpk["mlp_norm"])
         y = _ffn(cfg, lpk, hn.reshape(bs * Sq, -1), spec, m, lay_exp,
-                 cap_factor=None, ep_axes=ep_axes, moe_backend=moe_backend)
+                 cap_factor=None, ep_axes=ep_axes, moe_backend=moe_backend,
+                 li=li)
         h = h + y.reshape(bs, Sq, -1).astype(h.dtype)
         pool = lax.dynamic_update_index_in_dim(pool, pool_l, li, axis=0)
         return (h, pool), None
 
     L = pool.shape[0]
     (x, new_pool), _ = lax.scan(
-        layer_fn, (x, pool), (pack["layers"], jnp.arange(L)))
+        layer_fn, (x, pool), (layers, jnp.arange(L)))
     x = apply_norm(cfg, x, pack["final_norm"])
     # sample at the last valid position of each slot
     last = jnp.clip(valid_len - 1, 0, Sq - 1)

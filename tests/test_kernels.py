@@ -82,12 +82,40 @@ def test_moe_gemm_matches_ref(E, C, D, W, dtype, seed):
     ks = jax.random.split(jax.random.PRNGKey(seed), 2)
     x = jax.random.normal(ks[0], (E, C, D), dt)
     w = jax.random.normal(ks[1], (E, W, D), dt)
-    out = grouped_matmul_pallas(x, w, block_c=64, block_w=64, interpret=True)
+    out = grouped_matmul_pallas(x, w[None], 0, block_c=64, block_w=64,
+                                interpret=True)
     ref = grouped_matmul_ref(x, w)
     tol = 1e-4 if dtype == "f32" else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol * D)
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("shape", ["w13", "w2"])
+@pytest.mark.parametrize("li", [0, 1, 2])
+def test_moe_gemm_reads_layer_of_stack(li, shape, dtype):
+    """The kernel (jitted, traced layer index) and the dispatcher's ref
+    read layer li of an (L=3, E, W, D) stack: both equal the oracle on
+    w_stack[li], the ref exactly, the kernel exactly in f32."""
+    from repro.kernels.moe_gemm.ops import grouped_matmul
+    dt = jnp.float32 if dtype == "f32" else jnp.bfloat16
+    E, C, D, I = 3, 24, 32, 48
+    W, Din = (2 * I, D) if shape == "w13" else (D, I)
+    ks = jax.random.split(jax.random.PRNGKey(7 * li + len(shape)), 2)
+    x = jax.random.normal(ks[0], (E, C, Din), dt)
+    w = jax.random.normal(ks[1], (3, E, W, Din), dt)
+    want = np.asarray(grouped_matmul_ref(x, w[li]), np.float32)
+    kern = jax.jit(lambda x, w, i: grouped_matmul_pallas(
+        x, w, i, interpret=True))
+    out = np.asarray(kern(x, w, jnp.int32(li)), np.float32)
+    ref = np.asarray(grouped_matmul(x, w, jnp.int32(li), backend="ref"),
+                     np.float32)
+    np.testing.assert_array_equal(ref, want)
+    if dtype == "f32":
+        np.testing.assert_array_equal(out, want)
+    else:
+        np.testing.assert_allclose(out, want, rtol=3e-2, atol=3e-2 * Din)
 
 
 @settings(**HYP)

@@ -73,9 +73,11 @@ def _attn(B, Sq):
 
 
 def _gmm(C, W, Dc):
+    """A two-layer weight stack and its layer-index operand."""
     E = CFG.num_experts
-    return (lambda x, w: grouped_matmul_pallas(x, w, interpret=False),
-            [((E, C, Dc), BF), ((E, W, Dc), BF)])
+    return (lambda x, w, li: grouped_matmul_pallas(x, w, li,
+                                                   interpret=False),
+            [((E, C, Dc), BF), ((2, E, W, Dc), BF), ((1,), I32)])
 
 
 # per-rank switch shapes: EP holds E/G whole experts, TP a 1/G width slice
@@ -126,3 +128,57 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_cache):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2**30
+
+
+def test_mixed_step_reads_expert_stacks_in_place(topo, no_cache,
+                                                 monkeypatch):
+    """The whole serve step at mixtral-8x7b widths, 2 layers, one chip,
+    rung 4 x chunk 64: the layer scan hands the grouped GEMM the stacked
+    expert weights, so the optimised HLO slices no layer's w13 or w2 out
+    of them and the step's temporaries stay below one layer's w13."""
+    import re
+
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.compat import make_mesh
+    from repro.kernels import dispatch
+    from repro.serving.kvcache import CacheConfig
+    from repro.serving.steps import (_pack_specs_for, _params_like,
+                                     build_decode_pack, build_mixed_step)
+
+    cfg = CFG.replace(num_layers=2, sliding_window=0,
+                      capacity_factor=CFG.num_experts / CFG.top_k)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    cc = CacheConfig(page_size=PAGE, pages_ep=64, max_pages_per_req=16)
+    B, Sq = 4, 64
+    # the process runs on the CPU; the kernels are compiled for the chip
+    monkeypatch.setattr(dispatch, "resolve_backend",
+                        lambda *a, **k: "pallas")
+    step = build_mixed_step(cfg, mesh, "tp", cc, B, Sq, donate=False)
+    pack = jax.eval_shape(lambda p: build_decode_pack(cfg, p, "tp", 1),
+                          _params_like(cfg, "tp", 1, 1))
+    specs = _pack_specs_for(cfg, "tp", 1, 1, "model", ("data", "model"))
+
+    def arg(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    pack = jax.tree.map(lambda a, s: arg(a.shape, a.dtype, s), pack, specs,
+                        is_leaf=lambda x: isinstance(x, P))
+    compiled = step.lower(
+        pack, arg((1, 1, *cc.rank_shape(cfg, 1)), BF, P("data", "model")),
+        arg((1, B, Sq), I32, P("data", None, None)),
+        arg((1, B), I32, P("data", None)), arg((1, B), I32, P("data", None)),
+        arg((1, B, cc.max_pages_per_req), I32, P("data", None, None)),
+        arg((2,), jnp.uint32, P())).compile()
+    moe = pack["layers"]["moe"]
+    layer_bytes = {int(np.prod(w.shape[1:])) * w.dtype.itemsize
+                   for w in (moe["w13"], moe["w2"])}
+    itemsize = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    for dt, dims in re.findall(r"= (\w+)\[([\d,]*)\]\{[^}]*\} dynamic-slice",
+                               compiled.as_text()):
+        n = int(np.prod([int(d) for d in dims.split(",") if d]))
+        assert n * itemsize.get(dt, 0) not in layer_bytes, (dt, dims)
+    w13_bytes = max(layer_bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < w13_bytes
