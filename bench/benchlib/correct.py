@@ -51,18 +51,18 @@ def packed(sample: list, pack: int):
     return toks, seg, np.asarray(idx, np.int32), np.asarray(want, np.int32)
 
 
-def check(conf: dict, weights, sample: list, pack: int,
+def check(arch, conf: dict, weights, sample: list, pack: int,
           control: bool = False) -> dict:
-    """Widest gap over the sample. With `control`, the reference in fp8 is
-    put in the program's place: at every position the gap of the token the
-    fp8 forward ranks first."""
-    from benchlib import reference
+    """Widest gap over the sample, by the reference of the architecture
+    module `arch`. With `control`, the reference in fp8 is put in the
+    program's place: at every position the gap of the token the fp8
+    forward ranks first."""
     toks, seg, idx, want = packed(sample, pack)
-    xr = reference.hidden(conf, weights, toks, seg, idx)
+    xr = arch.hidden(conf, weights, toks, seg, idx)
     if control:
-        xl = reference.hidden(conf, weights, toks, seg, idx, quant="fp8")
-        want = reference.head(conf, weights, xl, want, quant="fp8")[2]
-    best, picked, _ = reference.head(conf, weights, xr, want)
+        xl = arch.hidden(conf, weights, toks, seg, idx, quant="fp8")
+        want = arch.head(conf, weights, xl, want, quant="fp8")[2]
+    best, picked, _ = arch.head(conf, weights, xr, want)
     g = best - picked
     n = max(len(g), 1)
     return {"max_logit_gap": float(g.max()) if len(g) else float("inf"),

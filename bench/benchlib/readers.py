@@ -4,7 +4,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchlib import flops
 from benchlib import trace as tr
 
 
@@ -51,35 +50,30 @@ def dispatches(run):
 def mfu_pct(run):
     if run.trace is None or not run.trace.ops:
         return None
-    m = run.dims
-    work = sum(flops.forward(m, rows) for _, _, rows in dispatches(run))
+    work = sum(run.arch.forward(run.dims, rows)
+               for _, _, rows in dispatches(run))
     peak = run.chips * run.peaks["bf16_flops"]
     return work / (run.trace.window_s * peak) * 100.0
 
 
-def roofline_pct(run, kernel: str, cost):
+def roofline_pct(run, kernel: str):
     """Least time the chips could take for the kernel's useful work,
     step by step (the larger of operations over peak and bytes over
-    bandwidth), over the kernel's device time."""
-    if run.trace is None:
+    bandwidth), over the kernel's device time. The architecture's `costs`
+    count the work of the kernel by its trace name; an architecture that
+    has no such kernel, or a trace without it, gives None."""
+    cost = run.arch.costs.get(kernel)
+    if run.trace is None or cost is None:
         return None
     t = tr.kernel_s(run.trace, kernel)
     if t <= 0:
         return None
-    m, pk = run.dims, run.peaks
+    pk = run.peaks
     bound = 0.0
     for _, _, rows in dispatches(run):
-        f, b = cost(m, rows)
+        f, b = cost(run.dims, rows)
         bound += max(f / pk["bf16_flops"], b / pk["hbm_bytes_per_s"])
     return bound / run.chips / t * 100.0
-
-
-def moe_gemm_roofline(run):
-    return roofline_pct(run, "moe_grouped_matmul", flops.moe_gemm)
-
-
-def paged_attn_roofline(run):
-    return roofline_pct(run, "paged_attention", flops.paged_attention)
 
 
 def idle_pct(run):

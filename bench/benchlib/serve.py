@@ -16,45 +16,17 @@ from collections import deque
 from dataclasses import dataclass, field
 
 
-def model_config(conf: dict):
-    """The program's ModelConfig for a configuration file (Hugging Face
-    key names). Dropless expert capacity: capacity_factor = E / k."""
-    import jax.numpy as jnp
-
-    from repro.models.common import ModelConfig
-    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
-        conf["torch_dtype"]]
-    E = conf.get("num_local_experts") or conf.get("num_experts") or 0
-    k = conf["num_experts_per_tok"]
-    if not conf.get("norm_topk_prob", True):
-        raise ValueError("the program renormalises the top-k gates; a "
-                         "configuration without norm_topk_prob cannot run")
-    return ModelConfig(
-        name=conf["name"], family="moe",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim") or 0,
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        num_experts=E, top_k=k,
-        d_expert=conf.get("moe_intermediate_size")
-        or conf["intermediate_size"],
-        capacity_factor=E / k, qk_norm=bool(conf.get("qk_norm")),
-        sliding_window=conf.get("sliding_window") or 0,
-        rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        param_dtype=dtype, compute_dtype=dtype)
-
-
-def build(conf: dict, seed: int, backend: str | None = None):
-    """Engine under test, built through the launcher's construction path."""
+def build(arch, conf: dict, seed: int, backend: str | None = None):
+    """Engine under test for the configuration file `conf`, built through
+    the launcher's construction path; `arch` is the file's architecture
+    module (bench/arch), which gives the program's ModelConfig."""
     from repro.launch.serve import build_engine
     from repro.serving.kvcache import CacheConfig
     e = conf["engine"]
     cc = CacheConfig(page_size=e["page_size"], pages_ep=e["pages_ep"],
                      max_pages_per_req=e["max_pages_per_req"])
     return build_engine(
-        model_config(conf), mesh=conf["mesh"], layouts=e["layouts"],
+        arch.model_config(conf), mesh=conf["mesh"], layouts=e["layouts"],
         policy=e["policy"], t_high=e.get("t_high"), cache=cc,
         ladder=tuple(e["ladder"]), prefill_chunk=e["prefill_chunk"],
         token_budget=e["token_budget"], chunk_layers=e["chunk_layers"],

@@ -5,4 +5,4 @@ from benchlib import readers
 
 
 def read(run):
-    return readers.moe_gemm_roofline(run)
+    return readers.roofline_pct(run, "moe_grouped_matmul")

@@ -5,4 +5,4 @@ from benchlib import readers
 
 
 def read(run):
-    return readers.paged_attn_roofline(run)
+    return readers.roofline_pct(run, "paged_attention")

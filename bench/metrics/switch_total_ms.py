@@ -1,5 +1,5 @@
 """Switch: mean `SwitchRecord.total_s` over the switches in the window, the
-each live switch from plan to commit, chunks included (ms).
+time of each live switch from plan to commit, chunks included (ms).
 """
 
 

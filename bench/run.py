@@ -7,8 +7,11 @@
 The cell (an entry of BENCHMARK.json's `workloads`) names a configuration
 (its file under bench/configs), a traffic mix (bench/traffic/<mix>.json) and
 the chips it needs; its correctness limit is bench/limits/<cell>.json and
-each metric is read by bench/metrics/<metric>.py. Adding a cell or a metric
-adds files and entries and edits none.
+each metric is read by bench/metrics/<metric>.py. The configuration file's
+`"arch"` names its architecture module, bench/arch/<arch>.py: the program's
+model configuration, the work counts and the plain reference of that block
+(bench/arch/topk_moe.py states the contract). Adding a cell, a metric or an
+architecture adds files and entries and edits none.
 
 Set-up builds the engine through the launcher (weights made on the device
 from the seed), warms the cell's own ladder and chunk shapes, and keeps
@@ -57,6 +60,7 @@ class Cell:
     mix: dict             # traffic file
     limits: dict          # correctness limits file
     metrics: list         # BENCHMARK.json entries this run reports
+    arch: object          # the configuration's architecture module
 
 
 def load_cell(name: str, trace: bool, root: Path = ROOT) -> Cell:
@@ -75,13 +79,13 @@ def load_cell(name: str, trace: bool, root: Path = ROOT) -> Cell:
     else:
         metrics = e2e
     bench = root / "bench"
+    conf = json.loads((root / entry["file"]).read_text())
     return Cell(
-        name=name, chips=cell["chips"],
-        conf=json.loads((root / entry["file"]).read_text()),
+        name=name, chips=cell["chips"], conf=conf,
         mix=json.loads((bench / "traffic" / f"{cell['traffic']}.json")
                        .read_text()),
         limits=json.loads((bench / "limits" / f"{name}.json").read_text()),
-        metrics=metrics)
+        metrics=metrics, arch=load_arch(conf["arch"]))
 
 
 def reader(name: str, root: Path = ROOT):
@@ -93,11 +97,17 @@ def reader(name: str, root: Path = ROOT):
     return mod.read
 
 
+def load_arch(name: str):
+    """The architecture module bench/arch/<name>.py."""
+    return importlib.import_module(f"arch.{name}")
+
+
 @dataclass
 class Run:
     """What a metric reader sees."""
     conf: dict
-    dims: object          # flops.Dims
+    arch: object          # the configuration's architecture module
+    dims: object          # arch.dims(conf)
     peaks: dict
     chips: int
     setup_s: float
@@ -143,7 +153,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     and judges it by the cell's limits into `control_correct`."""
     import jax
 
-    from benchlib import correct, device, flops, reference, serve, traffic
+    from benchlib import correct, device, serve, traffic
     from benchlib import trace as tr
     from repro.launch.serve import use_compile_cache
 
@@ -156,7 +166,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     conf = cell.conf
     wseed = seed % 2**31
     t0 = time.perf_counter()
-    eng = serve.build(conf, wseed, backend)
+    eng = serve.build(cell.arch, conf, wseed, backend)
     t1 = time.perf_counter()
     eng.warmup()
     t2 = time.perf_counter()
@@ -186,9 +196,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         from repro.kernels import dispatch
         backends = {f"{op}[{b}]": n
                     for (op, b), n in sorted(dispatch.COUNTS.items())}
-    run = Run(conf=conf, dims=flops.Dims.of(conf), peaks=peaks,
-              chips=cell.chips, setup_s=win.t_open - t_start, window=win,
-              trace=tr_)
+    run = Run(conf=conf, arch=cell.arch, dims=cell.arch.dims(conf),
+              peaks=peaks, chips=cell.chips, setup_s=win.t_open - t_start,
+              window=win, trace=tr_)
     if keep is not None:
         keep["run"] = run
     metrics = {}
@@ -207,10 +217,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     free_device_memory()
     lim = cell.limits
     sample = correct.pick(served, seed, lim["pack_tokens"])
-    weights = reference.make_weights(conf, wseed, devices)
-    res = correct.check(conf, weights, sample, lim["pack_tokens"])
-    low = (correct.check(conf, weights, sample, lim["pack_tokens"],
-                         control=True) if control else None)
+    weights = cell.arch.make_weights(conf, wseed, devices)
+    res = correct.check(cell.arch, conf, weights, sample, lim["pack_tokens"])
+    low = (correct.check(cell.arch, conf, weights, sample,
+                         lim["pack_tokens"], control=True)
+           if control else None)
     del weights
     free_device_memory()
     compared = {k: {"value": res[k], "limit": v}

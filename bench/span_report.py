@@ -65,7 +65,7 @@ def traced_window(cell, seed: int, seconds: float, *, chip: bool = True,
         device.require_tpu(cell.chips)
     use_compile_cache(bench_run.ROOT)
     counter = device.CompileCounter()
-    eng = serve.build(cell.conf, seed % 2**31, backend)
+    eng = serve.build(cell.arch, cell.conf, seed % 2**31, backend)
     eng.warmup()
     reqs = traffic.generate(cell.mix, seconds, seed, cell.conf["vocab_size"])
     logdir = tempfile.mkdtemp(prefix="bench_spans_")

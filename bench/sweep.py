@@ -49,7 +49,7 @@ def main(argv=None) -> int:
     device.require_tpu(cell.chips)
     use_compile_cache(bench_run.ROOT)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    eng = serve.build(cell.conf, args.seed % 2**31)
+    eng = serve.build(cell.arch, cell.conf, args.seed % 2**31)
     eng.warmup()
     knee = None
     for i, rate in enumerate(sorted(float(r) for r in args.rates.split(","))):
@@ -59,9 +59,9 @@ def main(argv=None) -> int:
         reqs = traffic.generate(mix, args.seconds, args.seed + i,
                                 cell.conf["vocab_size"])
         win = serve.run_window(eng, reqs, args.seconds, "first_token")
-        run = bench_run.Run(conf=cell.conf, dims=None, peaks={},
-                            chips=cell.chips, setup_s=0.0, window=win,
-                            trace=None)
+        run = bench_run.Run(conf=cell.conf, arch=cell.arch, dims=None,
+                            peaks={}, chips=cell.chips, setup_s=0.0,
+                            window=win, trace=None)
         tt = [(r.times[0] - r.due) * 1e3 for r in win.sent if r.times]
         third = args.seconds / 3
 

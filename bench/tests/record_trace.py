@@ -35,7 +35,7 @@ def main(argv=None) -> int:
     cell = bench_run.load_cell(args.workload, True)
     device.require_tpu(cell.chips)
     use_compile_cache(bench_run.ROOT)
-    eng = serve.build(cell.conf, 7)
+    eng = serve.build(cell.arch, cell.conf, 7)
     eng.warmup()
     reqs = traffic.generate(cell.mix, args.seconds, 7,
                             cell.conf["vocab_size"])

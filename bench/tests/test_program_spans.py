@@ -79,8 +79,8 @@ def test_request_stamps(chat_window):
     assert served
     for q in served:
         assert q.arrival_s <= q.prefill_start_s <= q.first_token_s
-    run = bench_run.Run(conf={}, dims=None, peaks={}, chips=1, setup_s=0.0,
-                        window=win, trace=None)
+    run = bench_run.Run(conf={}, arch=None, dims=None, peaks={}, chips=1,
+                        setup_s=0.0, window=win, trace=None)
     assert spans.prefill_ms_p95(run) > 0
 
 

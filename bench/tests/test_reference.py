@@ -5,17 +5,18 @@ import jax
 import numpy as np
 import pytest
 
+import run as bench_run
 import tiny
-from benchlib import reference, serve
 
 
 @pytest.mark.parametrize("name", ["mixtral-8x7b-l4", "qwen3-235b-a22b-l1"])
 def test_weights_match_the_program(name):
     from repro.models.registry import init_params
     conf = tiny.conf(name)
+    arch = bench_run.load_arch(conf["arch"])
     key = jax.random.PRNGKey(2**31 - 5)
-    ours = reference._init(conf, key)
-    theirs = init_params(serve.model_config(conf), key)
+    ours = arch.init(conf, key)
+    theirs = init_params(arch.model_config(conf), key)
     pairs = [(ours["embed"], theirs["embed"]),
              (ours["lm_head"], theirs["lm_head"]),
              (ours["final_norm"], theirs["final_norm"]["scale"])]

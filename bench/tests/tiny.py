@@ -52,11 +52,14 @@ def switch_cell():
     """The four-chip switch cell's files at tiny widths on a 1x4 mesh with
     tp and ep resident (4 layers, so the reference spreads one layer per
     device), chunked live switches with warm movers, and a burst that
-    takes in-flight requests well past the threshold and back."""
+    takes in-flight requests well past the threshold and back. The token
+    budget is the top rung times the chunk, as in the file."""
     import run as bench_run
     c = conf("mixtral-8x7b-l8-tpep4", torch_dtype="float32",
              num_hidden_layers=4)
-    c["engine"].update(t_high=4, ladder=[4, 8])
+    e = c["engine"]
+    e.update(t_high=4, ladder=[4, 8])
+    e["token_budget"] = e["ladder"][-1] * e["prefill_chunk"]
     m = mix("bursty-switch")
     quiet, burst, after = m["arrivals"]["phases"]
     quiet["rate"], burst["rate"], after["rate"] = 1.0, 18.0, 1.0
@@ -67,4 +70,5 @@ def switch_cell():
         metrics=[{"name": n, "unit": u} for n, u in (
             ("ttft_p95_ms", "ms"), ("tpot_p95_ms", "ms"),
             ("switch_pause_ms", "ms"), ("switch_total_ms", "ms"),
-            ("setup_s", "s"))])
+            ("setup_s", "s"))],
+        arch=bench_run.load_arch(c["arch"]))
