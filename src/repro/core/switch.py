@@ -51,7 +51,7 @@ from repro.models.common import ModelConfig
 from repro.models.moe import (ExpertLayout, make_expert_layout, pack_experts,
                               pack_w13, unpack_experts, unpack_w13)
 from repro.serving.kvcache import (CacheConfig, CacheMove, PageAllocator,
-                                   PrefixCache, pages_needed)
+                                   PrefixCache, num_kv_layers, pages_needed)
 
 
 # ---------------------------------------------------------------------------
@@ -78,6 +78,50 @@ def pair_expert_layouts(cfg: ModelConfig, src, dst, G: int,
     """Source/destination rank-major ExpertLayouts of a src->dst switch."""
     src, dst = get_layout(src), get_layout(dst)
     return (src.expert_layout(cfg, G, chips), dst.expert_layout(cfg, G, chips))
+
+
+def _expert_cells(lay: ExpertLayout, chip: int, E: int, I: int) -> tuple:
+    """(expert range, width-column range) chip `chip` holds under `lay`:
+    rank chip % G, whose (ep, tp) block is (rank // tp_inner, rank %
+    tp_inner), as `pack_experts` orders ranks."""
+    r = chip % lay.G
+    e, t = r // lay.tp_inner, r % lay.tp_inner
+    ne, nw = E // lay.ep, I // lay.tp_inner
+    return (e * ne, (e + 1) * ne), (t * nw, (t + 1) * nw)
+
+
+def expert_bytes_sent(cfg: ModelConfig, src_lay: ExpertLayout,
+                      dst_lay: ExpertLayout, chips: int,
+                      itemsize: int) -> int:
+    """Bytes of the expert store the busiest chip sends in a src->dst
+    reshard. A cell (expert, width column) holds 3 * D elements a layer (its
+    gate and up rows of w13, its column of w2); a chip sends the cells it
+    holds in src and not in dst, which every other cell's new owner lacks.
+    tp->ep on G chips: E*I/G cells held, (E/G)*(I/G) kept, so a chip sends
+    E*I*(G-1)/G^2 cells; ep->tp the same."""
+    E, I = cfg.num_experts, cfg.d_expert
+    worst = 0
+    for c in range(chips):
+        (se, sw), (de, dw) = (_expert_cells(lay, c, E, I)
+                              for lay in (src_lay, dst_lay))
+        keep = (max(0, min(se[1], de[1]) - max(se[0], de[0]))
+                * max(0, min(sw[1], dw[1]) - max(sw[0], dw[0])))
+        worst = max(worst, (se[1] - se[0]) * (sw[1] - sw[0]) - keep)
+    return worst * 3 * cfg.d_model * cfg.num_layers * itemsize
+
+
+def kv_bytes_sent(cfg: ModelConfig, cc: CacheConfig, G: int, chips: int,
+                  kv_dir: str, pages: int, itemsize: int) -> int:
+    """Bytes of `pages` moved KV pages a chip sends, spread evenly over
+    `chips` (the planner balances pages over ranks). A page holds K heads
+    of K and V in every KV layer; tp->ep its owner lacks the K - K/G heads
+    other ranks hold, ep->tp each of the G - 1 other ranks lacks its K/G
+    (kv_local) heads."""
+    gi = group_info(cfg, G)
+    heads = (cfg.num_kv_heads - gi.kv_local if kv_dir == "tp_to_ep"
+             else (G - 1) * gi.kv_local)
+    per_head = num_kv_layers(cfg) * 2 * cc.page_size * cfg.dh * itemsize
+    return -(-pages * heads * per_head // chips)
 
 
 # ---------------------------------------------------------------------------

@@ -47,8 +47,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.layouts import EP, TP, get_layout, group_info
-from repro.core.switch import (apply_assignments,
-                               expert_pair_dst_struct, kv_migration_direction,
+from repro.core.switch import (apply_assignments, expert_bytes_sent,
+                               expert_pair_dst_struct, kv_bytes_sent,
+                               kv_migration_direction,
                                make_migrate_kv, make_migrate_kv_chunk,
                                make_reshard_experts_direct,
                                make_reshard_experts_direct_chunk,
@@ -91,6 +92,9 @@ class SwitchStats:
     delta_pages: int = 0
     chunks: int = 1
     live_requests: int = 0
+    bytes_sent: int = 0     # owner-changed expert bytes the busiest chip
+                            # sends + an even share of the moved pages'
+                            # (0 across worlds: those movers use the host)
 
 
 @dataclass
@@ -268,6 +272,24 @@ class SwitchExecutor:
         arrays, pmax = self._stack_plans(plans)
         return arrays, pmax, assignments, new_alloc, kv_dir, cache_moves
 
+    def _bytes_sent(self, src, dst, kv_dir, kv_pages: int,
+                    kv_itemsize: int) -> int:
+        """Owner-changed bytes a chip's movers send for a src->dst switch
+        moving `kv_pages` pages (the plan's, and at commit the dirty-page
+        delta's too), counted from the plan: the busiest chip's expert
+        slices (switch.expert_bytes_sent) and an even share over the chips
+        of the pages' head slices (switch.kv_bytes_sent)."""
+        n = 0
+        if self.cfg.is_moe:
+            src_lay, dst_lay = pair_expert_layouts(self.cfg, src, dst,
+                                                   self.G, self.chips)
+            n += expert_bytes_sent(self.cfg, src_lay, dst_lay, self.chips,
+                                   jnp.dtype(self.cfg.param_dtype).itemsize)
+        if self.Lk > 0 and kv_dir is not None:
+            n += kv_bytes_sent(self.cfg, self.cc, self.G, self.chips, kv_dir,
+                               kv_pages, kv_itemsize)
+        return n
+
     # ------------------------------------------------------------------
     # monolithic mode (the baseline; pause == total)
     # ------------------------------------------------------------------
@@ -309,10 +331,14 @@ class SwitchExecutor:
                 new_caches = [PrefixCache.rebuild(new_alloc[d], cache_moves[d])
                               for d in range(self.Dd)]
             total = time.perf_counter() - t0
+            kv_pages = int(vm.sum())
             stats = SwitchStats(direction=f"{src}_to_{dst}", total_s=total,
                                 pause_s=total, plan_s=t_plan, weights_s=t_w,
-                                kv_s=t_kv, kv_pages=int(vm.sum()), chunks=1,
-                                live_requests=len(live))
+                                kv_s=t_kv, kv_pages=kv_pages, chunks=1,
+                                live_requests=len(live),
+                                bytes_sent=self._bytes_sent(
+                                    src, dst, kv_dir, kv_pages,
+                                    kv_flat.dtype.itemsize))
         return experts, kv_flat, new_alloc, new_caches, stats
 
     # ------------------------------------------------------------------
@@ -582,7 +608,10 @@ class SwitchExecutor:
                 pause_s=s.plan_pause_s + (now - t_pause0),
                 plan_s=s.plan_pause_s, kv_pages=s.kv_pages,
                 delta_pages=delta_pages, chunks=len(s.chunks),
-                live_requests=s.live_requests)
+                live_requests=s.live_requests,
+                bytes_sent=self._bytes_sent(
+                    s.src, s.dst, s.kv_dir, s.kv_pages + delta_pages,
+                    kv_flat.dtype.itemsize))
             out = (s.experts_dst,
                    s.kv_dst if s.kv_dst is not None else kv_flat,
                    s.new_alloc, new_caches, stats)

@@ -138,6 +138,11 @@ class SwitchRecord:
                                        # for a monolithic switch)
     chunks: int = 1
     delta_pages: int = 0
+    bytes_sent: int = 0                # owner-changed bytes a chip's
+                                       # movers send: the busiest chip's
+                                       # expert slices + an even share of
+                                       # the pages (plan + delta; 0 across
+                                       # worlds, via the host)
 
 
 class MoebiusEngine:
@@ -438,8 +443,11 @@ class MoebiusEngine:
         assert target is not self.active, "switch target == active layout"
         assert target in self.layouts, \
             f"layout {target} not resident (EngineConfig.layouts)"
-        with span("switch", direction=f"{self.active}_to_{target}"):
-            return self._execute_switch(target)
+        with span("switch", direction=f"{self.active}_to_{target}") as sp:
+            done = self._execute_switch(target)
+            if done:
+                sp.set_metadata(bytes_sent=self.switch_records[-1].bytes_sent)
+            return done
 
     def _execute_switch(self, target: LayoutSpec) -> bool:
         cross_world = self.ex._is_cross_world(target)
@@ -469,7 +477,8 @@ class MoebiusEngine:
                 t=self.now(), direction=st.direction, total_s=st.total_s,
                 weights_s=st.weights_s, kv_s=st.kv_s, plan_s=st.plan_s,
                 kv_pages=st.kv_pages, live_requests=st.live_requests,
-                pause_s=st.pause_s, chunks=st.chunks)
+                pause_s=st.pause_s, chunks=st.chunks,
+                bytes_sent=st.bytes_sent)
         self.switch_records.append(rec)
         self.metrics.switch(rec.t, rec.direction, rec.pause_s, rec.total_s)
         if cross_world:
@@ -544,7 +553,7 @@ class MoebiusEngine:
             weights_s=0.0, kv_s=0.0, plan_s=st.plan_s,
             kv_pages=st.kv_pages, live_requests=st.live_requests,
             pause_s=st.pause_s, chunks=st.chunks,
-            delta_pages=st.delta_pages)
+            delta_pages=st.delta_pages, bytes_sent=st.bytes_sent)
 
     # ------------------------------------------------------------------
     # fault tolerance (DESIGN.md §12)

@@ -14,7 +14,7 @@ The step's span tree (DESIGN.md §9):
     moebius.step {step, B, Sq, dec, pre}
       moebius.sched.admit         admission, deadline expiry, prefill starts
       moebius.policy              queue snapshot + coordinator
-      moebius.switch {direction}  a live switch, when taken
+      moebius.switch {direction, bytes_sent}  a live switch, when taken
         moebius.switch.plan       plan and stage (decode paused)
         moebius.switch.chunk {i}  one layer chunk; overlap decode beside it
         moebius.switch.commit     dirty-page delta + commit (decode paused)
@@ -32,7 +32,8 @@ two-phase and in-switch dispatches carry theirs on `exec.stage`.
 
 The switch spans sit at the bounds of `SwitchStats`' timers, so plan +
 commit is the switch's `pause_s`; a monolithic switch has no chunks and
-its whole migration is the commit.
+its whole migration is the commit. `bytes_sent`, set when the switch
+commits, is its `SwitchRecord.bytes_sent`.
 """
 from __future__ import annotations
 

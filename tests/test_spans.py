@@ -143,7 +143,7 @@ def test_span_records_nothing_outside_a_session():
 
 def test_switch_spans_match_pause():
     """On four CPU devices: a live chunked switch each way opens
-    `moebius.switch {direction}` over one `switch.plan`, one
+    `moebius.switch {direction, bytes_sent}` over one `switch.plan`, one
     `switch.chunk {i}` per chunk and one `switch.commit`, and plan +
     commit is the switch's recorded pause."""
     out = run_multidevice("""
@@ -194,6 +194,7 @@ for s, rec in zip(sw, eng.switch_records[-2:]):
     assert names[-1] == "moebius.switch.commit", names
     chunks = [k for k in kids if k[0] == "moebius.switch.chunk"]
     assert [c[3]["i"] for c in chunks] == list(range(rec.chunks))
+    assert s[3]["bytes_sent"] == rec.bytes_sent > 0, (s[3], rec)
     pc = sum(k[2] - k[1] for k in kids if k[0] in (
         "moebius.switch.plan", "moebius.switch.commit")) * 1e-9
     print("pause", rec.pause_s, "plan+commit", pc)
