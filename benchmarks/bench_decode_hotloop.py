@@ -206,7 +206,8 @@ def _skew_rows(smoke: bool, seed: int = 0):
                           preferred_element_type=jnp.float32)
 
     grouped_ffn = jax.jit(
-        lambda xd: _grouped_ffn_local(cfg, w13[None], w2[None], 0, xd))
+        lambda xd, n: _grouped_ffn_local(cfg, w13[None], w2[None], 0, xd,
+                                         n)[0])
     einsum_ffn = jax.jit(einsum_ffn)
 
     rows = []
@@ -216,10 +217,12 @@ def _skew_rows(smoke: bool, seed: int = 0):
         # serving headroom); hotC: the worst-case bucket a hot expert
         # forces you to provision (factor E)
         for cap, capf in (("balC", 2.0), ("hotC", float(E))):
-            xd, _, _, dropped = routed_dispatch(cfg, rw, x, cap_factor=capf)
+            xd, disp, _, dropped = routed_dispatch(cfg, rw, x,
+                                                   cap_factor=capf)
+            n = disp.sum(axis=(0, 2)).astype(jnp.int32)   # rows per expert
             t_e = time_call(einsum_ffn, xd, warmup=2, iters=5)
-            t_g = time_call(grouped_ffn, xd, warmup=2, iters=5)
-            same = bool(jnp.array_equal(einsum_ffn(xd), grouped_ffn(xd)))
+            t_g = time_call(grouped_ffn, xd, n, warmup=2, iters=5)
+            same = bool(jnp.array_equal(einsum_ffn(xd), grouped_ffn(xd, n)))
             rows.append((
                 f"decode_hotloop.skew.{label}.{cap}.grouped_us", t_g * 1e6,
                 f"einsum_us={t_e*1e6:.1f} C={xd.shape[1]} "

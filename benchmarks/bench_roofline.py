@@ -90,8 +90,10 @@ def _cases(smoke: bool):
         w = jnp.ones((1, E, W, D), jnp.float32)
         fl = 2.0 * E * C * W * D
         by = 4.0 * (E * C * D + E * W * D + E * C * W)
+        n = jnp.full((E,), C, jnp.int32)
         return (lambda bk: jax.jit(
-            lambda a, b: grouped_matmul(a, b, 0, backend=bk))), (x, w), fl, by
+            lambda a, b: grouped_matmul(a, b, 0, n, backend=bk))), (x, w), \
+            fl, by
 
     def kv_pack():
         from repro.kernels.kv_pack.ops import gather_pages_rows

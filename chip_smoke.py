@@ -146,13 +146,17 @@ def kernel_parity(cfg, backend=None, *, G: int = 4, page: int = 16,
                             backend=backend),
             paged_attention_ref(*args, q_offset=qo, window=window))
     # a two-layer stack read at layer 1 (layer 0 zeros): the kernel must
-    # take its tiles from the layer the index names
+    # take its tiles from the layer the index names, and only the rows the
+    # counts route (0 to all 32, some experts none; the rest zero)
+    counts = (jnp.arange(E, dtype=jnp.int32) * 13) % 33
+    routed = jnp.arange(32)[None, :, None] < counts[:, None, None]
     for name, (w_out, w_in) in (("w13", (2 * I, D)), ("w2", (D, I))):
         x, wl = rnd(E, 32, w_in), rnd(E, w_out, w_in)
         w = jnp.stack([jnp.zeros_like(wl), wl])
         errs[f"grouped_matmul_{name}"] = _close(
-            "grouped_matmul", grouped_matmul(x, w, 1, backend=backend),
-            grouped_matmul_ref(x, wl))
+            "grouped_matmul",
+            grouped_matmul(x, w, 1, counts, backend=backend),
+            jnp.where(routed, grouped_matmul_ref(x, wl), 0))
         del x, wl, w
     # duplicate-free page lists (scatter is unspecified on duplicates)
     idx = jax.random.permutation(next(keys), pages)[:8].astype(jnp.int32)

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.kernels.moe_gemm.ops import grouped_matmul
+from repro.kernels.moe_gemm.ops import grouped_matmul, weight_passes
 from repro.models.common import ModelConfig, dense_init, split_keys
 
 
@@ -266,31 +266,39 @@ def shared_expert_forward(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
 # Explicit per-rank decode paths (inside shard_map over `axis`)
 # ---------------------------------------------------------------------------
 
-def _grouped_ffn_local(cfg: ModelConfig, w13, w2, li, xd, *,
+def _grouped_ffn_local(cfg: ModelConfig, w13, w2, li, xd, counts, *,
                        backend: str | None = None):
-    """xd (E_loc, C, D); w13 (L, E_loc, W13_loc, D), w2 (L, E_loc, D,
-    W2_loc): the layer stacks, computed at layer li.
+    """xd (E_loc, C, D) with counts (E_loc,) routed rows at the front of
+    each expert's C slots; w13 (L, E_loc, W13_loc, D), w2 (L, E_loc, D,
+    W2_loc): the layer stacks, computed at layer li. Returns the (E_loc,
+    C, D) fp32 output and the (2,) weight passes of both GEMMs
+    (`weight_passes`: the kernel's, the dense grid's).
 
     Both GEMMs route through kernels/moe_gemm.grouped_matmul, which reads
-    layer li's tiles straight from the stack (no per-layer copy); w2 stores
-    its width axis last, so the same (E,C,D)x(E,W,D)->(E,C,W) contraction
-    fits both.  With fp32 compute_dtype the ref backend is bit-identical to
-    the old inline einsums; sub-fp32 compute pays one fp32->compute
-    round-trip per GEMM on the kernel path (tolerance policy: DESIGN.md
-    §14).
+    layer li's tiles straight from the stack (no per-layer copy) and only
+    the experts and rows the counts name; w2 stores its width axis last,
+    so the same (E,C,D)x(E,W,D)->(E,C,W) contraction fits both.  With fp32
+    compute_dtype the ref backend is bit-identical to the old inline
+    einsums; sub-fp32 compute pays one fp32->compute round-trip per GEMM
+    on the kernel path (tolerance policy: DESIGN.md §14).
     """
-    h = grouped_matmul(xd, w13, li, backend=backend).astype(jnp.float32)
+    h = grouped_matmul(xd, w13, li, counts,
+                       backend=backend).astype(jnp.float32)
     hg, hu = jnp.split(h, 2, axis=-1)
     h = (jax.nn.silu(hg) * hu).astype(cfg.compute_dtype)
-    return grouped_matmul(h, w2, li, backend=backend).astype(jnp.float32)
+    y = grouped_matmul(h, w2, li, counts, backend=backend)
+    passes = weight_passes(xd, w13, counts) + weight_passes(h, w2, counts)
+    return y.astype(jnp.float32), passes
 
 
 def moe_decode_tp(cfg: ModelConfig, p: dict, x: jax.Array, axis: str | None,
                   *, li, cap_factor: float | None = None,
-                  moe_backend: str | None = None):
+                  moe_backend: str | None = None, passes: list | None = None):
     """TP decode: x (T, D) replicated over `axis`; w13/w2 are this rank's
     (L, E, W_loc) layer stacks (G dim already squeezed), used at layer li.
     Output is a *partial* sum — caller psums together with attention output.
+    A `passes` list receives this rank's (2,) expert weight passes
+    (`_grouped_ffn_local`).
     """
     T, D = x.shape
     E = cfg.num_experts
@@ -302,8 +310,11 @@ def moe_decode_tp(cfg: ModelConfig, p: dict, x: jax.Array, axis: str | None,
     disp, _ = _dispatch_tensors(khot, jnp.zeros((E,), jnp.float32), C)
     xd = jnp.einsum("tec,td->ecd", disp,
                     x.astype(jnp.float32)).astype(cfg.compute_dtype)
-    y = _grouped_ffn_local(cfg, p["w13"], p["w2"], li, xd,
-                           backend=moe_backend)              # partial over axis
+    counts = jnp.minimum(khot.sum(0), C).astype(jnp.int32)
+    y, ps = _grouped_ffn_local(cfg, p["w13"], p["w2"], li, xd, counts,
+                               backend=moe_backend)          # partial over axis
+    if passes is not None:
+        passes.append(ps)
     out = jnp.einsum("tec,ecd->td", disp * gate_full[..., None], y)
     out = out.astype(cfg.compute_dtype)
     if cfg.num_shared_experts:
@@ -314,13 +325,15 @@ def moe_decode_tp(cfg: ModelConfig, p: dict, x: jax.Array, axis: str | None,
 
 def moe_decode_ep(cfg: ModelConfig, p: dict, x: jax.Array, axis: str,
                   lay: ExpertLayout, *, li, cap_factor: float | None = None,
-                  moe_backend: str | None = None):
+                  moe_backend: str | None = None, passes: list | None = None):
     """EP decode under shard_map: x (T_loc, D) is this rank's token slice;
     w13/w2 are this rank's (L, E_loc, ...) layer stacks, used at layer li.
 
     Dispatch entries (token, k, tp-replica) -> per-dest buffers -> all_to_all
     -> local grouped FFN -> inverse all_to_all -> gate-weighted combine.
     Pure EP when lay.tp_inner == 1; hybrid otherwise (partials sum in combine).
+    A `passes` list receives this rank's (2,) expert weight passes
+    (`_grouped_ffn_local`).
     """
     T, D = x.shape
     E, k = cfg.num_experts, cfg.top_k
@@ -366,8 +379,11 @@ def moe_decode_ep(cfg: ModelConfig, p: dict, x: jax.Array, axis: str,
                            dtype=jnp.float32)                 # (G*Cd, C2)
     xd = jnp.einsum("te,tc,td->ecd", ehot_f, slot2,
                     rx.reshape(G * Cd, D)).astype(cfg.compute_dtype)
-    y = _grouped_ffn_local(cfg, p["w13"], p["w2"], li, xd,
-                           backend=moe_backend)               # (E_loc,C2,D)
+    counts = ehot_f.sum(0).astype(jnp.int32)
+    y, ps = _grouped_ffn_local(cfg, p["w13"], p["w2"], li, xd, counts,
+                               backend=moe_backend)           # (E_loc,C2,D)
+    if passes is not None:
+        passes.append(ps)
     y_back = jnp.einsum("te,tc,ecd->td", ehot_f, slot2,
                         y.astype(jnp.float32)).reshape(G, Cd, D)
     y_ret = lax.all_to_all(y_back, axis, split_axis=0, concat_axis=0,

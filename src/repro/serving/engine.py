@@ -700,6 +700,7 @@ class MoebiusEngine:
         self._step_i += 1
         m = self.metrics
         dec0, pre0 = m.decode_tokens, m.prefill_tokens
+        passes0 = m.moe_weight_passes
         with span("step", step=self._step_i) as sp:
             with span("sched.admit"):
                 if self.ecfg.idle_skip:
@@ -743,7 +744,8 @@ class MoebiusEngine:
                 m.pages_resident(sum(a.total_held()
                                      for a in self.sched.alloc))
             sp.set_metadata(B=B, Sq=Sq, dec=m.decode_tokens - dec0,
-                            pre=m.prefill_tokens - pre0)
+                            pre=m.prefill_tokens - pre0,
+                            passes=m.moe_weight_passes - passes0)
 
     def run(self, max_steps: int = 100000):
         for _ in range(max_steps):

@@ -466,9 +466,10 @@ class Executor:
                     jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(vl),
                     jnp.asarray(bt), self._step_key(step_i))
         with span("exec.launch"):
-            nxt, self.kv_flat, *logits = fn(*args)
+            nxt, self.kv_flat, passes, *logits = fn(*args)
         with span("exec.fetch"):
-            out = np.asarray(nxt)
+            out, passes = jax.device_get((nxt, passes))
+            self.metrics.weight_passes(passes)
             if logits:
                 lg = np.asarray(logits[0])
                 for row in plan.rows:
@@ -557,7 +558,7 @@ class Executor:
                 self.drain_decode()        # nothing live; flush the pipeline
                 return
             fn = self._decode_loop_fn(self.active, st.B, N)
-            out, self.kv_flat, tok, pos, bud = fn(
+            out, self.kv_flat, tok, pos, bud, passes = fn(
                 self._assemble_pack(self.active), self.kv_flat, st.tokens,
                 st.positions, st.budgets, st.block_tables,
                 self._step_key(step_i))
@@ -565,8 +566,9 @@ class Executor:
             # start the device->host copy now; the tokens are read one
             # engine iteration later, so host dispatch runs ahead of the
             # device
-            if hasattr(out, "copy_to_host_async"):
-                out.copy_to_host_async()
+            for a in (out, passes):
+                if hasattr(a, "copy_to_host_async"):
+                    a.copy_to_host_async()
             total = 0
             for d, s, r, steps in plan:
                 r.inflight += steps
@@ -574,7 +576,7 @@ class Executor:
                 total += steps
             self.metrics.decode(total, N)
             self.metrics.dispatch()
-            prev, self._pending = self._pending, (out, plan, st)
+            prev, self._pending = self._pending, (out, passes, plan, st)
             if prev is not None:
                 self._consume(prev)
 
@@ -583,8 +585,9 @@ class Executor:
         Output rows are deterministic in shape: slot budgets stop a request
         exactly at its target length on device, so `steps` per slot is
         known at dispatch time."""
-        out, plan, st = pending
-        arr = np.asarray(out)
+        out, passes, plan, st = pending
+        arr, passes = jax.device_get((out, passes))
+        self.metrics.weight_passes(passes)
         for d, s, r, steps in plan:
             for j in range(steps):
                 r.output.append(int(arr[d, s, j]))

@@ -34,6 +34,13 @@ class ServeMetrics:
     mixed_dispatches: int = 0
     # prefill compute actually dispatched (tokens through the prefill step)
     prefill_tokens: int = 0
+    # expert weight passes of the grouped GEMM, summed over dispatches,
+    # layers, both expert GEMMs and chips: sum_e ceil(routed rows / row
+    # block) per call, beside the E x ceil(C / 128) of the dense grid
+    # before it (kernels/moe_gemm); their ratio is the share of expert
+    # weight streaming the counts left
+    moe_weight_passes: int = 0
+    moe_weight_passes_dense: int = 0
     # prefix cache: per-request lookup outcomes + page-level sharing
     prefix_lookups: int = 0
     prefix_hits: int = 0
@@ -98,6 +105,12 @@ class ServeMetrics:
         self.decode_dispatches += 1
         self.decode_substeps += substeps
         self.decode_tokens += tokens
+
+    def weight_passes(self, passes) -> None:
+        """Add one dispatch's (..., 2) [grouped GEMM, dense grid] passes."""
+        p = np.asarray(passes).reshape(-1, 2).sum(0)
+        self.moe_weight_passes += int(p[0])
+        self.moe_weight_passes_dense += int(p[1])
 
     def dispatch(self, mixed: bool = False) -> None:
         self.dispatches += 1

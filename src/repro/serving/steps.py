@@ -169,14 +169,17 @@ def _write_pages(pool_l, k, v, page_ids, slots):
 
 def _ffn(cfg, lpk, h_flat, spec: LayoutSpec, m, lay_exp, cap_factor,
          ep_axes=None, moe_backend=None, *, li):
-    """h_flat (T, D) -> (T, D) ffn output; TP-style paths return AFTER psum.
+    """h_flat (T, D) -> ((T, D) ffn output, (2,) int32 expert weight passes
+    of this rank: the grouped GEMM's and the dense grid's, 0 for a dense
+    MLP); TP-style paths return AFTER psum.
     MoE: lpk["moe"]'s w13/w2 are the layer stacks, used at layer li."""
     if cfg.is_moe:
+        passes: list = []
         if spec.expert_kind == "tp":
             part = moe_decode_tp(cfg, lpk["moe"], h_flat, m, li=li,
                                  cap_factor=cap_factor,
-                                 moe_backend=moe_backend)
-            return lax.psum(part, m)
+                                 moe_backend=moe_backend, passes=passes)
+            return lax.psum(part, m), passes[0]
         if spec.expert_full_mesh:
             # TP attention feeds a replicated batch; each model rank owns
             # its 1/G token slice and dispatches over the FULL mesh
@@ -187,17 +190,20 @@ def _ffn(cfg, lpk, h_flat, spec: LayoutSpec, m, lay_exp, cap_factor,
             mine = lax.dynamic_slice_in_dim(h_flat, r * Tl, Tl, 0)
             y = moe_decode_ep(cfg, lpk["moe"], mine, ep_axes, lay_exp,
                               li=li, cap_factor=cap_factor,
-                              moe_backend=moe_backend)
-            return lax.all_gather(y, m, axis=0, tiled=True)
-        return moe_decode_ep(cfg, lpk["moe"], h_flat, m, lay_exp, li=li,
-                             cap_factor=cap_factor, moe_backend=moe_backend)
+                              moe_backend=moe_backend, passes=passes)
+            return lax.all_gather(y, m, axis=0, tiled=True), passes[0]
+        y = moe_decode_ep(cfg, lpk["moe"], h_flat, m, lay_exp, li=li,
+                          cap_factor=cap_factor, moe_backend=moe_backend,
+                          passes=passes)
+        return y, passes[0]
+    none = jnp.zeros((2,), jnp.int32)
     mlp = lpk["mlp"]
     if spec.dense_tp:
         if cfg.mlp_type == "swiglu":
             hh = jax.nn.silu(h_flat @ mlp["w_gate"]) * (h_flat @ mlp["w_up"])
         else:
             hh = jax.nn.gelu(h_flat @ mlp["w_up"])
-        return lax.psum(hh @ mlp["w_down"], m)
+        return lax.psum(hh @ mlp["w_down"], m), none
     # DP dense: DP attention + TP MLP -> all_gather tokens, width-local MLP,
     # reduce_scatter back (same per-layer volume as TP's all-reduce)
     full = lax.all_gather(h_flat, m, axis=0, tiled=True)       # (T*G, D)
@@ -206,7 +212,7 @@ def _ffn(cfg, lpk, h_flat, spec: LayoutSpec, m, lay_exp, cap_factor,
     else:
         hh = jax.nn.gelu(full @ mlp["w_up"])
     out = hh @ mlp["w_down"]
-    return lax.psum_scatter(out, m, scatter_dimension=0, tiled=True)
+    return lax.psum_scatter(out, m, scatter_dimension=0, tiled=True), none
 
 
 def _sample(cfg, pack, x, spec: LayoutSpec, m, key, temperature, slot0):
@@ -268,7 +274,9 @@ def _chunk_core(cfg, spec: LayoutSpec, pack, pool, tokens, positions,
     """One Sq-token step on squeezed per-rank params (inside shard_map).
 
     tokens (bs, Sq); positions/valid_len (bs,); bt (bs, maxp); pool = the
-    layout's KV view. Returns (next_token (bs,), new_pool, last_hidden).
+    layout's KV view. Returns (next_token (bs,), new_pool, last_hidden,
+    passes): passes (2,) int32 sums this rank's expert weight passes over
+    layers and both GEMMs, the grouped GEMM's and the dense grid's (`_ffn`).
     Shared verbatim by the single-step builder and the fused decode loop so
     both paths run byte-identical math.
     """
@@ -298,7 +306,7 @@ def _chunk_core(cfg, spec: LayoutSpec, pack, pool, tokens, positions,
         layers = {**layers, "moe": moe}
 
     def layer_fn(carry, xs):
-        h, pool = carry
+        h, pool, passes = carry
         lpk, li = xs
         if experts:
             lpk = dict(lpk, moe=dict(lpk["moe"], **experts))
@@ -319,22 +327,23 @@ def _chunk_core(cfg, spec: LayoutSpec, pack, pool, tokens, positions,
             attn = lax.psum(attn, m)
         h = h + attn.astype(h.dtype)
         hn = apply_norm(cfg, h, lpk["mlp_norm"])
-        y = _ffn(cfg, lpk, hn.reshape(bs * Sq, -1), spec, m, lay_exp,
-                 cap_factor=None, ep_axes=ep_axes, moe_backend=moe_backend,
-                 li=li)
+        y, ps = _ffn(cfg, lpk, hn.reshape(bs * Sq, -1), spec, m, lay_exp,
+                     cap_factor=None, ep_axes=ep_axes,
+                     moe_backend=moe_backend, li=li)
         h = h + y.reshape(bs, Sq, -1).astype(h.dtype)
         pool = lax.dynamic_update_index_in_dim(pool, pool_l, li, axis=0)
-        return (h, pool), None
+        return (h, pool, passes + ps), None
 
     L = pool.shape[0]
-    (x, new_pool), _ = lax.scan(
-        layer_fn, (x, pool), (layers, jnp.arange(L)))
+    (x, new_pool, passes), _ = lax.scan(
+        layer_fn, (x, pool, jnp.zeros((2,), jnp.int32)),
+        (layers, jnp.arange(L)))
     x = apply_norm(cfg, x, pack["final_norm"])
     # sample at the last valid position of each slot
     last = jnp.clip(valid_len - 1, 0, Sq - 1)
     xl = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     nxt = _sample(cfg, pack, xl, spec, m, key, temperature, 0)
-    return nxt, new_pool, xl
+    return nxt, new_pool, xl, passes
 
 
 def _layout_geometry(cfg, mesh, layout, cc, Bslot, m, da):
@@ -380,7 +389,10 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
     Global signature:
       pack, kv_flat (Dd, G, *rank_shape), tokens (Dd, Bslot, Sq), positions (Dd, Bslot),
       valid_len (Dd, Bslot), block_table (Dd, Bslot, maxp), key
-      -> (next_token (Dd, Bslot), kv_flat')
+      -> (next_token (Dd, Bslot), kv_flat', passes (Dd, G, 2))
+    `passes[d, r]` = rank r's expert weight passes of the step, summed
+    over layers and both expert GEMMs: the grouped GEMM's, then the dense
+    grid's (`_chunk_core`); zeros for a dense model.
     `positions` = global KV position of tokens[:, :, 0] (a decode row's
     kv_len - 1, a prefill row's prefill_pos);
     `valid_len` = #valid tokens in the row (1 for decode; 0 = dead slot).
@@ -401,12 +413,13 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
         pool = kv_flat.reshape(g["view"])                  # (L,2,pages,...)
         key = jax.random.wrap_key_data(key)
         pack = _squeeze_pack(cfg, spec, pack)
-        nxt, new_pool, xl = _chunk_core(
+        nxt, new_pool, xl, passes = _chunk_core(
             cfg, spec, pack, pool, tokens, positions, valid_len, bt, key,
             m=m, lay_exp=g["lay_exp"], ep_axes=g["ep_axes"],
             attn_backend=attn_backend, moe_backend=moe_backend,
             temperature=temperature, page=g["page"], maxp=maxp, Sq=Sq)
-        out = (nxt.reshape(1, bs), new_pool.reshape(kv_flat.shape))
+        out = (nxt.reshape(1, bs), new_pool.reshape(kv_flat.shape),
+               passes.reshape(1, 1, 2))
         if return_logits:
             head = pack["embed"] if cfg.tie_embeddings else pack["lm_head"]
             lg = (xl @ head.T.astype(xl.dtype)).astype(jnp.float32)
@@ -416,7 +429,7 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
         return out
 
     pspecs = _pack_specs_for(cfg, layout, g["G"], g["G_exp"], m, g["ep_axes"])
-    out_specs = (bspec2, flat_spec)
+    out_specs = (bspec2, flat_spec, P(da, m, None))
     if return_logits:
         out_specs = out_specs + ((P(da, m, None) if spec.slots_sharded
                                   else P(da, None, None)),)
@@ -450,7 +463,8 @@ def build_decode_loop(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
       pack, kv_flat (Dd, G, *rank_shape), tokens (Dd, B), positions (Dd, B),
       budgets (Dd, B), block_table (Dd, B, maxp), key
       -> (out_tokens (Dd, B, steps), kv_flat',
-          tokens' (Dd, B), positions' (Dd, B), budgets' (Dd, B))
+          tokens' (Dd, B), positions' (Dd, B), budgets' (Dd, B),
+          passes (Dd, G, 2))
 
     `tokens` = last generated token per slot (its KV is written at
     `positions` on the first substep, mirroring the single-step feed).
@@ -459,7 +473,8 @@ def build_decode_loop(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
     i < b. out_tokens[:, :, i] is substep i's sample (0 when inactive).
     At temperature 0 (greedy) the fused loop is byte-identical to `steps`
     single-step calls; with sampling the key is folded per substep, which
-    is a different stream than the engine's per-step fold.
+    is a different stream than the engine's per-step fold. `passes` sums
+    the single step's expert weight passes over the substeps.
     """
     m, da = model_axis, data_axes
     g = _layout_geometry(cfg, mesh, layout, cc, Bslot, m, da)
@@ -476,9 +491,9 @@ def build_decode_loop(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
         pack = _squeeze_pack(cfg, spec, pack)     # hoisted out of the loop
 
         def substep(i, carry):
-            pool, tok, pos, bud, out = carry
+            pool, tok, pos, bud, out, passes = carry
             active = (bud > 0).astype(jnp.int32)
-            nxt, pool, _ = _chunk_core(
+            nxt, pool, _, ps = _chunk_core(
                 cfg, spec, pack, pool, tok[:, None], pos, active, bt,
                 jax.random.fold_in(key, i),
                 m=m, lay_exp=g["lay_exp"], ep_axes=g["ep_axes"],
@@ -487,19 +502,22 @@ def build_decode_loop(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
             live = active > 0
             out = out.at[:, i].set(jnp.where(live, nxt, 0))
             return (pool, jnp.where(live, nxt, tok), pos + active,
-                    bud - active, out)
+                    bud - active, out, passes + ps)
 
         out0 = jnp.zeros((bs, steps), jnp.int32)
-        pool, tok, pos, bud, out = lax.fori_loop(
-            0, steps, substep, (pool, tokens, positions, budgets, out0))
+        pool, tok, pos, bud, out, passes = lax.fori_loop(
+            0, steps, substep, (pool, tokens, positions, budgets, out0,
+                                jnp.zeros((2,), jnp.int32)))
         return (out.reshape(1, bs, steps), pool.reshape(kv_flat.shape),
-                tok.reshape(1, bs), pos.reshape(1, bs), bud.reshape(1, bs))
+                tok.reshape(1, bs), pos.reshape(1, bs), bud.reshape(1, bs),
+                passes.reshape(1, 1, 2))
 
     pspecs = _pack_specs_for(cfg, layout, g["G"], g["G_exp"], m, g["ep_axes"])
     smapped = shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, flat_spec, bspec2, bspec2, bspec2, bspec3, P()),
-        out_specs=(bspec3, flat_spec, bspec2, bspec2, bspec2),
+        out_specs=(bspec3, flat_spec, bspec2, bspec2, bspec2,
+                   P(da, m, None)),
         check_vma=False)
     donate_args = (1,) if donate else ()
     return jax.jit(smapped, donate_argnums=donate_args)

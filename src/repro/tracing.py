@@ -11,7 +11,7 @@ arguments known only at the end of a span are added with
 
 The step's span tree (DESIGN.md §9):
 
-    moebius.step {step, B, Sq, dec, pre}
+    moebius.step {step, B, Sq, dec, pre, passes}
       moebius.sched.admit         admission, deadline expiry, prefill starts
       moebius.policy              queue snapshot + coordinator
       moebius.switch {direction, bytes_sent}  a live switch, when taken
@@ -29,6 +29,8 @@ The step's span tree (DESIGN.md §9):
 
 `step`'s B and Sq are its mixed dispatch's shape (0 when it ran none);
 two-phase and in-switch dispatches carry theirs on `exec.stage`.
+`passes` is the expert weight passes the grouped GEMM made in the
+dispatches the step fetched (`ServeMetrics.moe_weight_passes`).
 
 The switch spans sit at the bounds of `SwitchStats`' timers, so plan +
 commit is the switch's `pause_s`; a monolithic switch has no chunks and

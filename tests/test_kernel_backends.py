@@ -65,7 +65,8 @@ def test_force_ref_env_unifies_all_dispatchers(monkeypatch):
     from repro.kernels.kv_pack.ops import gather_pages
     from repro.kernels.moe_gemm.ops import grouped_matmul
     from repro.kernels.paged_attention.ops import paged_attention
-    grouped_matmul(jnp.ones((2, 4, 8)), jnp.ones((1, 2, 4, 8)), 0)
+    grouped_matmul(jnp.ones((2, 4, 8)), jnp.ones((1, 2, 4, 8)), 0,
+                   jnp.full((2,), 4, jnp.int32))
     gather_pages(jnp.ones((4, 2, 1, 4)), jnp.array([0, 1]))
     pack_peer_chunks(jnp.ones((2, 8, 4)), 2)
     paged_attention(jnp.ones((1, 1, 2, 4)), jnp.ones((4, 2, 2, 4)),
@@ -100,8 +101,8 @@ def test_grouped_matmul_backends_ragged(E, C, D, W, zero_experts, seed):
     # zero out the unfilled tail of each expert's bucket (ragged loads)
     mask = (jnp.arange(C)[None, :] < counts[:, None]).astype(jnp.float32)
     x = x * mask[..., None]
-    r = grouped_matmul(x, w[None], 0, backend="ref")
-    k = grouped_matmul(x, w[None], 0, backend="interpret")
+    r = grouped_matmul(x, w[None], 0, counts, backend="ref")
+    k = grouped_matmul(x, w[None], 0, counts, backend="interpret")
     np.testing.assert_allclose(np.asarray(k), np.asarray(r),
                                rtol=1e-5, atol=1e-4)
     # zero-token experts must produce exactly zero output in both

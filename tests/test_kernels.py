@@ -82,8 +82,8 @@ def test_moe_gemm_matches_ref(E, C, D, W, dtype, seed):
     ks = jax.random.split(jax.random.PRNGKey(seed), 2)
     x = jax.random.normal(ks[0], (E, C, D), dt)
     w = jax.random.normal(ks[1], (E, W, D), dt)
-    out = grouped_matmul_pallas(x, w[None], 0, block_c=64, block_w=64,
-                                interpret=True)
+    out = grouped_matmul_pallas(x, w[None], 0, jnp.full((E,), C),
+                                block_c=64, block_w=64, interpret=True)
     ref = grouped_matmul_ref(x, w)
     tol = 1e-4 if dtype == "f32" else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -105,12 +105,53 @@ def test_moe_gemm_reads_layer_of_stack(li, shape, dtype):
     ks = jax.random.split(jax.random.PRNGKey(7 * li + len(shape)), 2)
     x = jax.random.normal(ks[0], (E, C, Din), dt)
     w = jax.random.normal(ks[1], (3, E, W, Din), dt)
+    full = jnp.full((E,), C, jnp.int32)
     want = np.asarray(grouped_matmul_ref(x, w[li]), np.float32)
-    kern = jax.jit(lambda x, w, i: grouped_matmul_pallas(
-        x, w, i, interpret=True))
-    out = np.asarray(kern(x, w, jnp.int32(li)), np.float32)
-    ref = np.asarray(grouped_matmul(x, w, jnp.int32(li), backend="ref"),
+    kern = jax.jit(lambda x, w, i, n: grouped_matmul_pallas(
+        x, w, i, n, interpret=True))
+    out = np.asarray(kern(x, w, jnp.int32(li), full), np.float32)
+    ref = np.asarray(grouped_matmul(x, w, jnp.int32(li), full,
+                                    backend="ref"), np.float32)
+    np.testing.assert_array_equal(ref, want)
+    if dtype == "f32":
+        np.testing.assert_array_equal(out, want)
+    else:
+        np.testing.assert_allclose(out, want, rtol=3e-2, atol=3e-2 * Din)
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("shape", ["w13", "w2"])
+@pytest.mark.parametrize("counts", ["zero", "one", "odd_edge", "full",
+                                    "mix"])
+def test_moe_gemm_routed_rows(counts, shape, dtype):
+    """The kernel (jitted, traced layer index and counts) computes only the
+    rows routed to each expert: with rows >= counts[e] of x NaN, its
+    output there is finite and exactly zero, and the routed rows equal the
+    dispatcher's ref and the oracle, exactly in f32. Row blocks of 24 and
+    sub-blocks of 8 (f32) or 16 (bf16) put 19 across a sub-block edge and
+    27 across a row-block edge."""
+    from repro.kernels.moe_gemm.ops import grouped_matmul
+    dt = jnp.float32 if dtype == "f32" else jnp.bfloat16
+    E, C, D, I = 5, 40, 32, 24
+    W, Din = (2 * I, D) if shape == "w13" else (D, I)
+    n = {"zero": [0] * E, "one": [1] * E, "odd_edge": [19] * E,
+         "full": [C] * E, "mix": [0, 1, 19, C, 27]}[counts]
+    n = jnp.asarray(n, jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(len(counts) + len(shape)), 2)
+    routed = jnp.arange(C)[None, :, None] < n[:, None, None]
+    x = jnp.where(routed, jax.random.normal(ks[0], (E, C, Din), dt),
+                  jnp.nan).astype(dt)
+    w = jax.random.normal(ks[1], (2, E, W, Din), dt)
+    want = np.where(routed, np.asarray(
+        grouped_matmul_ref(jnp.where(routed, x, 0), w[1]), np.float32), 0)
+    kern = jax.jit(lambda x, w, i, n: grouped_matmul_pallas(
+        x, w, i, n, block_c=24, block_sub=8 if dtype == "f32" else 16,
+        interpret=True))
+    out = np.asarray(kern(x, w, jnp.int32(1), n), np.float32)
+    ref = np.asarray(grouped_matmul(x, w, jnp.int32(1), n, backend="ref"),
                      np.float32)
+    assert np.isfinite(out).all()
+    assert not out[~np.broadcast_to(routed, out.shape)].any()
     np.testing.assert_array_equal(ref, want)
     if dtype == "f32":
         np.testing.assert_array_equal(out, want)

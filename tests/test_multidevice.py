@@ -50,7 +50,7 @@ for layout in (TP, EP, TPEP):
     ti = np.zeros((2, 4, 8), np.int32); ti[:, 0, :P0] = prompt
     pos = np.zeros((2, 4), np.int32)
     vl = np.zeros((2, 4), np.int32); vl[:, 0] = P0
-    nxt, kv = pre(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
+    nxt, kv, _ = pre(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
                   jnp.asarray(vl), jnp.asarray(bt), key)
     out = [int(nxt[0, 0])]
     dec = build_serve_step(cfg, mesh, layout, cc, 4, Sq=1, donate=False)
@@ -59,7 +59,7 @@ for layout in (TP, EP, TPEP):
         ti = np.zeros((2, 4, 1), np.int32); ti[:, 0, 0] = np.array(nxt)[:, 0]
         pos = np.zeros((2, 4), np.int32); pos[:, 0] = kvlen
         vl = np.zeros((2, 4), np.int32); vl[:, 0] = 1
-        nxt, kv = dec(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
+        nxt, kv, _ = dec(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
                       jnp.asarray(vl), jnp.asarray(bt), key)
         out.append(int(nxt[0, 0])); kvlen += 1
     assert out == ref, (layout, out, ref)
@@ -232,7 +232,7 @@ for layout in (TP, EP, TPEP):
     for i, p in prompts.items():
         ti[:, i, :len(p)] = p; vl[:, i] = len(p)
         bt[:, i, :3] = pages[i]
-    nxt, kv = pre(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
+    nxt, kv, _ = pre(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
                   jnp.asarray(vl), jnp.asarray(bt), key)
     nxt = np.asarray(nxt)
     first = {i: int(nxt[0, i]) for i in prompts}
@@ -245,7 +245,7 @@ for layout in (TP, EP, TPEP):
         vl = np.zeros((2, B), np.int32)
         for i in prompts:
             ti[:, i, 0] = cur[i]; pos[:, i] = kl[i]; vl[:, i] = 1
-        nx, kv_a = dec(pack, kv_a, jnp.asarray(ti), jnp.asarray(pos),
+        nx, kv_a, _ = dec(pack, kv_a, jnp.asarray(ti), jnp.asarray(pos),
                        jnp.asarray(vl), jnp.asarray(bt), key)
         nx = np.asarray(nx)
         for i in prompts:
@@ -256,7 +256,7 @@ for layout in (TP, EP, TPEP):
     bud = np.zeros((2, B), np.int32)
     for i, p in prompts.items():
         tok[:, i] = first[i]; pos[:, i] = len(p); bud[:, i] = 100
-    out, kv_b, t2, p2, b2 = loop(pack, kv, jnp.asarray(tok),
+    out, kv_b, t2, p2, b2, _ = loop(pack, kv, jnp.asarray(tok),
                                  jnp.asarray(pos), jnp.asarray(bud),
                                  jnp.asarray(bt), key)
     out = np.asarray(out)

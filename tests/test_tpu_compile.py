@@ -26,6 +26,7 @@ from repro.kernels.moe_gemm.kernel import grouped_matmul_pallas
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
 
 CFG = get_config("mixtral-8x7b")
+QWEN = get_config("qwen3-235b-a22b")
 BF = jnp.bfloat16
 I32 = jnp.int32
 G = 4                      # switch group of the four-chip host
@@ -72,12 +73,13 @@ def _attn(B, Sq):
              ((B,), I32), ((B,), I32)])
 
 
-def _gmm(C, W, Dc):
-    """A two-layer weight stack and its layer-index operand."""
-    E = CFG.num_experts
-    return (lambda x, w, li: grouped_matmul_pallas(x, w, li,
-                                                   interpret=False),
-            [((E, C, Dc), BF), ((2, E, W, Dc), BF), ((1,), I32)])
+def _gmm(C, W, Dc, E=CFG.num_experts):
+    """A two-layer weight stack, its layer-index operand and the routed
+    row counts."""
+    return (lambda x, w, li, n: grouped_matmul_pallas(x, w, li, n,
+                                                      interpret=False),
+            [((E, C, Dc), BF), ((2, E, W, Dc), BF), ((1,), I32),
+             ((E,), I32)])
 
 
 # per-rank switch shapes: EP holds E/G whole experts, TP a 1/G width slice
@@ -91,6 +93,15 @@ CASES = {
     "paged_attention_mixed": lambda: _attn(8, 64),
     "grouped_matmul_w13": lambda: _gmm(64, 2 * I, D),
     "grouped_matmul_w2": lambda: _gmm(64, D, I),
+    # the largest grouped GEMM of each cell: chat's rung 16 x chunk 64,
+    # the rollout's 128 experts at C 1024, and the four-chip EP step's
+    # receive slots for its 2 local experts at rung 16 (C2 = 512 x 4)
+    "grouped_matmul_chat_w2_c1024": lambda: _gmm(1024, D, I),
+    "grouped_matmul_rollout_w13_c1024": lambda: _gmm(
+        1024, 2 * QWEN.d_expert, QWEN.d_model, QWEN.num_experts),
+    "grouped_matmul_rollout_w2_c1024": lambda: _gmm(
+        1024, QWEN.d_model, QWEN.d_expert, QWEN.num_experts),
+    "grouped_matmul_ep_w2_c2048": lambda: _gmm(2048, D, I, E_LOC),
     "gather_pages": lambda: (
         lambda p, i: gather_pages_pallas(p, i, interpret=False),
         [((PAGES, PAGE, CFG.num_kv_heads, CFG.dh), BF), ((N,), I32)]),
