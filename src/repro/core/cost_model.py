@@ -155,20 +155,6 @@ def decode_step_time(cfg: ModelConfig, layout: str, B: int, kv_len: int,
     }
 
 
-def crossover_batch(cfg: ModelConfig, kv_len: int = 4096,
-                    hw: HWSpec = TPU_V5E, G: int = 8,
-                    lo: int = 1, hi: int = 4096) -> int:
-    """Smallest B where EP beats TP (paper Fig. 2's switch point)."""
-    b = lo
-    while b <= hi:
-        tp = decode_step_time(cfg, TP, b, kv_len, hw, G)["total"]
-        ep = decode_step_time(cfg, EP, b, kv_len, hw, G)["total"]
-        if ep < tp:
-            return b
-        b *= 2
-    return hi
-
-
 def sweep(cfg: ModelConfig, batches, kv_len: int = 4096,
           hw: HWSpec = TPU_V5E, G: int = 8,
           layouts=(TP, EP), chips: int | None = None) -> list[dict]:

@@ -124,11 +124,6 @@ class LayoutSpec(str):
         """True when each model rank owns a private page pool (EP view)."""
         return self.kv_view == "ep"
 
-    def prefill_width(self, G: int) -> int:
-        """Prefill batch-slot rows per data group: rank-sharded layouts run
-        one request per model rank; replicated layouts run one per group."""
-        return G if self.slots_sharded else 1
-
     def batch_quantum(self, G: int) -> int:
         """Decode batch-slot count must be a multiple of this. Rank-sharded
         slots need G | B; full-mesh experts split the replicated token set
@@ -488,18 +483,3 @@ def attn_rank_major(cfg: ModelConfig, ap: dict, G: int) -> dict:
 def _bcast_g(x: jax.Array, G: int) -> jax.Array:
     """(L?, dh) -> (L?, G, dh) replicated."""
     return jnp.broadcast_to(x[..., None, :], x.shape[:-1] + (G, x.shape[-1]))
-
-
-def expand_kv_heads(cfg: ModelConfig, x: jax.Array, G: int,
-                    head_axis: int = -2) -> jax.Array:
-    """(..., K, dh) -> (..., G*Kl, dh): materialize the rank-order KV head
-    blocks (replicated when K < G), matching attn_rank_major's layout. Used
-    for dense cross-KV caches that must shard on the model axis."""
-    gi = group_info(cfg, G)
-    ha = head_axis % x.ndim
-    blocks = []
-    for r in range(G):
-        start = gi.kv_block(r)
-        blocks.append(jax.lax.dynamic_slice_in_dim(x, start, gi.kv_local,
-                                                   ha))
-    return jnp.concatenate(blocks, axis=ha)

@@ -1,19 +1,8 @@
-"""Production mesh builders (functions, not module constants — importing
-this module never touches jax device state)."""
+"""Mesh builders (functions, not module constants — importing this module
+never touches jax device state)."""
 from __future__ import annotations
 
 from repro import compat
-
-
-def make_production_mesh(*, multi_pod: bool = False,
-                         world: int | None = None):
-    """Single pod: (data=16, model=16) = 256 chips. Multi-pod: leading
-    pod axis (2, 16, 16) = 512 chips; `pod` is pure DP. `world` overrides
-    the model-axis extent (elastic world sizes, DESIGN.md §13)."""
-    g = 16 if world is None else int(world)
-    shape = (2, 16, g) if multi_pod else (16, g)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
@@ -32,8 +21,3 @@ def submesh(mesh, world: int, model_axis: str = "model"):
     ax = mesh.axis_names.index(model_axis)
     dev = mesh.devices.take(np.arange(world), axis=ax)
     return Mesh(dev, mesh.axis_names)
-
-
-def data_axes_of(mesh) -> tuple:
-    """All pure-DP axes (everything except `model`)."""
-    return tuple(a for a in mesh.axis_names if a != "model")

@@ -148,10 +148,6 @@ def main():
                          "tokens first, prefill chunks into the remainder); "
                          "0 = auto: the quantum-rounded prefill chunk, so "
                          "full-mesh layouts keep their 1/G-per-rank split")
-    ap.add_argument("--two-phase", action="store_true",
-                    help="legacy separate prefill/decode dispatches per "
-                         "iteration instead of one mixed-batch step "
-                         "(byte-identical outputs; two dispatches/iter)")
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable shared-prefix page reuse (refcounted "
                          "pages + CoW; on by default)")
@@ -188,7 +184,6 @@ def main():
                        policy=args.policy, t_high=args.t_high,
                        prefill_chunk=args.prefill_chunk,
                        token_budget=args.token_budget,
-                       mixed_batch=not args.two_phase,
                        decode_steps=args.decode_steps,
                        prefix_cache=not args.no_prefix_cache,
                        qos=args.qos, attn_backend=args.attn_backend,

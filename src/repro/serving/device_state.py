@@ -5,7 +5,7 @@ the decode loop actually reads — last token, KV position, remaining-token
 budget, block-table row — lives on device in the step's sharding and is
 updated by small jitted delta scatters when requests join, grow their page
 list, or get their budget clamped/restored, instead of being re-materialized
-from host metadata every step (the `_decode_once` path's per-token
+from host metadata every step (the single-step path's per-token
 (Dd, B, maxp) rebuild + upload).
 
 The state is functional: `build_decode_loop` returns the advanced

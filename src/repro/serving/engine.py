@@ -13,16 +13,12 @@ The engine is decomposed into three layers (DESIGN.md §7):
 `MoebiusEngine` wires the first two and keeps the classic synchronous
 `step()`/`run()` API: admission -> policy -> (switch?) -> ONE
 token-budgeted mixed dispatch per iteration (decode rows first, prefill
-chunks into the remaining budget; DESIGN.md §10). Setting
-`EngineConfig.mixed_batch = False` restores the legacy two-phase
-prefill-then-decode iteration — same plans, same step functions, so the
-outputs are byte-identical either way. The switch is executed between
-(now mixed) steps without
-draining: request metadata is rewritten on host, expert weights are
-resharded and the paged KV migrated by the jitted movers, and the target
-layout's pre-warmed step functions are *selected*, not rebuilt. The
-`SwitchCoordinator` observes the Scheduler's queue snapshot — never engine
-internals.
+chunks into the remaining budget; DESIGN.md §10). The switch is executed
+between steps without draining: request metadata is rewritten on host,
+expert weights are resharded and the paged KV migrated by the jitted
+movers, and the target layout's pre-warmed step functions are
+*selected*, not rebuilt. The `SwitchCoordinator` observes the
+Scheduler's queue snapshot — never engine internals.
 """
 from __future__ import annotations
 
@@ -50,19 +46,11 @@ class EngineConfig:
     layouts: tuple = (TP, EP)
     ladder: tuple = (4, 8, 16, 32)
     prefill_chunk: int = 32
-    # ONE dispatch per iteration mixing decode rows with prefill chunks
-    # under `token_budget` (DESIGN.md §10). False = the legacy two-phase
-    # prefill-then-decode iteration (same step fns; byte-identical outputs)
-    mixed_batch: bool = True
-    # per-iteration mixed-batch token budget; 0 = auto: the executor's
-    # prefill chunk, which is already rounded up to a multiple of every
-    # resident layout's prefill_quantum, so full-mesh layouts keep their
-    # 1/G-per-rank prefill split
+    # per-iteration mixed-batch token budget (DESIGN.md §10); 0 = auto:
+    # the executor's prefill chunk, which is already rounded up to a
+    # multiple of every resident layout's prefill_quantum, so full-mesh
+    # layouts keep their 1/G-per-rank prefill split
     token_budget: int = 0
-    # virtual-clock seconds charged per device step-fn dispatch (0 = off).
-    # Only meaningful with an injected clock: benches use it to model the
-    # per-dispatch overhead that mixed batching halves during a storm
-    dispatch_dt: float = 0.0
     temperature: float = 0.0
     time_scale: float = 1.0            # virtual seconds per wall second
     direct_reshard: bool = True        # paper's fused path when pure-EP
@@ -171,7 +159,6 @@ class MoebiusEngine:
         self._t0 = time.monotonic()
         self._clock = self.ecfg.clock
         self._clock_skip = 0.0
-        self._charged_disp = 0         # dispatches already billed dispatch_dt
         # fault tolerance (DESIGN.md §12)
         self._faults = (None if self.ecfg.faults is None
                         else FaultInjector(self.ecfg.faults))
@@ -339,45 +326,16 @@ class MoebiusEngine:
         self.ex.drain_decode()
 
     # ------------------------------------------------------------------
-    # prefill / decode phases (Scheduler plans, Executor dispatches)
+    # the serve step (Scheduler plans, Executor dispatches)
     # ------------------------------------------------------------------
-    def _run_prefill(self) -> None:
-        # CoW copies from prefill admission must land before anything can
-        # write the source pages — flush even when no row dispatches
-        self.ex.run_copies(self.sched.drain_copies())
-        if not self.sched.prefilling:
-            return
-        with span("sched.plan"):
-            picked = self.sched.select_prefill_rows(self.ex.prefill_chunk)
-        if not picked:
-            return
-        nxt = self.ex.run_prefill(picked, self._step_i)
-        with span("sched.commit"):
-            t = self.now()
-            for r, d, row, n in picked:
-                self.sched.finish_prefill(r, n, int(nxt[d, row]), t)
-
-    def _decode_once(self) -> None:
-        if not self.sched.running:
-            return
-        with span("sched.plan"):
-            B, stepped = self.sched.plan_decode(self._step_i)
-        self.ex.run_copies(self.sched.drain_copies())
-        if not stepped:
-            return
-        toks = self.ex.run_decode(B, stepped, self._step_i)
-        with span("sched.commit"):
-            self.sched.commit_decode(stepped, toks)
-
     def _decode_step(self) -> None:
-        """Dispatch one decode iteration on whichever control plane the
-        engine is configured for (also the overlap step during a chunked
-        switch, which stays decode-only in BOTH engine modes: prefill does
-        not advance while a switch session is staging)."""
+        """The decode step between the chunks of a chunked switch: prefill
+        does not advance while a switch session is staging, so it is the
+        serve step planned with no prefill chunk (or the fused loop)."""
         if self.ecfg.decode_steps > 1:
             self.ex.decode_fused(self.sched, self._step_i)
-        else:
-            self._decode_once()
+        elif self.sched.running:
+            self._dispatch(chunk=0)
 
     def _mixed_step(self) -> tuple[int, int]:
         """ONE token-budgeted dispatch per iteration (DESIGN.md §10): all
@@ -394,10 +352,15 @@ class MoebiusEngine:
             # boundary and run single-token mixed dispatches until the
             # storm passes (runners re-join the fused loop afterwards)
             self.ex.suspend_fused(self.sched)
+        return self._dispatch(chunk=self.ex.prefill_chunk)
+
+    def _dispatch(self, chunk: int) -> tuple[int, int]:
+        """Plan, copy, run and commit one mixed step with prefill chunks of
+        at most `chunk` tokens (0: decode rows only)."""
         with span("sched.plan"):
             plan = self.sched.plan_mixed(self._step_i,
                                          budget=self.token_budget,
-                                         chunk=self.ex.prefill_chunk)
+                                         chunk=chunk)
         # CoW copies from BOTH prefill admission and the plan's page growth
         # must land before the dispatch that could write their source pages
         self.ex.run_copies(self.sched.drain_copies())
@@ -407,20 +370,6 @@ class MoebiusEngine:
         with span("sched.commit"):
             self.sched.commit_mixed(plan, nxt, self.now())
         return plan.B, plan.Sq
-
-    def _charge_dispatches(self) -> None:
-        """Virtual-clock cost model: bill `dispatch_dt` seconds per device
-        step-fn dispatch issued this iteration. A storm iteration costs two
-        dispatches under two-phase (prefill + decode) but one under mixed
-        batching — the bursty bench's TPOT gate measures exactly this."""
-        dt = self.ecfg.dispatch_dt
-        if dt <= 0 or self._clock is None:
-            return
-        adv = getattr(self._clock, "advance", None)
-        delta = self.metrics.dispatches - self._charged_disp
-        self._charged_disp = self.metrics.dispatches
-        if adv is not None and delta > 0:
-            adv(delta * dt)
 
     # ------------------------------------------------------------------
     # switch
@@ -732,14 +681,8 @@ class MoebiusEngine:
                 for d in started:
                     if d.req.prefill_start_s is None:
                         d.req.prefill_start_s = t
-            if self.ecfg.mixed_batch:
-                B, Sq = self._mixed_step()
-            else:       # two-phase: each dispatch's shape is on exec.stage
-                B = Sq = 0
-                self._run_prefill()
-                self._decode_step()
+            B, Sq = self._mixed_step()
             with span("account"):
-                self._charge_dispatches()
                 self._check_recoveries()
                 m.pages_resident(sum(a.total_held()
                                      for a in self.sched.alloc))

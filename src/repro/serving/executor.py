@@ -6,13 +6,10 @@ step-function caches (`ResidentRuntime`), `DeviceDecodeState` + the fused
 one-deep dispatch pipeline, the CoW page copier, and the `SwitchExecutor`.
 It consumes the Scheduler's plans/decisions (`MixedPlan`s, `CopyPages`)
 and reports completions back through the scheduler callbacks
-(`commit_mixed` / `finish_prefill` / `commit_decode` are driven by the
-engine facade; fused-pipeline retirements go through the `on_finish`
-hook). `run_mixed` is THE dispatch path: one step-fn cache keyed by
-(layout, rung, chunk width) serves mixed, pure-decode, and pure-prefill
-plans alike — the legacy two-phase entry points (`run_prefill` /
-`run_decode`) are thin wrappers that build single-kind plans, so both
-engine modes share one set of compiled executables.
+(`commit_mixed` is driven by the engine facade; fused-pipeline
+retirements go through the `on_finish` hook). `run_mixed` is THE dispatch
+path: one step-fn cache keyed by (layout, rung, chunk width) serves
+mixed, pure-decode, and pure-prefill plans alike.
 
 Memory discipline mirrors the paper: the control plane (attention/embed/norm
 packs, compiled steps) is resident for EVERY registered layout (the
@@ -38,7 +35,7 @@ from repro.serving.device_state import DeviceDecodeState
 from repro.serving.kvcache import COPY_W, CacheConfig, make_copy_pages
 from repro.serving.metrics import ServeMetrics
 from repro.serving.request import Request
-from repro.serving.scheduler import MixedPlan, MixedRow
+from repro.serving.scheduler import MixedPlan
 from repro.serving.steps import (build_decode_loop, build_decode_pack,
                                  build_mixed_step, decode_pack_specs)
 from repro.tracing import span
@@ -250,10 +247,8 @@ class Executor:
 
     def _mixed_fn(self, layout: LayoutSpec, B: int, Sq: int):
         """THE serve step (steps.build_mixed_step), cached by
-        (layout, rung, chunk width). Sq == 1 is the classic decode shape;
-        Sq == prefill_chunk serves mixed and pure-prefill plans. Legacy
-        two-phase dispatches route through the same keys, so both engine
-        modes select from one set of compiled executables."""
+        (layout, rung, chunk width). Sq == 1 is the decode shape;
+        Sq == prefill_chunk serves mixed and pure-prefill plans."""
         return self.rt.get_or_build(
             (layout, "mixed", B, Sq),
             lambda: build_mixed_step(
@@ -263,9 +258,6 @@ class Executor:
                 moe_backend=self.ecfg.moe_backend,
                 return_logits=self.ecfg.record_logits))
 
-    def _decode_fn(self, layout: LayoutSpec, B: int):
-        return self._mixed_fn(layout, B, 1)
-
     def _decode_loop_fn(self, layout: LayoutSpec, B: int, N: int):
         return self.rt.get_or_build(
             (layout, "decode_loop", B, N),
@@ -274,10 +266,6 @@ class Executor:
                 temperature=self.ecfg.temperature, data_axes=(self.da,),
                 model_axis=self.m, attn_backend=self.ecfg.attn_backend,
                 moe_backend=self.ecfg.moe_backend))
-
-    def _prefill_fn(self, layout: LayoutSpec):
-        Bp = get_layout(layout).prefill_width(self._world(layout))
-        return self._mixed_fn(layout, Bp, self.prefill_chunk)
 
     def warmup(self, layouts=None):
         """Compile every resident layout's runtime at startup (paper §4.4).
@@ -289,16 +277,12 @@ class Executor:
         compiles nothing). Inactive layouts are built only; their first
         execution happens behind a switch, whose benches warm explicitly.
         """
-        mixed = getattr(self.ecfg, "mixed_batch", True)
         for lo in (self.layouts if layouts is None else layouts):
-            if not mixed:
-                # the (prefill width, chunk) shape serves two-phase only
-                self._prefill_fn(lo)
             for b in self.ladder_for(lo):
-                self._decode_fn(lo, b)
-                if mixed:
-                    # mixed plans pair any ladder rung with the chunk width
-                    self._mixed_fn(lo, b, self.prefill_chunk)
+                # decode-only plans (Sq 1) and mixed plans, which pair any
+                # ladder rung with the chunk width
+                self._mixed_fn(lo, b, 1)
+                self._mixed_fn(lo, b, self.prefill_chunk)
                 if self.ecfg.decode_steps > 1:
                     self._decode_loop_fn(lo, b, self.ecfg.decode_steps)
             if self.ecfg.prefix_cache:
@@ -319,25 +303,14 @@ class Executor:
             # the live loop's per-step key derivation compiles here too
             key = self._step_key(0)
             maxp = self.cc.max_pages_per_req
-            if not mixed:
-                Bp = get_layout(lo).prefill_width(self._world(lo))
-                toks = jnp.zeros((self.Dd, Bp, self.prefill_chunk),
-                                 jnp.int32)
-                z2 = jnp.zeros((self.Dd, Bp), jnp.int32)
-                bt = jnp.zeros((self.Dd, Bp, maxp), jnp.int32)
-                self._prefill_fn(lo)(pk, jnp.zeros_like(self.kv_flat),
-                                     toks, z2, z2, bt, key)
             for b in self.ladder_for(lo):
                 z2 = jnp.zeros((self.Dd, b), jnp.int32)
                 bt = jnp.zeros((self.Dd, b, maxp), jnp.int32)
-                self._decode_fn(lo, b)(
-                    pk, jnp.zeros_like(self.kv_flat),
-                    jnp.zeros((self.Dd, b, 1), jnp.int32), z2, z2, bt, key)
-                if mixed:
-                    self._mixed_fn(lo, b, self.prefill_chunk)(
+                for sq in (1, self.prefill_chunk):
+                    self._mixed_fn(lo, b, sq)(
                         pk, jnp.zeros_like(self.kv_flat),
-                        jnp.zeros((self.Dd, b, self.prefill_chunk),
-                                  jnp.int32), z2, z2, bt, key)
+                        jnp.zeros((self.Dd, b, sq), jnp.int32), z2, z2, bt,
+                        key)
                 if self.ecfg.decode_steps > 1:
                     # match the live call's committed shardings exactly
                     st = DeviceDecodeState(self._mesh_for(lo), lo, self.Dd,
@@ -422,7 +395,7 @@ class Executor:
                 self.copy_pages(c.d, c.pool, list(c.pairs))
 
     # ------------------------------------------------------------------
-    # mixed-batch dispatch (THE serve path; two-phase wrappers below)
+    # mixed-batch dispatch (THE serve path)
     # ------------------------------------------------------------------
     def _staging(self, B: int, Sq: int) -> tuple:
         """(tokens, positions, valid_len, block_table) host buffers for one
@@ -483,28 +456,6 @@ class Executor:
             self.metrics.decode(n_dec, 1)
         self.metrics.dispatch(mixed=bool(n_dec and n_pref))
         return out
-
-    def run_prefill(self, picked: list, step_i: int) -> np.ndarray:
-        """Two-phase wrapper: one chunked prefill step (rows from
-        Scheduler.select_prefill_rows) as a prefill-only MixedPlan."""
-        rows = tuple(MixedRow(r, d, row, r.prefill_pos, n, "prefill")
-                     for r, d, row, n in picked)
-        plan = MixedPlan(B=self.active.prefill_width(self._world(self.active)),
-                         Sq=self.prefill_chunk, rows=rows,
-                         prefill_tokens=sum(n for *_, n in picked))
-        return self.run_mixed(plan, step_i)
-
-    def run_decode(self, B: int, stepped: list[Request],
-                   step_i: int) -> dict[int, int]:
-        """Two-phase wrapper: one single-token decode step over `stepped`
-        (slots assigned by Scheduler.plan_decode) as a decode-only
-        MixedPlan; returns rid -> token."""
-        # the fed token is output[-1]: its KV position is kv_len - 1
-        rows = tuple(MixedRow(r, r.data_group, r.slot, r.kv_len - 1, 1,
-                              "decode") for r in stepped)
-        plan = MixedPlan(B=B, Sq=1, rows=rows, decode_tokens=len(stepped))
-        nxt = self.run_mixed(plan, step_i)
-        return {r.rid: int(nxt[r.data_group, r.slot]) for r in stepped}
 
     # ------------------------------------------------------------------
     # fused decode (decode_steps > 1): device-resident state, N-step loop

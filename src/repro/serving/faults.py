@@ -7,8 +7,9 @@ boundary of a chunked switch (`switch_chunk` within the
 `switch_index`-th switch attempt) — and a `FaultInjector` hands each
 fault to the engine exactly once, in trigger order. Under a
 `VirtualClock` the same plan replays the same engine history bit for
-bit, which is what lets `benchmarks/bench_chaos.py` gate byte-identity
-of surviving requests against a fault-free run.
+bit, which is what lets `test_chaos_fault_plan_replay`
+(tests/test_multidevice_recovery.py) hold surviving requests
+byte-identical to a fault-free run.
 
 Fault kinds (applied by `MoebiusEngine._apply_fault` and the chunked
 switch loop):

@@ -116,11 +116,11 @@ class AsyncEngine:
     `generate()`/`submit()` enqueue work; iterating any returned
     `TokenStream` (or calling `run_until_complete`) pumps the shared event
     loop: admission -> policy/switch -> ONE token-budgeted mixed dispatch
-    per iteration (two phases under `mixed_batch=False`), with arrivals
-    drawn from the engine clock. Submissions must be
-    arrival-ordered (the admission queue is a deque scanned at its head —
-    the same trace-replay contract as `MoebiusEngine.submit`); requests
-    without an explicit `arrival_s` arrive "now", which is always ordered.
+    per iteration, with arrivals drawn from the engine clock. Submissions
+    must be arrival-ordered (the admission queue is a deque scanned at its
+    head — the same trace-replay contract as `MoebiusEngine.submit`);
+    requests without an explicit `arrival_s` arrive "now", which is always
+    ordered.
     """
 
     def __init__(self, engine: MoebiusEngine, step_dt: float | None = None,

@@ -28,8 +28,7 @@ class ServeMetrics:
     decode_tokens: int = 0
     # device step-fn dispatches of ANY kind (prefill / decode / fused /
     # mixed); mixed_dispatches counts the ones that carried BOTH decode and
-    # prefill rows — the engine charges `dispatch_dt` virtual seconds per
-    # dispatch, which is exactly where mixed batching beats two-phase
+    # prefill rows
     dispatches: int = 0
     mixed_dispatches: int = 0
     # prefill compute actually dispatched (tokens through the prefill step)

@@ -17,7 +17,7 @@ everything device-visible it wants done is expressed as a typed decision —
   * `StartPrefill`  — pages acquired (cache hits forked), the request
                       entered `prefilling`; returned by `start_prefills`;
   * `Grow`          — a running request's block table grew (recorded in
-                      `last_decisions` by the decode planners);
+                      `last_decisions` by `plan_mixed` / `plan_fused`);
   * `Preempt`       — a pool-exhaustion victim was teacher-force-requeued;
   * `Truncate`      — a request hit its page cap and finished early
                       (both in `last_decisions` and from
@@ -30,11 +30,11 @@ everything device-visible it wants done is expressed as a typed decision —
                       packed into the remaining budget (`plan_mixed`).
 
 The Executor (`serving/executor.py`) consumes the plans + copies and
-reports completions back through `finish_prefill` / `commit_decode` /
-`commit_mixed` / `finish_request`. Layout geometry is duck-typed: the active `LayoutSpec`
-is handed over as an opaque object (`set_layout`) and only its pure
-attributes (`kv_per_rank`, `slots_sharded`, `prefill_width`,
-`decode_ladder`) are read — no layout import, no jax.
+reports completions back through `commit_mixed` / `finish_request`.
+Layout geometry is duck-typed: the active `LayoutSpec` is handed over as
+an opaque object (`set_layout`) and only its pure attributes
+(`kv_per_rank`, `slots_sharded`, `decode_ladder`) are read — no layout
+import, no jax.
 
 Multi-tenant QoS (DESIGN.md §11): an injected `QosPolicy`
 (serving/qos.py, equally device-free) makes three decision points
@@ -189,10 +189,11 @@ class Scheduler:
         # set once any submitted request carries a deadline, so the
         # per-iteration deadline scan costs nothing on deadline-free runs
         self._deadlines_used = False
-        # decisions of the CURRENT planning pass (Grow/Preempt/Truncate
-        # from plan_decode / plan_fused+resolve_fused) — observability and
-        # unit-test surface; executors read request state directly.
-        # Cleared at the start of each planning pass, so it stays bounded.
+        # decisions of the CURRENT planning pass (Grow/Truncate from
+        # plan_mixed; Grow/Preempt/Truncate from plan_fused+resolve_fused)
+        # — observability and unit-test surface; executors read request
+        # state directly. Cleared at the start of each planning pass, so
+        # it stays bounded.
         self.last_decisions: list = []
 
     # ------------------------------------------------------------------
@@ -740,27 +741,6 @@ class Scheduler:
         self.waiting = [r for r in self.waiting if id(r) not in started]
         return out
 
-    def prefill_row(self, r: Request) -> int:
-        """Batch row of a prefilling request: rank-sharded layouts run one
-        request per owning model rank; replicated layouts use row 0."""
-        return r.owner_rank if self.spec.slots_sharded else 0
-
-    def select_prefill_rows(self, chunk: int) -> list[tuple]:
-        """Pick at most one prefilling request per (data group, batch row)
-        for this step's chunked prefill: [(req, d, row, n_tokens), ...]."""
-        used, picked = set(), []
-        order = self.prefilling if self.qos is None else \
-            sorted(self.prefilling, key=self.qos.admission_key)
-        for r in order:
-            d = r.data_group
-            row = self.prefill_row(r)
-            if (d, row) in used:
-                continue                      # row already used this step
-            n = min(chunk, r.prompt_len - r.prefill_pos)
-            used.add((d, row))
-            picked.append((r, d, row, n))
-        return picked
-
     def finish_prefill(self, r: Request, n: int, next_token: int,
                        t: float) -> bool:
         """Advance a prefilling request by the `n` tokens the Executor ran;
@@ -817,77 +797,6 @@ class Scheduler:
         self.last_decisions.append(Grow(r, tuple(got)))
         return True
 
-    def plan_decode(self, step_i: int):
-        """One single-step decode plan: slot compaction (host metadata only
-        — free every iteration), page growth, starvation recovery. Returns
-        (B, stepped) — the ladder rung and the requests scheduled into it,
-        with `r.slot` assigned."""
-        self.last_decisions = []
-        per_group: dict[int, list[Request]] = {d: [] for d in range(self.Dd)}
-        for r in self.running.values():
-            per_group[r.data_group].append(r)
-
-        def rotated(reqs):
-            lst = sorted(reqs, key=lambda q: q.rid)
-            if not lst:
-                return lst
-            off = step_i % len(lst)        # fairness under oversubscription
-            return lst[off:] + lst[:off]
-
-        if not self.spec.slots_sharded:
-            need = max(len(v) for v in per_group.values())
-            B = self.pick_B(need)
-            for d, reqs in per_group.items():
-                for i, r in enumerate(rotated(reqs)):
-                    r.slot = i if i < B else None
-        else:
-            bs_need = 1
-            for d, reqs in per_group.items():
-                load = [0] * self.G
-                for r in reqs:
-                    r.slot = None
-                for r in rotated(reqs):
-                    g = r.owner_rank
-                    r.slot_local = load[g]
-                    load[g] += 1
-                bs_need = max(bs_need, max(load))
-            B = self.pick_B(bs_need * self.G)
-            bs_loc = B // self.G
-            for r in self.running.values():
-                # requests beyond this rung's per-rank slots wait a turn
-                r.slot = (r.owner_rank * bs_loc + r.slot_local
-                          if r.slot_local < bs_loc else None)
-        stepped: list[Request] = []
-        starved: list[Request] = []
-        for r in list(self.running.values()):
-            if r.slot is None or r.slot >= B:
-                continue
-            ok = self.ensure_pages(r)
-            if ok == "cap":
-                # at max_pages_per_req with no room for the next token:
-                # retrying forever would livelock — finish with truncation
-                self.last_decisions.append(self.truncate(r))
-                continue
-            if ok == "dry":
-                starved.append(r)
-                continue
-            stepped.append(r)
-        if starved:
-            # nobody can free pages for a starved pool by finishing if the
-            # pool's holders are themselves stuck — preempt/truncate so the
-            # engine always makes progress (no retry-forever livelock)
-            self.last_decisions += self.handle_starvation(starved,
-                                                          exclude=stepped)
-        return B, stepped
-
-    def commit_decode(self, stepped: list[Request], tokens: dict) -> None:
-        """Retire one single-step decode dispatch: append each request's
-        sampled token (keyed by rid) and finish the ones that are done."""
-        for r in stepped:
-            r.output.append(int(tokens[r.rid]))
-            if r.done():
-                self.finish_request(r)
-
     # ------------------------------------------------------------------
     # mixed-batch planning (token-budgeted decode + prefill, one dispatch)
     # ------------------------------------------------------------------
@@ -925,15 +834,16 @@ class Scheduler:
         FIFO over `prefilling`, each clamped to `chunk` and to what the
         budget still holds. When decode alone fills the budget, the
         head-of-line prefill still gets a 1-token grant so a sustained
-        storm can never starve prefill outright.
+        storm can never starve prefill outright. `chunk == 0` plans the
+        decode rows alone (`Sq == 1`), as between a switch's chunks.
 
-        Slot assignment matches `plan_decode` (rotation under
-        oversubscription, owner-rank ranges under sharded slots); prefill
-        rows take the slots after each group's/rank's decode rows, so the
-        rung is sized for both. Page growth, CoW, starvation recovery run
-        exactly as in the two-phase planner — prefill rows already own
-        their pages (acquired at `start_prefill` under the watermark) and
-        are excluded from preemption while scheduled."""
+        Decode slots rotate with `step_i` under oversubscription (owner-rank
+        ranges under sharded slots); prefill rows take the slots after each
+        group's/rank's decode rows, so the rung is sized for both. Decode
+        rows grow their pages (CoW first) and starved pools recover by
+        preemption or truncation — prefill rows already own their pages
+        (acquired at `start_prefill` under the watermark) and are excluded
+        from preemption while scheduled."""
         self.last_decisions = []
         per_group: dict[int, list[Request]] = {d: [] for d in range(self.Dd)}
         for r in self.running.values():
@@ -1021,6 +931,8 @@ class Scheduler:
                 continue
             ok = self.ensure_pages(r)
             if ok == "cap":
+                # at max_pages_per_req with no room for the next token:
+                # retrying forever would livelock — finish with truncation
                 self.last_decisions.append(self.truncate(r))
                 continue
             if ok == "dry":
@@ -1032,8 +944,11 @@ class Scheduler:
         for r, d, row, n in kept:
             rows.append(MixedRow(r, d, row, r.prefill_pos, n, "prefill"))
         if starved:
-            # scheduled prefill rows are live this dispatch — their pages
-            # must not be preempted out from under the staged batch
+            # nobody can free pages for a starved pool by finishing if its
+            # holders are stuck themselves — preempt/truncate so the engine
+            # always makes progress. Scheduled prefill rows are live this
+            # dispatch: their pages must not be preempted out from under
+            # the staged batch
             self.handle_starvation(
                 starved, exclude=stepped + [p[0] for p in kept])
         return MixedPlan(B=B, Sq=chunk if kept else 1, rows=tuple(rows),
@@ -1061,8 +976,9 @@ class Scheduler:
     # fused decode planning (decode_steps > 1)
     # ------------------------------------------------------------------
     def fused_rung(self) -> int:
-        """Ladder rung for the current running set (same sizing rule as the
-        single-step path; slots are sticky between rung changes)."""
+        """Ladder rung for the current running set (same sizing rule as
+        `plan_mixed`'s decode rows; slots are sticky between rung
+        changes)."""
         if not self.spec.slots_sharded:
             per_group = [0] * self.Dd
             for r in self.running.values():

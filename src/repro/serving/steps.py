@@ -441,12 +441,6 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
     return jax.jit(smapped, donate_argnums=donate_args)
 
 
-# The historical name: Sq == 1 built "the decode step", Sq > 1 "the prefill
-# step". They were always the same function — the mixed-batch engine just
-# makes that the contract, so the alias stays for existing call sites.
-build_serve_step = build_mixed_step
-
-
 def build_decode_loop(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
                       Bslot: int, steps: int, *, temperature: float = 0.0,
                       data_axes=("data",), model_axis: str = "model",

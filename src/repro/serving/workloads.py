@@ -6,12 +6,11 @@ Mirrors the paper's evaluation workloads (§6.2, §6.3) at configurable scale:
   * rollout: one batch of N prompts; outputs heavy-tailed (lognormal capped),
     inputs short/clustered — the burst-to-long-tail decay of Fig. 1(c).
   * prefill storm: a handful of long-lived decoders hit by a sustained
-    wave of prompt-heavy arrivals — the mixed-batch TPOT stressor
-    (DESIGN.md §10; shared by bench_bursty's storm gate and the
-    byte-identity tests).
+    wave of prompt-heavy arrivals — the mixed-batch stressor of the
+    byte-identity tests (DESIGN.md §10).
   * qos mix: bursty interactive arrivals over a steady batch floor — the
-    multi-tenant trace the QoS scheduler is measured on (DESIGN.md §11;
-    bench_qos gates interactive p99 attainment QoS vs class-blind).
+    multi-tenant trace the QoS scheduler is tested on (DESIGN.md §11:
+    interactive attainment with QoS vs class-blind).
 """
 from __future__ import annotations
 
@@ -98,7 +97,8 @@ class QosMixSpec:
     batch requests with bursts of short-prompt interactive requests
     layered on top. Under a class-blind FIFO the interactive TTFT waits
     behind the batch floor's prefill tokens; the QoS scheduler packs
-    interactive first — that gap is bench_qos's gate. Arrivals and
+    interactive first — that gap is what
+    `test_qos_beats_class_blind_on_mixed_tenant_trace` holds. Arrivals and
     lengths are deterministic (only token ids come from `seed`), so two
     engines replaying the same spec see byte-identical traces."""
     duration_s: float = 12.0
@@ -116,7 +116,7 @@ class QosMixSpec:
 
 
 def qos_mixed_trace(spec: QosMixSpec, seed: int = 0) -> list[Request]:
-    """Arrival-ordered, slo_class-tagged trace for the QoS benchmarks."""
+    """Arrival-ordered, slo_class-tagged multi-tenant trace."""
     rng = np.random.default_rng(seed)
     lo, hi = spec.token_range
     plan = []                               # (t, class, plen, olen)

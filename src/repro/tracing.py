@@ -28,7 +28,7 @@ The step's span tree (DESIGN.md §9):
       moebius.account             per-step bookkeeping
 
 `step`'s B and Sq are its mixed dispatch's shape (0 when it ran none);
-two-phase and in-switch dispatches carry theirs on `exec.stage`.
+the decode steps between a switch's chunks carry theirs on `exec.stage`.
 `passes` is the expert weight passes the grouped GEMM made in the
 dispatches the step fetched (`ServeMetrics.moe_weight_passes`).
 

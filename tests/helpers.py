@@ -13,6 +13,20 @@ DEFAULT_DEVICES = int(os.environ.get("REPRO_TEST_DEVICES", "8"))
 
 _COUNT_FLAG = "--xla_force_host_platform_device_count"
 
+# Preamble of the multidevice subprocesses: a (2, 4) mesh and a tiny
+# float32 cut of mixtral-8x7b.
+COMMON = """
+import jax, jax.numpy as jnp, numpy as np
+import jax.random as jr
+from repro.configs import get_config
+from repro.compat import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
+cfg = get_config("mixtral-8x7b").reduced(
+    num_heads=8, num_kv_heads=2, head_dim=8, d_model=32, num_layers=2,
+    num_experts=8, top_k=2, d_expert=32, vocab_size=256, capacity_factor=8.0,
+    param_dtype=jnp.float32, compute_dtype=jnp.float32)
+"""
+
 
 def default_devices() -> int:
     """REPRO_TEST_DEVICES read at CALL time, not import time, so a test

@@ -8,22 +8,9 @@ boundary aborts + rolls back) / AFTER the shrink.
 """
 import pytest
 
-from tests.helpers import run_multidevice
+from tests.helpers import COMMON, run_multidevice
 
 pytestmark = pytest.mark.multidevice
-
-
-COMMON = """
-import jax, jax.numpy as jnp, numpy as np
-import jax.random as jr
-from repro.configs import get_config
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
-cfg = get_config("mixtral-8x7b").reduced(
-    num_heads=8, num_kv_heads=2, head_dim=8, d_model=32, num_layers=2,
-    num_experts=8, top_k=2, d_expert=32, vocab_size=256, capacity_factor=8.0,
-    param_dtype=jnp.float32, compute_dtype=jnp.float32)
-"""
 
 
 def test_elastic_resize_preserves_outputs():

@@ -34,8 +34,6 @@ def test_spec_is_frozen():
 
 def test_batch_slot_geometry():
     G = 4
-    assert TP.prefill_width(G) == 1 and TPEP.prefill_width(G) == 1
-    assert EP.prefill_width(G) == G
     # ladder rounding: slot-sharded and full-mesh layouts need G | B
     assert TP.decode_ladder((3, 8), G) == (3, 8)
     assert EP.decode_ladder((3, 8), G) == (4, 8)

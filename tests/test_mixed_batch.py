@@ -1,10 +1,12 @@
 """Token-budgeted mixed-batch dispatch (DESIGN.md §10), single device.
 
-The unified step is an execution-shape change, not a semantic one: at
+Batch composition is an execution-shape choice, not a semantic one: at
 temperature 0 every request's output depends only on its own prompt and
-KV, so mixed-batch outputs must equal the legacy two-phase loop
-byte-for-byte — on a prefill storm, across a live layout switch, under
-the fused decode loop, and with shared-prefix reuse in play.
+KV, so a token budget of one prefill chunk per step and one of four
+chunks (`WIDE`) must give byte-identical outputs — on a prefill storm,
+across a live layout switch, under the fused decode loop, and with
+shared-prefix reuse in play — and so must a run whose layout never
+switched.
 """
 import copy
 
@@ -24,6 +26,9 @@ from repro.serving.workloads import StormSpec, replay, storm_trace
 def mesh11():
     return make_mesh((1, 1), ("data", "model"))
 
+
+# token budget of four prefill chunks (the default budget is one chunk)
+WIDE = 32
 
 SPEC = StormSpec(n_decoders=2, decoder_prompt=6, decoder_output=10,
                  n_storm=3, storm_prompt=24, storm_output=2,
@@ -58,64 +63,69 @@ def _run_trace(cfg, mesh, reqs0, **kw):
 
 def test_mixed_matches_two_phase_on_storm_trace(tiny_moe, mesh11):
     """The flagship identity: a prefill storm over live decoders produces
-    byte-identical outputs under one mixed dispatch per iteration and
-    under the legacy prefill-then-decode pair."""
+    byte-identical outputs whether each dispatch packs one prefill chunk
+    beside the decode rows or four."""
     reqs0 = storm_trace(SPEC, seed=0)
-    out_m, eng_m = _run_trace(tiny_moe, mesh11, reqs0, mixed_batch=True)
-    out_t, eng_t = _run_trace(tiny_moe, mesh11, reqs0, mixed_batch=False)
-    assert out_m == out_t
-    # the storm really did share dispatches with live decode rows
-    assert eng_m.metrics.mixed_dispatches > 0
-    assert eng_t.metrics.mixed_dispatches == 0
+    out_n, eng_n = _run_trace(tiny_moe, mesh11, reqs0)
+    out_w, eng_w = _run_trace(tiny_moe, mesh11, reqs0, token_budget=WIDE)
+    assert out_n == out_w
+    # the storm really did share dispatches with live decode rows, and
+    # the wide budget packed its prompts into fewer dispatches
+    assert eng_n.metrics.mixed_dispatches > 0
+    assert eng_w.metrics.mixed_dispatches > 0
+    assert eng_w.metrics.dispatches < eng_n.metrics.dispatches
 
 
 def test_mixed_matches_two_phase_across_live_switch(tiny_moe, mesh11):
-    """Same identity with a live tp->ep switch mid-run in both modes
-    (the switch drains in-flight work, then the new layout resumes the
-    same plan shapes)."""
+    """Same identity with a live tp->ep switch mid-run under both budgets,
+    against a never-switched run (the switch drains in-flight work, then
+    the new layout resumes the same plan shapes)."""
     rng = np.random.default_rng(1)
     reqs0 = [Request(rid=i, prompt=list(rng.integers(5, 200, 6 + 8 * (i % 2))),
                      max_new_tokens=6, forced_len=6, arrival_s=0.0)
              for i in range(5)]
 
-    def run(mixed):
-        eng = _mk(tiny_moe, mesh11, mixed_batch=mixed)
+    def run(switch, **kw):
+        eng = _mk(tiny_moe, mesh11, **kw)
         for r in copy.deepcopy(reqs0):
             eng.submit(r)
         switched, i = False, 0
         while eng.pending or eng.waiting or eng.prefilling or eng.running:
-            if not switched and eng.running:
+            if switch and not switched and eng.running:
                 eng.execute_switch("ep")
                 switched = True
             eng.step()
             i += 1
             assert i < 1000
-        assert switched
+        assert switched == switch
         return _outputs(eng, reqs0)
 
-    assert run(True) == run(False)
+    base = run(False)
+    assert run(True) == base
+    assert run(True, token_budget=WIDE) == base
 
 
 def test_mixed_with_fused_decode_suspends_and_resumes(tiny_moe, mesh11):
     """decode_steps > 1: a storm forces the fused pipeline to drain to a
     step boundary (suspend), serve single-token mixed steps, then re-join
-    the fused loop — outputs still byte-identical to every other mode."""
+    the fused loop — outputs still byte-identical to single-token decode
+    under either budget."""
     rng = np.random.default_rng(2)
     reqs0 = [Request(rid=i, prompt=list(rng.integers(5, 200, 5 + 10 * (i % 2))),
                      max_new_tokens=9, forced_len=9, arrival_s=0.0)
              for i in range(5)]
 
-    def run(mixed, steps):
-        eng = _mk(tiny_moe, mesh11, mixed_batch=mixed, decode_steps=steps)
+    def run(steps, **kw):
+        eng = _mk(tiny_moe, mesh11, decode_steps=steps, **kw)
         for r in copy.deepcopy(reqs0):
             eng.submit(r)
         eng.run(max_steps=2000)
         return _outputs(eng, reqs0)
 
-    ref = run(False, 1)
-    assert run(True, 1) == ref
-    assert run(True, 4) == ref
-    assert run(False, 4) == ref
+    ref = run(1)
+    assert run(4) == ref
+    assert run(1, token_budget=WIDE) == ref
+    assert run(4, token_budget=WIDE) == ref
 
 
 def test_mixed_budget_cap_and_min_grant_invariant(tiny_moe, mesh11):
@@ -146,22 +156,79 @@ def test_mixed_budget_cap_and_min_grant_invariant(tiny_moe, mesh11):
 
 def test_mixed_matches_two_phase_with_shared_prefixes(tiny_moe, mesh11):
     """Prefix-cache forks + CoW under the mixed planner: groups of
-    requests sharing one prompt reuse cached pages and still match the
-    two-phase outputs byte-for-byte."""
+    requests sharing one prompt reuse cached pages, and the outputs under
+    either budget match byte-for-byte."""
     rng = np.random.default_rng(4)
     base = list(rng.integers(5, 200, 10))
     reqs0 = [Request(rid=i, prompt=list(base) + [int(i) + 7],
                      max_new_tokens=6, forced_len=6, arrival_s=0.0)
              for i in range(4)]
 
-    def run(mixed):
-        eng = _mk(tiny_moe, mesh11, mixed_batch=mixed)
+    def run(**kw):
+        eng = _mk(tiny_moe, mesh11, **kw)
         for r in copy.deepcopy(reqs0):
             eng.submit(r)
         eng.run(max_steps=2000)
         return _outputs(eng, reqs0), eng
 
-    out_m, eng_m = run(True)
-    out_t, _ = run(False)
-    assert out_m == out_t
-    assert eng_m.metrics.prefix_hits > 0
+    out_n, eng_n = run()
+    out_w, eng_w = run(token_budget=WIDE)
+    assert out_n == out_w
+    assert eng_n.metrics.prefix_hits > 0 and eng_w.metrics.prefix_hits > 0
+
+
+def test_switch_overlap_steps_dispatch_decode_only_plans(tiny_moe, mesh11):
+    """Between the layer chunks of a chunked switch the engine keeps
+    decoding on the source layout: every overlap step is a mixed plan with
+    decode rows only (Sq 1, the rung `pick_B` gives the running rows)
+    dispatched through run_mixed; prefill waits for the commit, and the
+    outputs match a never-switched run."""
+    rng = np.random.default_rng(5)
+    reqs0 = [Request(rid=i, prompt=list(rng.integers(5, 200, 5)),
+                     max_new_tokens=12, forced_len=12, arrival_s=0.0)
+             for i in range(3)]
+    # a long prompt still prefilling when the first switch starts
+    reqs0.append(Request(rid=3, prompt=list(rng.integers(5, 200, 40)),
+                         max_new_tokens=4, forced_len=4, arrival_s=0.0))
+
+    def run(switches):
+        eng = _mk(tiny_moe, mesh11, chunk_layers=1)
+        seen = []                  # [plan, rung expected, in switch, ran]
+        plan_mixed, run_mixed = eng.sched.plan_mixed, eng.ex.run_mixed
+
+        def spy_plan(*a, **k):
+            B = eng.sched.pick_B(max(1, len(eng.sched.running)))
+            plan = plan_mixed(*a, **k)
+            seen.append([plan, B, eng.switch_in_progress(), False])
+            return plan
+
+        def spy_run(plan, step_i):
+            assert seen[-1][0] is plan
+            seen[-1][3] = True
+            return run_mixed(plan, step_i)
+
+        eng.sched.plan_mixed, eng.ex.run_mixed = spy_plan, spy_run
+        for r in copy.deepcopy(reqs0):
+            eng.submit(r)
+        sw, i = dict(switches), 0
+        while eng.sched.has_work():
+            if i in sw:
+                assert eng.sched.prefilling or sw[i] == "tp"
+                assert eng.execute_switch(sw[i])
+            eng.step()
+            i += 1
+            assert i < 1000
+        return _outputs(eng, reqs0), seen, eng
+
+    base, _, _ = run(())
+    out, seen, eng = run(((2, "ep"), (9, "tp")))
+    assert out == base
+    assert len(eng.switch_records) == 2
+    overlap = [s for s in seen if s[2]]
+    # two layers, one per chunk: two overlap steps a switch
+    assert len(overlap) == 4
+    for plan, B, _, ran in overlap:
+        assert ran and plan.rows
+        assert plan.Sq == 1 and plan.prefill_tokens == 0
+        assert {row.kind for row in plan.rows} == {"decode"}
+        assert plan.B == B

@@ -258,11 +258,11 @@ def test_plan_decode_records_grow_decisions():
     q = _running(s, 1, npages=1)
     q.prefill_pos = 3
     q.output = [5]                            # next decode write needs page 2
-    B, stepped = s.plan_decode(step_i=0)
-    assert stepped == [q]
+    plan = s.plan_mixed(0, budget=8, chunk=0)      # decode rows only
+    assert [row.req for row in plan.rows] == [q] and plan.Sq == 1
     assert [d for d in s.last_decisions if isinstance(d, Grow)]
     # next pass clears the log; a no-growth step records nothing
-    B, stepped = s.plan_decode(step_i=1)
+    s.plan_mixed(1, budget=8, chunk=0)
     assert not s.last_decisions
 
 
@@ -355,6 +355,28 @@ def test_plan_mixed_pure_decode_keeps_decode_step_shape():
     plan = s.plan_mixed(0, budget=8, chunk=16)
     assert plan.Sq == 1 and plan.prefill_tokens == 0
     assert [r.kind for r in plan.rows] == ["decode"]
+
+
+@pytest.mark.parametrize("qos", [False, True])
+def test_plan_mixed_chunk_zero_plans_decode_rows_only(qos):
+    """chunk 0 (the decode steps between a switch's chunks): prefill waits,
+    even past the min-grant, and the decode rows, rung and slots are the
+    ones a scheduler with no prefill waiting plans."""
+    plans = []
+    for prefill in (True, False):
+        s = make_sched(npages=65, qos=_qos() if qos else None)
+        for i in range(5):
+            _decoding(s, 10 + i)
+        if prefill:
+            s.submit(req(0, plen=20, out=4))
+            s.admit(t=0.0)
+            assert len(s.start_prefills()) == 1
+        plan = s.plan_mixed(3, budget=4, chunk=0 if prefill else 16)
+        plans.append((plan.B, plan.Sq, plan.prefill_tokens,
+                      [(r.req.rid, r.kind, r.row, r.start_pos, r.n_tokens)
+                       for r in plan.rows]))
+    assert plans[0] == plans[1]
+    assert plans[0][:3] == (8, 1, 0)
 
 
 def test_plan_mixed_prefill_fifo_and_chunk_clamp():

@@ -112,19 +112,6 @@ def test_request_stamps_prefill_start(tiny_moe, tmp_path):
             <= r.finish_s
 
 
-def test_two_phase_step_phases(tiny_moe, tmp_path):
-    """The legacy prefill-then-decode iteration opens a plan, the executor
-    phases and a commit for each of its dispatches."""
-    eng = _engine(tiny_moe, mixed_batch=False)
-    spans, logged = _traced(eng, tmp_path)
-    for (st, kids), dispatches in zip(by_step(spans), logged):
-        assert len(kids) + 1 <= MAX_SPANS_PER_STEP
-        n = len(dispatches)
-        assert sum(1 for k in kids if k[0] == "exec.launch") == n
-        assert st[3]["dec"] == sum(d[2] for d in dispatches)
-        assert st[3]["pre"] == sum(d[3] for d in dispatches)
-
-
 def test_fused_decode_span(tiny_moe, tmp_path):
     eng = _engine(tiny_moe, decode_steps=2)
     spans, _ = _traced(eng, tmp_path, n_reqs=2)

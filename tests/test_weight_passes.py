@@ -19,7 +19,7 @@ from repro.serving.request import Request
 DENSE_ROWS = 128            # the row tile of the grid before the counts
 
 
-def _serve_recording_routes(cfg, monkeypatch, mixed_batch):
+def _serve_recording_routes(cfg, monkeypatch, token_budget=0):
     """Serve five requests; return the engine and, per MoE call, the
     step's routed expert ids and the call's expert capacity C."""
     seen = []
@@ -38,7 +38,7 @@ def _serve_recording_routes(cfg, monkeypatch, mixed_batch):
         CacheConfig(page_size=4, pages_ep=64, max_pages_per_req=16),
         ecfg=EngineConfig(start_layout="tp", ladder=(4, 8), prefill_chunk=8,
                           temperature=0.0, policy=pol,
-                          mixed_batch=mixed_batch))
+                          token_budget=token_budget))
     rng = np.random.default_rng(3)
     for i in range(5):
         eng.submit(Request(rid=i, prompt=list(rng.integers(5, 200, 5 + 4 * i)),
@@ -66,9 +66,9 @@ def _host_passes(cfg, seen):
     return made, dense
 
 
-@pytest.mark.parametrize("mixed_batch", [True, False])
-def test_weight_passes_follow_routing(tiny_moe, monkeypatch, mixed_batch):
-    eng, seen = _serve_recording_routes(tiny_moe, monkeypatch, mixed_batch)
+@pytest.mark.parametrize("token_budget", [0, 32])
+def test_weight_passes_follow_routing(tiny_moe, monkeypatch, token_budget):
+    eng, seen = _serve_recording_routes(tiny_moe, monkeypatch, token_budget)
     m = eng.metrics
     assert seen and m.dispatches > 0
     assert (m.moe_weight_passes, m.moe_weight_passes_dense) == \
@@ -82,7 +82,7 @@ def test_weight_passes_equal_dense_when_tiles_are_full(tiny_moe,
     """Every token routed to both of two experts: each expert's count is
     the step's C, which fills the one row tile of either grid."""
     cfg = tiny_moe.replace(num_experts=2, top_k=2, capacity_factor=1.0)
-    eng, seen = _serve_recording_routes(cfg, monkeypatch, True)
+    eng, seen = _serve_recording_routes(cfg, monkeypatch)
     m = eng.metrics
     assert all(C <= DENSE_ROWS for _, C in seen)
     assert m.moe_weight_passes == m.moe_weight_passes_dense > 0
