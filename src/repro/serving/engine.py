@@ -13,11 +13,13 @@ The engine is decomposed into three layers (DESIGN.md §7):
 `MoebiusEngine` wires the first two and keeps the classic synchronous
 `step()`/`run()` API: admission -> policy -> (switch?) -> ONE
 token-budgeted mixed dispatch per iteration (decode rows first, prefill
-chunks into the remaining budget; DESIGN.md §10). The switch is executed
-between steps without draining: request metadata is rewritten on host,
-expert weights are resharded and the paged KV migrated by the jitted
-movers, and the target layout's pre-warmed step functions are
-*selected*, not rebuilt. The `SwitchCoordinator` observes the
+chunks into the remaining budget; DESIGN.md §10), pipelined one deep: an
+iteration launches its dispatch, then lands the one before it. The
+switch is executed between steps without draining requests (only the
+dispatch in flight lands): request metadata is rewritten on host, expert
+weights are resharded and the paged KV migrated by the jitted movers, and
+the target layout's pre-warmed step functions are *selected*, not
+rebuilt. The `SwitchCoordinator` observes the
 Scheduler's queue snapshot — never engine internals.
 """
 from __future__ import annotations
@@ -186,7 +188,11 @@ class MoebiusEngine:
                                qos=qos)
         self.sched.set_layout(start)   # syncs sched.G with start's world
         self.sched.clear_slot = self.ex.clear_slot
+        self.sched.drain = self._drain
         self.ex.on_finish = self.sched.finish_request
+        # the mixed-step pipeline, one deep: (plan, device next tokens) of
+        # the dispatch launched and not yet landed, or None
+        self._in_flight: tuple | None = None
         # the policy runs on the engine's virtual clock (time_scale-aware),
         # never wall time: cooldowns stay correct under scaled replay; it
         # observes the SCHEDULER's queue snapshot, not engine internals
@@ -323,6 +329,7 @@ class MoebiusEngine:
         self.sched.clear_prefix_cache()
 
     def _drain_decode(self) -> None:
+        self._drain("fault")
         self.ex.drain_decode()
 
     # ------------------------------------------------------------------
@@ -331,11 +338,14 @@ class MoebiusEngine:
     def _decode_step(self) -> None:
         """The decode step between the chunks of a chunked switch: prefill
         does not advance while a switch session is staging, so it is the
-        serve step planned with no prefill chunk (or the fused loop)."""
+        serve step planned with no prefill chunk (or the fused loop). It
+        lands before the next chunk, unpipelined, so the commit's
+        dirty-page delta sees every write of the overlap window."""
         if self.ecfg.decode_steps > 1:
             self.ex.decode_fused(self.sched, self._step_i)
         elif self.sched.running:
             self._dispatch(chunk=0)
+            self._drain("switch")
 
     def _mixed_step(self) -> tuple[int, int]:
         """ONE token-budgeted dispatch per iteration (DESIGN.md §10): all
@@ -346,6 +356,7 @@ class MoebiusEngine:
             if not self.sched.prefilling:
                 # pure decode: the fused N-step pipeline serves it (copies
                 # from admission land inside decode_fused's drain)
+                self._drain("fused")
                 self.ex.decode_fused(self.sched, self._step_i)
                 return 0, 0
             # a prefill chunk joins: drain the one-deep pipeline to a step
@@ -355,8 +366,11 @@ class MoebiusEngine:
         return self._dispatch(chunk=self.ex.prefill_chunk)
 
     def _dispatch(self, chunk: int) -> tuple[int, int]:
-        """Plan, copy, run and commit one mixed step with prefill chunks of
-        at most `chunk` tokens (0: decode rows only)."""
+        """Plan, copy and launch one mixed step with prefill chunks of at
+        most `chunk` tokens (0: decode rows only), then land the dispatch
+        launched before it: the pipeline is one deep (DESIGN.md §10), so
+        the host plans and stages step n+1 while the chip runs step n. A
+        step with nothing to launch lands what is in flight."""
         with span("sched.plan"):
             plan = self.sched.plan_mixed(self._step_i,
                                          budget=self.token_budget,
@@ -365,11 +379,33 @@ class MoebiusEngine:
         # must land before the dispatch that could write their source pages
         self.ex.run_copies(self.sched.drain_copies())
         if not plan.rows:
+            self._drain("idle")
             return 0, 0
         nxt = self.ex.run_mixed(plan, self._step_i)
-        with span("sched.commit"):
-            self.sched.commit_mixed(plan, nxt, self.now())
+        prev, self._in_flight = self._in_flight, (plan, nxt)
+        if prev is not None:
+            self.metrics.overlapped_dispatches += 1
+            self._land(*prev)
         return plan.B, plan.Sq
+
+    def _land(self, plan, nxt) -> None:
+        """Fetch one launched dispatch's tokens and commit them."""
+        toks = self.ex.fetch_mixed(nxt)
+        with span("sched.commit"):
+            self.sched.commit_mixed(plan, toks, self.now())
+
+    def _drain(self, cause: str) -> None:
+        """Land the dispatch in flight, if any, so every request sits at a
+        step boundary: before a step that launches nothing, a switch, a
+        preemption or truncation, a cancel, deadline expiry or a fault,
+        and at the end of `run`. Counted by cause."""
+        if self._in_flight is None:
+            return
+        with span("exec.drain", cause=cause):
+            prev, self._in_flight = self._in_flight, None
+            self._land(*prev)
+        drains = self.metrics.pipeline_drains
+        drains[cause] = drains.get(cause, 0) + 1
 
     # ------------------------------------------------------------------
     # switch
@@ -400,8 +436,9 @@ class MoebiusEngine:
 
     def _execute_switch(self, target: LayoutSpec) -> bool:
         cross_world = self.ex._is_cross_world(target)
-        # fused decode: fetch in-flight tokens so every request's kv_len and
-        # pages sit at a step boundary before the plan snapshot
+        # fetch in-flight tokens so every request's kv_len and pages sit at
+        # a step boundary before the plan snapshot
+        self._drain("switch")
         self.ex.drain_decode()
         if self.ex._is_cross_world(target):
             # shrink feasibility gate, BEFORE any planning: the destination
@@ -544,7 +581,8 @@ class MoebiusEngine:
         """Client-side cancellation (SSE disconnect): drop the request
         wherever it sits and free its slot/pages through the scheduler's
         finish path. Returns False for an unknown/finished rid."""
-        self.ex.drain_decode()        # cancel_request needs inflight == 0
+        self._drain("cancel")         # cancel_request needs inflight == 0
+        self.ex.drain_decode()
         r = self.sched.cancel_request(rid)
         if r is None:
             return False
@@ -593,6 +631,8 @@ class MoebiusEngine:
 
     def _apply_fault(self, f) -> None:
         """Act on one fired Fault (see serving/faults.py for the kinds)."""
+        if f.kind != "switch":         # a scripted switch drains itself
+            self._drain("fault")
         self.metrics.faults_injected += 1
         if f.kind == "rank_fail":
             from repro.distributed.elastic import fail_rank
@@ -649,7 +689,7 @@ class MoebiusEngine:
         self._step_i += 1
         m = self.metrics
         dec0, pre0 = m.decode_tokens, m.prefill_tokens
-        passes0 = m.moe_weight_passes
+        passes0, ov0 = m.moe_weight_passes, m.overlapped_dispatches
         with span("step", step=self._step_i) as sp:
             with span("sched.admit"):
                 if self.ecfg.idle_skip:
@@ -660,8 +700,9 @@ class MoebiusEngine:
                         self._apply_fault(f)
                 self.sched.admit(self.now())
                 if self.sched.deadline_due(self.now()):
-                    # expiry finishes requests in place: drain the fused
-                    # pipeline first so none has in-flight tokens
+                    # expiry finishes requests in place: drain both
+                    # pipelines first so none has in-flight tokens
+                    self._drain("deadline")
                     self.ex.drain_decode()
                     self.sched.expire_deadlines(self.now())
             # policy: sample once per iteration, between steps, through the
@@ -688,12 +729,14 @@ class MoebiusEngine:
                                      for a in self.sched.alloc))
             sp.set_metadata(B=B, Sq=Sq, dec=m.decode_tokens - dec0,
                             pre=m.prefill_tokens - pre0,
-                            passes=m.moe_weight_passes - passes0)
+                            passes=m.moe_weight_passes - passes0,
+                            overlap=m.overlapped_dispatches - ov0)
 
     def run(self, max_steps: int = 100000):
         for _ in range(max_steps):
             if not self.sched.has_work():
                 break
             self.step()
-        self.ex.drain_decode()         # flush a half-open fused pipeline
+        self._drain("end")             # flush half-open pipelines
+        self.ex.drain_decode()
         return self.metrics.summary()

@@ -9,7 +9,10 @@ and reports completions back through the scheduler callbacks
 (`commit_mixed` is driven by the engine facade; fused-pipeline
 retirements go through the `on_finish` hook). `run_mixed` is THE dispatch
 path: one step-fn cache keyed by (layout, rung, chunk width) serves
-mixed, pure-decode, and pure-prefill plans alike.
+mixed, pure-decode, and pure-prefill plans alike. It launches and returns
+the step's next tokens on the device; `fetch_mixed` reads them after the
+engine has launched the next step (the one-deep pipeline, DESIGN.md §10),
+and decode rows take tokens still in flight from the carried buffer.
 
 Memory discipline mirrors the paper: the control plane (attention/embed/norm
 packs, compiled steps) is resident for EVERY registered layout (the
@@ -17,6 +20,8 @@ dual-mode buffer); the data plane (expert weights, KV pool) exists once, in
 the active layout.
 """
 from __future__ import annotations
+
+from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +42,8 @@ from repro.serving.metrics import ServeMetrics
 from repro.serving.request import Request
 from repro.serving.scheduler import MixedPlan
 from repro.serving.steps import (build_decode_loop, build_decode_pack,
-                                 build_mixed_step, decode_pack_specs)
+                                 build_mixed_step, carry_sharding,
+                                 decode_pack_specs)
 from repro.tracing import span
 
 
@@ -160,9 +166,16 @@ class Executor:
         # one-deep dispatch pipeline (outputs consumed one iteration late)
         self._dstate: DeviceDecodeState | None = None
         self._pending: tuple | None = None
-        # host staging buffers, reused across steps (keyed by (B, Sq) and
-        # zeroed in place instead of reallocated every dispatch)
+        # host staging buffers, keyed by (B, Sq): two sets a shape, reset
+        # in place and used in turn (`_staging`)
         self._stage_bufs: dict = {}
+        # the mixed-step pipeline: the carried token buffer (the last
+        # dispatch's next tokens, on the device), the rows a rank holds in
+        # that dispatch, and (plan, passes, logits) of each dispatch
+        # launched and not yet fetched, oldest first
+        self._carry = self._new_carry(active)
+        self._carry_rows = 1
+        self._launched: deque = deque()
         # same-world switch executors, lazily built per world; the
         # cross-world switcher stages through host memory (no common mesh)
         self._switchers: dict[int, SwitchExecutor] = {}
@@ -200,6 +213,15 @@ class Executor:
         sh = NamedSharding(self.meshes[w], P(self.da, self.m))
         return jax.jit(lambda: jnp.zeros(shape, self.cfg.param_dtype),
                        out_shardings=sh)()
+
+    def _new_carry(self, layout: LayoutSpec):
+        """Zero carried token buffer for `layout`: (Dd, top rung) in the
+        serve step's row sharding, whatever rung a step runs."""
+        shape = (self.Dd, self.ladder_for(layout)[-1])
+        return jax.device_put(
+            np.zeros(shape, np.int32),
+            carry_sharding(self._mesh_for(layout), layout,
+                           data_axes=(self.da,), model_axis=self.m))
 
     def _experts_to_host(self) -> dict:
         """Canonical (L, E, ...) numpy copy of the active expert store,
@@ -306,11 +328,12 @@ class Executor:
             for b in self.ladder_for(lo):
                 z2 = jnp.zeros((self.Dd, b), jnp.int32)
                 bt = jnp.zeros((self.Dd, b, maxp), jnp.int32)
+                src = jnp.full((self.Dd, b), -1, jnp.int32)
                 for sq in (1, self.prefill_chunk):
                     self._mixed_fn(lo, b, sq)(
                         pk, jnp.zeros_like(self.kv_flat),
                         jnp.zeros((self.Dd, b, sq), jnp.int32), z2, z2, bt,
-                        key)
+                        key, self._carry, src)
                 if self.ecfg.decode_steps > 1:
                     # match the live call's committed shardings exactly
                     st = DeviceDecodeState(self._mesh_for(lo), lo, self.Dd,
@@ -398,33 +421,45 @@ class Executor:
     # mixed-batch dispatch (THE serve path)
     # ------------------------------------------------------------------
     def _staging(self, B: int, Sq: int) -> tuple:
-        """(tokens, positions, valid_len, block_table) host buffers for one
-        (rung, chunk) shape — zeroed in place and reused across steps."""
-        bufs = self._stage_bufs.get((B, Sq))
-        if bufs is None:
+        """(tokens, positions, valid_len, block_table, src) host buffers
+        for one (rung, chunk) shape — reset in place (src to -1, the rest
+        to 0) and reused. Two sets a shape take turns: a dispatch may
+        still read its inputs from host memory while the next is staged,
+        and the one before it has been fetched by then."""
+        sets = self._stage_bufs.get((B, Sq))
+        if sets is None:
             maxp = self.cc.max_pages_per_req
-            bufs = (np.zeros((self.Dd, B, Sq), np.int32),
-                    np.zeros((self.Dd, B), np.int32),
-                    np.zeros((self.Dd, B), np.int32),
-                    np.zeros((self.Dd, B, maxp), np.int32))
-            self._stage_bufs[(B, Sq)] = bufs
-        else:
-            for a in bufs:
-                a.fill(0)
+            sets = [tuple(np.zeros(shape, np.int32) for shape in (
+                (self.Dd, B, Sq), (self.Dd, B), (self.Dd, B),
+                (self.Dd, B, maxp), (self.Dd, B))) for _ in range(2)]
+            self._stage_bufs[(B, Sq)] = sets
+        sets.reverse()
+        bufs = sets[0]
+        for a in bufs[:4]:
+            a.fill(0)
+        bufs[4].fill(-1)
         return bufs
 
-    def run_mixed(self, plan: MixedPlan, step_i: int) -> np.ndarray:
-        """Dispatch ONE mixed-batch step: decode rows (n_tokens == 1) and
-        prefill-chunk rows under a single executable. Returns the (Dd, B)
-        next-token array the engine hands to Scheduler.commit_mixed."""
+    def run_mixed(self, plan: MixedPlan, step_i: int) -> jax.Array:
+        """Stage and launch ONE mixed-batch step: decode rows (n_tokens ==
+        1) and prefill-chunk rows under a single executable. A decode row
+        whose input token is still in flight (`MixedRow.src`) takes it
+        from the carried token buffer on the device. Returns the step's
+        (Dd, B) next tokens, still on the device: `fetch_mixed` brings
+        them to the host for Scheduler.commit_mixed, after the next
+        dispatch is launched."""
         B, Sq = plan.B, plan.Sq
         with span("exec.stage", B=B, Sq=Sq, slots=self.Dd * B * Sq) as sp:
-            toks, pos, vl, bt = self._staging(B, Sq)
+            toks, pos, vl, bt, src = self._staging(B, Sq)
             n_dec = n_pref = 0
             for row in plan.rows:
                 r, d, s, n = row.req, row.d, row.row, row.n_tokens
                 if row.kind == "decode":
-                    toks[d, s, 0] = r.output[-1]
+                    if row.src >= 0:
+                        # its index in its rank's block of the carry
+                        src[d, s] = row.src % self._carry_rows
+                    else:
+                        toks[d, s, 0] = r.output[-1]
                     n_dec += 1
                 else:
                     toks[d, s, :n] = r.prompt_array()[row.start_pos:
@@ -437,24 +472,41 @@ class Executor:
             fn = self._mixed_fn(self.active, B, Sq)
             args = (self._assemble_pack(self.active), self.kv_flat,
                     jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(vl),
-                    jnp.asarray(bt), self._step_key(step_i))
+                    jnp.asarray(bt), self._step_key(step_i), self._carry,
+                    jnp.asarray(src))
         with span("exec.launch"):
-            nxt, self.kv_flat, passes, *logits = fn(*args)
-        with span("exec.fetch"):
-            out, passes = jax.device_get((nxt, passes))
-            self.metrics.weight_passes(passes)
-            if logits:
-                lg = np.asarray(logits[0])
-                for row in plan.rows:
-                    # keyed by the KV position of the token these logits
-                    # sample
-                    key = (row.req.rid, row.start_pos + row.n_tokens)
-                    self.logits[key] = lg[row.d, row.row, :self.cfg.vocab_size]
+            nxt, self.kv_flat, passes, self._carry, *logits = fn(*args)
+        # start the device->host copies now; the host reads them after it
+        # has staged and launched the next dispatch
+        lg = logits[0] if logits else None
+        for a in (nxt, passes, lg):
+            if hasattr(a, "copy_to_host_async"):
+                a.copy_to_host_async()
+        self._carry_rows = (B // self._world(self.active)
+                            if self.active.slots_sharded else B)
+        self._launched.append((plan, passes, lg))
         if n_pref:
             self.metrics.prefill(n_pref)
         if n_dec:
             self.metrics.decode(n_dec, 1)
         self.metrics.dispatch(mixed=bool(n_dec and n_pref))
+        return nxt
+
+    def fetch_mixed(self, nxt) -> np.ndarray:
+        """Host copy of the oldest launched dispatch's next tokens (`nxt`,
+        as `run_mixed` returned it); records its expert weight passes and,
+        under record_logits, its logits."""
+        plan, passes, lg = self._launched.popleft()
+        with span("exec.fetch"):
+            out, passes = jax.device_get((nxt, passes))
+            self.metrics.weight_passes(passes)
+            if lg is not None:
+                lg = np.asarray(lg)
+                for row in plan.rows:
+                    # keyed by the KV position of the token these logits
+                    # sample
+                    key = (row.req.rid, row.start_pos + row.n_tokens)
+                    self.logits[key] = lg[row.d, row.row, :self.cfg.vocab_size]
         return out
 
     # ------------------------------------------------------------------
@@ -580,6 +632,7 @@ class Executor:
         self.active = target
         self._dstate = None
         self._pack_cache.clear()
+        self._carry = self._new_carry(target)
 
     def _commit_cross_world(self, target: LayoutSpec, live: list[Request]):
         """Commit the cross-world session: device_put the staged host

@@ -31,6 +31,13 @@ class ServeMetrics:
     # prefill rows
     dispatches: int = 0
     mixed_dispatches: int = 0
+    # the mixed-step pipeline (DESIGN.md §10): dispatches launched while
+    # the one before was still in flight, and drains to a step boundary
+    # by cause ("idle", "switch", "starved", "truncate", "cancel",
+    # "deadline", "fault", "fused", "end"). Every mixed dispatch is either
+    # overlapped or the last before a drain (or still in flight).
+    overlapped_dispatches: int = 0
+    pipeline_drains: dict = field(default_factory=dict)
     # prefill compute actually dispatched (tokens through the prefill step)
     prefill_tokens: int = 0
     # expert weight passes of the grouped GEMM, summed over dispatches,
@@ -223,6 +230,8 @@ class ServeMetrics:
                                     else float("nan")),
             "dispatches": self.dispatches,
             "mixed_dispatches": self.mixed_dispatches,
+            "overlapped_dispatches": self.overlapped_dispatches,
+            "pipeline_drains": dict(self.pipeline_drains),
             "decode_dispatches": self.decode_dispatches,
             "decode_substeps": self.decode_substeps,
             "decode_tokens": self.decode_tokens,

@@ -51,11 +51,14 @@ class Request:
     # absolute virtual-clock deadline (frontend `max_time`): past it the
     # Scheduler truncates the request with whatever it generated
     deadline_s: float | None = None
-    # fused-decode bookkeeping (engine decode_steps > 1): tokens dispatched
-    # on device but not yet fetched, and the remaining-token budget the
-    # DeviceDecodeState currently holds for this request's slot
+    # tokens dispatched on device but not yet fetched (the mixed-step
+    # pipeline and the fused decode loop), and the remaining-token budget
+    # the fused loop's DeviceDecodeState holds for this request's slot
     inflight: int = 0
     budget_dev: int = 0
+    # mixed-step pipeline: the row of the dispatch in flight that samples
+    # this request's next token (read only while `inflight` is above 0)
+    carry_row: int = -1
     # metrics, on the engine clock: the first time the request left
     # `waiting` (its prefill phase runs from here to the first token)
     prefill_start_s: float | None = None
@@ -88,6 +91,10 @@ class Request:
     def target_len(self) -> int:
         return self.forced_len if self.forced_len is not None \
             else self.max_new_tokens
+
+    def to_plan(self) -> int:
+        """Tokens still to plan: those neither produced nor in flight."""
+        return self.target_len - len(self.output) - self.inflight
 
     def done(self) -> bool:
         return len(self.output) >= self.target_len

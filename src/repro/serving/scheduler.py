@@ -101,7 +101,7 @@ class Truncate:
 @dataclass(frozen=True)
 class MixedRow:
     """One batch row of a mixed dispatch. A decode row feeds the last
-    sampled token (`n_tokens == 1`, `start_pos == kv_len - 1`); a prefill
+    sampled token (`n_tokens == 1`, `start_pos` its KV position); a prefill
     row feeds the next `n_tokens` prompt tokens from `start_pos`. Both run
     through the same step function — the row shape IS the phase."""
     req: Request
@@ -110,6 +110,9 @@ class MixedRow:
     start_pos: int                # KV position of the row's first token
     n_tokens: int                 # valid tokens this dispatch
     kind: str                     # "decode" | "prefill"
+    # decode row whose input token is still in flight: the row of the
+    # dispatch in flight that samples it (-1: the token is on the host)
+    src: int = -1
 
 
 @dataclass(frozen=True)
@@ -175,6 +178,9 @@ class Scheduler:
         # Executor hook: vacate a fused-decode device slot (no-op default
         # covers the single-step path and device-free unit tests)
         self.clear_slot = self._clear_slot_host
+        # engine hook: land the mixed dispatch in flight, before planning
+        # preempts or truncates (`drain(cause)`; no-op when nothing flies)
+        self.drain = lambda cause: None
 
         self.pending: deque[Request] = deque()     # not yet arrived
         self.waiting: list[Request] = []
@@ -369,7 +375,7 @@ class Scheduler:
         any fused-decode device slot, and send it back to `waiting` for
         re-prefill. Shared by pool-exhaustion preemption and rank-failure
         recovery (distributed/elastic.py). Requires r.inflight == 0 —
-        callers drain the fused pipeline first."""
+        callers land every dispatch in flight first."""
         assert r.inflight == 0, "requeueing a request with in-flight tokens"
         d = r.data_group
         if r.pages:
@@ -741,25 +747,6 @@ class Scheduler:
         self.waiting = [r for r in self.waiting if id(r) not in started]
         return out
 
-    def finish_prefill(self, r: Request, n: int, next_token: int,
-                       t: float) -> bool:
-        """Advance a prefilling request by the `n` tokens the Executor ran;
-        on prompt completion take the first sampled token, index the pages
-        in the prefix cache, and promote to `running` (or finish outright).
-        Returns True when the request completed its prefill."""
-        r.prefill_pos += n
-        if r.prefill_pos < r.prompt_len:
-            return False
-        self.cache_insert(r)
-        r.output.append(next_token)
-        r.first_token_s = t
-        r.state = State.RUNNING
-        self.prefilling.remove(r)
-        self.running[r.rid] = r
-        if r.done():
-            self.finish_request(r)
-        return True
-
     # ------------------------------------------------------------------
     # decode planning
     # ------------------------------------------------------------------
@@ -784,7 +771,7 @@ class Scheduler:
         or "dry" (pool exhausted even after cache eviction — preempt)."""
         if not self.cow_if_shared(r):
             return "dry"
-        need = pages_needed(r.kv_len + 1, self.cc.page_size)
+        need = pages_needed(r.kv_len + r.inflight + 1, self.cc.page_size)
         if need <= len(r.pages):
             return True
         if need > self.cc.max_pages_per_req:
@@ -843,11 +830,26 @@ class Scheduler:
         rows grow their pages (CoW first) and starved pools recover by
         preemption or truncation — prefill rows already own their pages
         (acquired at `start_prefill` under the watermark) and are excluded
-        from preemption while scheduled."""
+        from preemption while scheduled.
+
+        The plan's tokens count as in flight once it is made (every plan
+        with rows is dispatched): a decode row advances its request's
+        `inflight`, a prefill row its `prefill_pos`, and a request whose
+        last prompt chunk it carries turns `running` with its first token
+        in flight. So the next plan may be made before this one's tokens
+        land (`commit_mixed`): it sees every position, page and finish by
+        length, and a decode row whose input token is still in flight
+        names the row that samples it (`MixedRow.src`). Preemption and
+        truncation first land the dispatch in flight (the `drain` hook)."""
         self.last_decisions = []
         per_group: dict[int, list[Request]] = {d: [] for d in range(self.Dd)}
+        live: list[Request] = []       # runners with a token left to plan
         for r in self.running.values():
-            per_group[r.data_group].append(r)
+            if r.to_plan() > 0:
+                live.append(r)
+                per_group[r.data_group].append(r)
+            else:
+                r.slot = None          # its last token is in flight
 
         def rotated(reqs):
             lst = sorted(reqs, key=lambda q: q.rid)
@@ -863,7 +865,7 @@ class Scheduler:
         else:
             cap_loc = max(1, cap_rows // self.G)
             cnt: dict = {}
-            for r in self.running.values():
+            for r in live:
                 k = (r.data_group, r.owner_rank)
                 cnt[k] = cnt.get(k, 0) + 1
             n_dec = sum(min(c, cap_loc) for c in cnt.values())
@@ -910,7 +912,7 @@ class Scheduler:
                 bs_need = max(bs_need, loads[k[0]][k[1]] + pref_cnt[k])
             B = self.pick_B(bs_need * self.G)
             bs_loc = B // self.G
-            for r in self.running.values():
+            for r in live:
                 r.slot = (r.owner_rank * bs_loc + r.slot_local
                           if r.slot_local < bs_loc else None)
             used_g = {(d, g): min(loads[d][g], bs_loc)
@@ -926,10 +928,15 @@ class Scheduler:
         rows: list[MixedRow] = []
         stepped: list[Request] = []
         starved: list[Request] = []
-        for r in list(self.running.values()):
+        for r in live:
             if r.slot is None or r.slot >= B:
                 continue
             ok = self.ensure_pages(r)
+            if ok is not True:
+                # land the dispatch in flight first: its finishes free
+                # pages, and a truncated request keeps its last token
+                self.drain("truncate" if ok == "cap" else "starved")
+                ok = self.ensure_pages(r)
             if ok == "cap":
                 # at max_pages_per_req with no room for the next token:
                 # retrying forever would livelock — finish with truncation
@@ -939,8 +946,9 @@ class Scheduler:
                 starved.append(r)
                 continue
             stepped.append(r)
-            rows.append(MixedRow(r, r.data_group, r.slot, r.kv_len - 1, 1,
-                                 "decode"))
+            rows.append(MixedRow(r, r.data_group, r.slot,
+                                 r.kv_len + r.inflight - 1, 1, "decode",
+                                 src=r.carry_row if r.inflight else -1))
         for r, d, row, n in kept:
             rows.append(MixedRow(r, d, row, r.prefill_pos, n, "prefill"))
         if starved:
@@ -951,26 +959,47 @@ class Scheduler:
             # the staged batch
             self.handle_starvation(
                 starved, exclude=stepped + [p[0] for p in kept])
+        for row in rows:
+            self._count_in_flight(row)
         return MixedPlan(B=B, Sq=chunk if kept else 1, rows=tuple(rows),
                          decode_tokens=len(stepped),
                          prefill_tokens=sum(n for *_, n in kept))
 
+    def _count_in_flight(self, row: MixedRow) -> None:
+        """Advance `row`'s request as its dispatch will: a prefill chunk
+        moves the cursor, and the last one indexes the prompt's pages in
+        the prefix cache and promotes the request to `running`; a row
+        that samples a token adds one in flight at `carry_row`."""
+        r = row.req
+        if row.kind == "prefill":
+            r.prefill_pos += row.n_tokens
+            if r.prefill_pos < r.prompt_len:
+                return
+            self.cache_insert(r)
+            r.state = State.RUNNING
+            self.prefilling.remove(r)
+            self.running[r.rid] = r
+        r.inflight += 1
+        r.carry_row = row.row
+
     def commit_mixed(self, plan: MixedPlan, tokens, t: float) -> None:
-        """Retire one mixed dispatch. `tokens` is indexable as
+        """Land one mixed dispatch's tokens. `tokens` is indexable as
         `tokens[d][row]` — the Executor's (Dd, B) next-token array, or
-        plain nested lists in device-free tests. Decode rows append their
-        sampled token; prefill rows advance by their chunk (the sampled
-        token only counts on prompt completion, exactly as
-        `finish_prefill` has always defined)."""
+        plain nested lists in device-free tests. Each row that sampled a
+        token (a decode row, or a prompt's last chunk, whose token is the
+        first, stamped `t`) appends it, and a request that reaches its
+        length finishes; a chunk inside the prompt samples nothing."""
         for row in plan.rows:
             r = row.req
-            if row.kind == "decode":
-                r.output.append(int(tokens[row.d][row.row]))
-                if r.done():
-                    self.finish_request(r)
-            else:
-                self.finish_prefill(r, row.n_tokens,
-                                    int(tokens[row.d][row.row]), t)
+            if (row.kind == "prefill"
+                    and row.start_pos + row.n_tokens < r.prompt_len):
+                continue
+            r.output.append(int(tokens[row.d][row.row]))
+            r.inflight -= 1
+            if row.kind == "prefill":
+                r.first_token_s = t
+            if r.done():
+                self.finish_request(r)
 
     # ------------------------------------------------------------------
     # fused decode planning (decode_steps > 1)
@@ -1033,7 +1062,7 @@ class Scheduler:
                 r.slot = s
                 is_join = True
             s = r.slot
-            remaining = r.target_len - len(r.output) - r.inflight
+            remaining = r.to_plan()
             if remaining <= 0:
                 continue                   # finished on device; awaiting fetch
             kv_eff = r.kv_len + r.inflight

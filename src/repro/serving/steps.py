@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map
@@ -346,6 +347,19 @@ def _chunk_core(cfg, spec: LayoutSpec, pack, pool, tokens, positions,
     return nxt, new_pool, xl, passes
 
 
+def _row_spec(spec: LayoutSpec, m, da) -> P:
+    """Spec of a (Dd, Bslot) per-row array: slots split over the model
+    axis where the layout shards them, else replicated over it."""
+    return P(da, m) if spec.slots_sharded else P(da, None)
+
+
+def carry_sharding(mesh, layout, *, data_axes=("data",),
+                   model_axis: str = "model") -> NamedSharding:
+    """Sharding of the serve step's carried token buffer in `layout`."""
+    return NamedSharding(mesh, _row_spec(get_layout(layout), model_axis,
+                                         tuple(data_axes)))
+
+
 def _layout_geometry(cfg, mesh, layout, cc, Bslot, m, da):
     """Shared builder geometry: spec, shard specs, expert layout, KV view."""
     spec = get_layout(layout)
@@ -359,7 +373,7 @@ def _layout_geometry(cfg, mesh, layout, cc, Bslot, m, da):
         page=cc.page_size, maxp=cc.max_pages_per_req,
         view=cc.view_shape(cfg, G, spec),      # (L,2,pages,page,Kh,dh)
         bs=Bslot // G if spec.slots_sharded else Bslot,
-        bspec2=P(da, m) if spec.slots_sharded else P(da, None),
+        bspec2=_row_spec(spec, m, da),
         bspec3=P(da, m, None) if spec.slots_sharded else P(da, None, None),
         flat_spec=P(da, m))
     return geo
@@ -388,8 +402,16 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
 
     Global signature:
       pack, kv_flat (Dd, G, *rank_shape), tokens (Dd, Bslot, Sq), positions (Dd, Bslot),
-      valid_len (Dd, Bslot), block_table (Dd, Bslot, maxp), key
-      -> (next_token (Dd, Bslot), kv_flat', passes (Dd, G, 2))
+      valid_len (Dd, Bslot), block_table (Dd, Bslot, maxp), key,
+      carry (Dd, Btop), src (Dd, Bslot)
+      -> (next_token (Dd, Bslot), kv_flat', passes (Dd, G, 2), carry')
+    `carry` is the carried token buffer: the previous dispatch's next
+    tokens, each rank's at the front of its own block, so a decode row
+    whose input token is still on the device takes it from there. `src`
+    is the row's index into its rank's block of `carry` (-1: the host
+    staged the token in `tokens`). `carry'` holds this step's next tokens
+    the same way. Its shape is the top rung's whatever `Bslot` is, so a
+    change of rung needs no other executable.
     `passes[d, r]` = rank r's expert weight passes of the step, summed
     over layers and both expert GEMMs: the grouped GEMM's, then the dense
     grid's (`_chunk_core`); zeros for a dense model.
@@ -405,8 +427,13 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
     spec, bs, maxp = g["spec"], g["bs"], g["maxp"]
     bspec2, bspec3, flat_spec = g["bspec2"], g["bspec3"], g["flat_spec"]
 
-    def body(pack, kv_flat, tokens, positions, valid_len, block_table, key):
+    def body(pack, kv_flat, tokens, positions, valid_len, block_table, key,
+             carry, src):
+        carry = carry.reshape(-1)
+        src = src.reshape(bs)
         tokens = tokens.reshape(bs, Sq)
+        fed = jnp.where(src >= 0, carry[jnp.maximum(src, 0)], tokens[:, 0])
+        tokens = tokens.at[:, 0].set(fed)
         positions = positions.reshape(bs)
         valid_len = valid_len.reshape(bs)
         bt = block_table.reshape(bs, maxp)
@@ -419,7 +446,7 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
             attn_backend=attn_backend, moe_backend=moe_backend,
             temperature=temperature, page=g["page"], maxp=maxp, Sq=Sq)
         out = (nxt.reshape(1, bs), new_pool.reshape(kv_flat.shape),
-               passes.reshape(1, 1, 2))
+               passes.reshape(1, 1, 2), carry.at[:bs].set(nxt)[None])
         if return_logits:
             head = pack["embed"] if cfg.tie_embeddings else pack["lm_head"]
             lg = (xl @ head.T.astype(xl.dtype)).astype(jnp.float32)
@@ -429,13 +456,14 @@ def build_mixed_step(cfg: ModelConfig, mesh, layout: str, cc: CacheConfig,
         return out
 
     pspecs = _pack_specs_for(cfg, layout, g["G"], g["G_exp"], m, g["ep_axes"])
-    out_specs = (bspec2, flat_spec, P(da, m, None))
+    out_specs = (bspec2, flat_spec, P(da, m, None), bspec2)
     if return_logits:
         out_specs = out_specs + ((P(da, m, None) if spec.slots_sharded
                                   else P(da, None, None)),)
     smapped = shard_map(
         body, mesh=mesh,
-        in_specs=(pspecs, flat_spec, bspec3, bspec2, bspec2, bspec3, P()),
+        in_specs=(pspecs, flat_spec, bspec3, bspec2, bspec2, bspec3, P(),
+                  bspec2, bspec2),
         out_specs=out_specs, check_vma=False)
     donate_args = (1,) if donate else ()
     return jax.jit(smapped, donate_argnums=donate_args)

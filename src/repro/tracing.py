@@ -11,7 +11,7 @@ arguments known only at the end of a span are added with
 
 The step's span tree (DESIGN.md §9):
 
-    moebius.step {step, B, Sq, dec, pre, passes}
+    moebius.step {step, B, Sq, dec, pre, passes, overlap}
       moebius.sched.admit         admission, deadline expiry, prefill starts
       moebius.policy              queue snapshot + coordinator
       moebius.switch {direction, bytes_sent}  a live switch, when taken
@@ -22,15 +22,23 @@ The step's span tree (DESIGN.md §9):
       moebius.exec.copies         copy-on-write page copies
       moebius.exec.stage {B, Sq, dec, pre, slots}  host buffers + uploads
       moebius.exec.launch         the jitted step call (compiles show here)
-      moebius.exec.fetch          host blocked on the sampled tokens
+      moebius.exec.fetch          host blocked on the previous dispatch's
+                                  sampled tokens
       moebius.exec.fused          one fused N-step decode dispatch
-      moebius.sched.commit        tokens into requests
+      moebius.sched.commit        those tokens into requests
+      moebius.exec.drain {cause}  lands the dispatch in flight (its
+                                  exec.fetch + sched.commit inside)
       moebius.account             per-step bookkeeping
 
-`step`'s B and Sq are its mixed dispatch's shape (0 when it ran none);
-the decode steps between a switch's chunks carry theirs on `exec.stage`.
-`passes` is the expert weight passes the grouped GEMM made in the
-dispatches the step fetched (`ServeMetrics.moe_weight_passes`).
+The mixed step is pipelined one deep: a step launches its own dispatch,
+then fetches and commits the one launched before it (`overlap` 1), or
+launches with nothing in flight (`overlap` 0). `step`'s B, Sq, dec and
+pre are its launched dispatch's (0 when it launched none); the decode
+steps between a switch's chunks carry theirs on `exec.stage`. `passes`
+is the expert weight passes the grouped GEMM made in the dispatches the
+step fetched (`ServeMetrics.moe_weight_passes`). A drain can also open
+inside `sched.plan` (before a preemption or truncation), `switch` or
+`sched.admit` (cancel, deadline, fault).
 
 The switch spans sit at the bounds of `SwitchStats`' timers, so plan +
 commit is the switch's `pause_s`; a monolithic switch has no chunks and

@@ -157,8 +157,9 @@ def test_stream_survives_preemption(tiny_dense, mesh11):
     st = fe.generate(list(range(1, 6)), max_new_tokens=6)
     first_two = [next(st), next(st)]
     r = st.req
-    # force a mid-stream requeue (what pool-exhaustion preemption does)
-    eng.ex.drain_decode()
+    # force a mid-stream requeue (what pool-exhaustion preemption does),
+    # its in-flight token landed first as rank-failure recovery lands it
+    eng._drain_decode()
     eng.sched.requeue_for_reprefill(r)
     rest = st.tokens()
     assert len(first_two) + len(rest) == 6

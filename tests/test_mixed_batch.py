@@ -232,3 +232,153 @@ def test_switch_overlap_steps_dispatch_decode_only_plans(tiny_moe, mesh11):
         assert plan.Sq == 1 and plan.prefill_tokens == 0
         assert {row.kind for row in plan.rows} == {"decode"}
         assert plan.B == B
+
+
+# ---------------------------------------------------------------------------
+# the one-deep pipeline: step n+1 is planned and staged while n is in flight
+# ---------------------------------------------------------------------------
+
+def _pipeline_trace():
+    """Two short decoders (each prompt ends in the dispatch before its
+    first decode), a three-chunk prompt beside them, its prefix-cache
+    follower, a request that finishes at its first token, and a wave that
+    takes the rung from 4 to 8 and back."""
+    rng = np.random.default_rng(7)
+    shared = list(rng.integers(5, 200, 20))
+    reqs = [Request(rid=0, prompt=list(rng.integers(5, 200, 5)),
+                    max_new_tokens=14, arrival_s=0.0),
+            Request(rid=1, prompt=list(rng.integers(5, 200, 6)),
+                    max_new_tokens=9, arrival_s=0.0),
+            Request(rid=2, prompt=shared, max_new_tokens=4, arrival_s=1.0),
+            Request(rid=3, prompt=list(shared), max_new_tokens=5,
+                    arrival_s=2.0),
+            Request(rid=4, prompt=list(rng.integers(5, 200, 7)),
+                    max_new_tokens=1, arrival_s=2.0)]
+    reqs += [Request(rid=5 + i, prompt=list(rng.integers(5, 200, 4 + i)),
+                     max_new_tokens=6 + i, arrival_s=6.0)
+             for i in range(5)]
+    return reqs
+
+
+CANCEL = (9, 6)                    # (before step, rid)
+
+
+def _pipelined(eng, reqs):
+    """`MoebiusEngine.step` on a clock that ticks once a step; checks the
+    pipeline's invariants on the way."""
+    for r in reqs:
+        eng.submit(r)
+    i = 0
+    while eng.sched.has_work():
+        if i + 1 == CANCEL[0]:
+            assert eng.cancel(CANCEL[1])
+        before = eng._in_flight
+        n0 = eng.metrics.dispatches
+        eng.step()
+        eng._clock.advance(1.0)
+        i += 1
+        assert i < 500
+        if eng._in_flight is not None:
+            assert eng.sched.has_work()
+        if eng.metrics.dispatches == n0 and before is not None:
+            # nothing launched: what was in flight landed in this step
+            assert eng._in_flight is None
+            assert all(row.req.inflight == 0 for row in before[0].rows)
+    return i
+
+
+def _synchronous(eng, reqs):
+    """One dispatch at a time through the same API: plan, launch, fetch
+    and commit in series, with admission as the engine's step does it."""
+    s, ex = eng.sched, eng.ex
+    for r in reqs:
+        s.submit(r)
+    i = 0
+    while s.has_work():
+        i += 1
+        if i == CANCEL[0]:
+            assert s.cancel_request(CANCEL[1]) is not None
+        s.admit(eng.now())
+        s.start_prefills()
+        plan = s.plan_mixed(i, budget=eng.token_budget,
+                            chunk=ex.prefill_chunk)
+        ex.run_copies(s.drain_copies())
+        if plan.rows:
+            nxt = ex.run_mixed(plan, i)
+            s.commit_mixed(plan, ex.fetch_mixed(nxt), eng.now())
+        eng._clock.advance(1.0)
+        assert i < 500
+
+
+@pytest.mark.parametrize("pages_ep", [64, 12])
+def test_pipelined_engine_matches_synchronous_driver(tiny_moe, mesh11,
+                                                     pages_ep):
+    """The pipelined engine gives every request the tokens a driver that
+    lands each dispatch before planning the next gives it, and drains to
+    a step boundary for a cancel and, in the tight pool, a preemption (the
+    ample pool serves the prefix-cache follower from the cache)."""
+    reqs0 = _pipeline_trace()
+    outs, engs = [], []
+    for drive in (_pipelined, _synchronous):
+        eng = MoebiusEngine(
+            tiny_moe, mesh11,
+            CacheConfig(page_size=4, pages_ep=pages_ep, max_pages_per_req=8),
+            ecfg=EngineConfig(start_layout="tp", ladder=(4, 8),
+                              prefill_chunk=8, temperature=0.0,
+                              clock=VirtualClock(),
+                              policy=PolicyConfig(t_high=10**9, t_low=-1,
+                                                  cooldown_s=10**9)))
+        drive(eng, copy.deepcopy(reqs0))
+        outs.append(_outputs(eng, reqs0))
+        engs.append(eng)
+    assert outs[0] == outs[1]
+    assert sorted(outs[0]) == list(range(len(reqs0)))
+    m = engs[0].metrics
+    assert m.overlapped_dispatches > 0 and m.mixed_dispatches > 0
+    assert m.pipeline_drains["cancel"] == 1
+    if pages_ep < 64:
+        assert m.preemptions > 0 and m.pipeline_drains["starved"] > 0
+    else:
+        assert m.prefix_hits > 0
+    assert engs[1].metrics.overlapped_dispatches == 0
+    for a in engs[0].alloc:
+        a.check()
+
+
+def test_pipeline_compiles_nothing_after_warmup(tiny_moe, mesh11):
+    """Warm-up builds every (rung, chunk width) step once; a window whose
+    rung changes between dispatches and whose prefill chunks ride beside
+    decode rows, with decode tokens taken from the carried buffer, then
+    compiles nothing: the buffer has one shape at every rung."""
+    import jax
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **_: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    eng = MoebiusEngine(
+        tiny_moe, mesh11,
+        CacheConfig(page_size=4, pages_ep=64, max_pages_per_req=8),
+        ecfg=EngineConfig(start_layout="tp", ladder=(4, 8), prefill_chunk=8,
+                          temperature=0.0, clock=VirtualClock(),
+                          policy=PolicyConfig(t_high=10**9, t_low=-1,
+                                              cooldown_s=10**9)))
+    eng.warmup()
+    shapes = []
+    orig = eng.ex.run_mixed
+
+    def run_mixed(plan, step_i):
+        shapes.append((plan.B, plan.Sq,
+                       any(r.src >= 0 for r in plan.rows)))
+        return orig(plan, step_i)
+    eng.ex.run_mixed = run_mixed
+    n0 = len(compiles)
+    for r in _pipeline_trace():
+        eng.submit(r)
+    while eng.sched.has_work():
+        eng.step()
+        eng._clock.advance(1.0)
+    assert len(compiles) == n0, compiles[n0:]
+    carried = {(B, Sq) for B, Sq, fed in shapes if fed}
+    assert {B for B, _ in carried} == {4, 8} and {Sq for _, Sq in carried} \
+        == {1, 8}

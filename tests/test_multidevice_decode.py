@@ -36,17 +36,27 @@ for layout in (TP, EP, TPEP):
     ti = np.zeros((2, 4, 8), np.int32); ti[:, 0, :P0] = prompt
     pos = np.zeros((2, 4), np.int32)
     vl = np.zeros((2, 4), np.int32); vl[:, 0] = P0
-    nxt, kv, _ = pre(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
-                  jnp.asarray(vl), jnp.asarray(bt), key)
+    carry = jnp.zeros((2, 4), jnp.int32)
+    src = np.full((2, 4), -1, np.int32)
+    nxt, kv, _, carry = pre(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
+                            jnp.asarray(vl), jnp.asarray(bt), key, carry,
+                            jnp.asarray(src))
     out = [int(nxt[0, 0])]
     dec = build_mixed_step(cfg, mesh, layout, cc, 4, Sq=1, donate=False)
     kvlen = P0
     for i in range(n - 1):
-        ti = np.zeros((2, 4, 1), np.int32); ti[:, 0, 0] = np.array(nxt)[:, 0]
+        # odd steps feed the token from the host, even ones from the carry
+        ti = np.zeros((2, 4, 1), np.int32)
+        src = np.full((2, 4), -1, np.int32)
+        if i % 2:
+            ti[:, 0, 0] = np.array(nxt)[:, 0]
+        else:
+            src[:, 0] = 0
         pos = np.zeros((2, 4), np.int32); pos[:, 0] = kvlen
         vl = np.zeros((2, 4), np.int32); vl[:, 0] = 1
-        nxt, kv, _ = dec(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
-                      jnp.asarray(vl), jnp.asarray(bt), key)
+        nxt, kv, _, carry = dec(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
+                                jnp.asarray(vl), jnp.asarray(bt), key, carry,
+                                jnp.asarray(src))
         out.append(int(nxt[0, 0])); kvlen += 1
     assert out == ref, (layout, out, ref)
 print("OK")
@@ -85,8 +95,10 @@ for layout in (TP, EP, TPEP):
     for i, p in prompts.items():
         ti[:, i, :len(p)] = p; vl[:, i] = len(p)
         bt[:, i, :3] = pages[i]
-    nxt, kv, _ = pre(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
-                  jnp.asarray(vl), jnp.asarray(bt), key)
+    carry = jnp.zeros((2, B), jnp.int32)
+    src = jnp.full((2, B), -1, jnp.int32)
+    nxt, kv, _, _ = pre(pack, kv, jnp.asarray(ti), jnp.asarray(pos),
+                     jnp.asarray(vl), jnp.asarray(bt), key, carry, src)
     nxt = np.asarray(nxt)
     first = {i: int(nxt[0, i]) for i in prompts}
     # path A: N single steps with host feedback
@@ -98,8 +110,8 @@ for layout in (TP, EP, TPEP):
         vl = np.zeros((2, B), np.int32)
         for i in prompts:
             ti[:, i, 0] = cur[i]; pos[:, i] = kl[i]; vl[:, i] = 1
-        nx, kv_a, _ = dec(pack, kv_a, jnp.asarray(ti), jnp.asarray(pos),
-                       jnp.asarray(vl), jnp.asarray(bt), key)
+        nx, kv_a, _, _ = dec(pack, kv_a, jnp.asarray(ti), jnp.asarray(pos),
+                          jnp.asarray(vl), jnp.asarray(bt), key, carry, src)
         nx = np.asarray(nx)
         for i in prompts:
             cur[i] = int(nx[0, i]); kl[i] += 1; outs_a[i].append(cur[i])

@@ -79,17 +79,27 @@ def _traced(eng, tmp_path, n_reqs=5):
 
 
 def test_mixed_step_phases_order_and_arguments(tiny_moe, tmp_path):
+    """A step plans, stages and launches its dispatch, then fetches and
+    commits the one launched before it; the last step launches nothing
+    and lands the last dispatch inside `exec.drain`."""
     eng = _engine(tiny_moe)
     spans, logged = _traced(eng, tmp_path)
     steps = by_step(spans)
     assert len(steps) == len(logged) > 5
-    for (st, kids), dispatches in zip(steps, logged):
+    for i, ((st, kids), dispatches) in enumerate(zip(steps, logged)):
         assert len(kids) + 1 <= MAX_SPANS_PER_STEP
         names = [k[0] for k in kids]
         assert names[:2] == ["sched.admit", "policy"]
         assert names[-1] == "account"
+        phases = [n for n in names if n in PHASES]
+        if not dispatches:
+            assert i == len(steps) - 1
+            assert phases == ["sched.plan", "exec.fetch", "sched.commit"]
+            assert "exec.drain" in names and st[3]["B"] == 0
+            continue
         (B, Sq, dec, pre), = dispatches
-        assert [n for n in names if n in PHASES] == PHASES
+        assert phases == (PHASES if i else PHASES[:3])
+        assert st[3]["overlap"] == (1 if i else 0)
         assert {k: st[3][k] for k in ("B", "Sq", "dec", "pre")} == \
             {"B": B, "Sq": Sq, "dec": dec, "pre": pre}
         stage, = [k for k in kids if k[0] == "exec.stage"]
@@ -101,6 +111,46 @@ def test_mixed_step_phases_order_and_arguments(tiny_moe, tmp_path):
     # steps follow one another
     for (a, _), (b, _) in zip(steps, steps[1:]):
         assert a[2] <= b[1]
+
+
+def test_pipeline_counters_account_for_every_dispatch(tiny_moe):
+    """Each mixed dispatch is launched while the one before it is in
+    flight, or is the last before a drain: a cancel drains under its own
+    cause, the step that finds nothing to launch under `idle`."""
+    eng = _engine(tiny_moe)
+    rng = np.random.default_rng(1)
+    for i in range(6):
+        eng.submit(Request(rid=i, prompt=list(rng.integers(5, 200, 9)),
+                           max_new_tokens=8))
+    for _ in range(6):
+        eng.step()
+    assert eng._in_flight is not None
+    assert eng.cancel(5)
+    eng.run()
+    m = eng.metrics
+    assert eng._in_flight is None
+    assert m.dispatches == m.overlapped_dispatches + sum(
+        m.pipeline_drains.values())
+    assert m.pipeline_drains == {"cancel": 1, "idle": 1}
+    assert m.overlapped_dispatches >= 0.8 * m.dispatches
+
+
+def test_step_overlap_and_drain_spans(tiny_moe, tmp_path):
+    """`moebius.step` carries `overlap`, and each drain opens
+    `moebius.exec.drain {cause}` over the fetch and commit it makes."""
+    eng = _engine(tiny_moe)
+    spans, logged = _traced(eng, tmp_path)
+    steps = [st for st, _ in by_step(spans)]
+    m = eng.metrics
+    assert sum(st[3]["overlap"] for st in steps) == \
+        m.overlapped_dispatches > 0
+    drains = [e for e in spans if e[0] == "exec.drain"]
+    assert [d[3]["cause"] for d in drains] == list(m.pipeline_drains) \
+        == ["idle"]
+    for d in drains:
+        inner = [e[0] for e in spans if d[1] <= e[1] and e[2] <= d[2]
+                 and e is not d]
+        assert inner == ["exec.fetch", "sched.commit"]
 
 
 def test_request_stamps_prefill_start(tiny_moe, tmp_path):

@@ -182,7 +182,8 @@ def test_mixed_step_reads_expert_stacks_in_place(topo, no_cache,
         arg((1, B, Sq), I32, P("data", None, None)),
         arg((1, B), I32, P("data", None)), arg((1, B), I32, P("data", None)),
         arg((1, B, cc.max_pages_per_req), I32, P("data", None, None)),
-        arg((2,), jnp.uint32, P())).compile()
+        arg((2,), jnp.uint32, P()), arg((1, B), I32, P("data", None)),
+        arg((1, B), I32, P("data", None))).compile()
     moe = pack["layers"]["moe"]
     layer_bytes = {int(np.prod(w.shape[1:])) * w.dtype.itemsize
                    for w in (moe["w13"], moe["w2"])}
